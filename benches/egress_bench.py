@@ -6,7 +6,7 @@ disclosed — the deployment core is shared, so single samples lie):
 
 - ``egress/engine``: the native egress engine (`native.egress_encode`,
   framing.cpp) turning a step's delivery matrix into per-user wire
-  streams — the ``host_egress_msgs_s`` number BASELINE.md tracks. Same
+  streams — the ``host_egress_msgs_s`` number. Same
   shape as bench.py's companion row: 1024 user slots, 16384 frames x
   1 KB, 16 receivers per frame.
 - ``egress/wire``: end-to-end host egress — pre-serialized frames fanned

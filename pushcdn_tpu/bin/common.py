@@ -195,6 +195,21 @@ def keypair_from_seed(seed: Optional[int],
     return scheme_by_name(scheme).generate_keypair(seed=seed)
 
 
+def free_ports(n: int) -> list:
+    """``n`` distinct loopback TCP ports that were free a moment ago (all
+    held open together while picking, so the kernel cannot hand the same
+    one out twice) — for launchers that pass ports on a command line."""
+    import socket
+    socks = [socket.socket() for _ in range(n)]
+    try:
+        for s in socks:
+            s.bind(("127.0.0.1", 0))
+        return [s.getsockname()[1] for s in socks]
+    finally:
+        for s in socks:
+            s.close()
+
+
 def spawn_binary(name: str, *args: str, env_extra=None, capture=True,
                  log_path=None):
     """Launch ``pushcdn_tpu.bin.<name>`` as a child process with the repo

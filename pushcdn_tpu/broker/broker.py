@@ -123,6 +123,9 @@ class Broker:
         self.host_links_kick = asyncio.Event()
         self._metrics_server = None
         self.device_plane = None
+        # parallel.runtime.Runtime of the process, set by bin/broker when
+        # a device plane was requested (compile accounting for topology)
+        self.device_runtime = None
         self.shard_runtime = None  # ShardRuntime when this is one of N workers
         self.durable = None  # DurableTopics, set in new() (ISSUE 14)
         self.seen_dialing: set[str] = set()  # peers we're currently dialing
@@ -370,7 +373,15 @@ class Broker:
             for t in sorted(set(conns.user_topics.values()))}
         state = getattr(self, "_route_state", None)
         runtime = self.shard_runtime
+        plane = self.device_plane
+        device = None
+        if plane is not None:
+            device = plane.describe()
+            if self.device_runtime is not None:
+                device["compile_cache"] = self.device_runtime.cache_dir
+                device.update(self.device_runtime.compiles.snapshot())
         return {
+            "device_plane": device,
             "shard_runtime": runtime.stats() if runtime is not None else None,
             "identity": str(self.identity),
             "draining": health_mod.draining() is not None,
@@ -504,4 +515,6 @@ class Broker:
         plane = self.device_plane
         if plane is not None:
             broker_metrics.DEVICE_STEPS.set(plane.steps)
+            broker_metrics.DEVICE_FRAMES_STAGED.set(plane.frames_staged)
             broker_metrics.DEVICE_MESSAGES_ROUTED.set(plane.messages_routed)
+            broker_metrics.DEVICE_PLANE_DISABLED.set(int(plane.disabled))

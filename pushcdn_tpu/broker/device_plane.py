@@ -26,10 +26,15 @@ Consistency design (single-writer, snapshot-per-step):
 - **Slot quarantine**: a released user slot is not reusable until the step
   that might still carry frames addressed to it has completed — prevents a
   recycled slot from leaking one user's messages to another.
-- **Failure = host fallback**: if a step raises, its staged frames are
-  re-routed on the host path (users-only, matching what the device would
-  have delivered) and the plane disables itself; staging then always
-  returns False and the broker is a plain host broker again.
+- **A failed warm-up is fatal**: if the first compile-and-run raises
+  (no usable device, a kernel Mosaic refuses), ``start`` raises and the
+  broker exits non-zero — a broker asked for a device plane never serves
+  as a silent host broker.
+- **A mid-run failure protects acknowledged frames**: if a later step
+  raises, its staged frames are re-routed on the host path (users-only,
+  matching what the device would have delivered) and the plane disables
+  itself; staging then always returns INELIGIBLE. The state is exported
+  as ``cdn_device_plane_disabled`` and in ``/debug/topology``.
 
 Flow per step:
   ingress: user_receive_loop → try_stage() → FrameRing (slot credits)
@@ -41,6 +46,7 @@ from __future__ import annotations
 
 import asyncio
 import logging
+import time
 from dataclasses import dataclass
 from typing import TYPE_CHECKING, List, Optional, Tuple
 
@@ -199,7 +205,9 @@ class DevicePlane:
         self._task: Optional[asyncio.Task] = None
         self._step_inflight = False
         self.steps = 0
+        self.frames_staged = 0      # frames accepted into a ring
         self.messages_routed = 0
+        self.warmup_s: Optional[float] = None
 
     # ---- user lifecycle (Connections observer; event-loop only) ----------
 
@@ -310,6 +318,7 @@ class DevicePlane:
         else:
             return StageResult.INELIGIBLE
         if ok:
+            self.frames_staged += 1
             self._kick.set()
             return StageResult.STAGED
         return StageResult.FULL
@@ -358,15 +367,16 @@ class DevicePlane:
                     placed = True
                     break
             results[idx] = StageResult.STAGED if placed else StageResult.FULL
-        staged_any = False
+        staged = 0
         for li, group in groups.items():
             n = self.rings[li].push_batch(
                 [g[1] for g in group], [g[2] for g in group],
                 [g[3] for g in group], [g[4] for g in group])
-            staged_any = staged_any or n > 0
+            staged += n
             for idx, *_ in group[n:]:  # raced-full leftovers
                 results[idx] = StageResult.FULL
-        if staged_any:
+        if staged:
+            self.frames_staged += staged
             self._kick.set()
         return results
 
@@ -378,9 +388,55 @@ class DevicePlane:
     # ---- the pump ---------------------------------------------------------
 
     async def start(self) -> None:
-        # compile the step off the hot path (first jit can take seconds)
+        logger.info("device plane: %s", self.describe())
+        # compile the step off the hot path (first jit can take seconds);
+        # a warm-up that raises propagates: the broker must not come up
+        # as a host broker behind a --device-plane flag
+        t0 = time.monotonic()
         await asyncio.to_thread(self._warmup)
+        self.warmup_s = time.monotonic() - t0
+        logger.info("device plane warm-up (compile + first step) took "
+                    "%.2f s", self.warmup_s)
         self._task = asyncio.create_task(self._pump(), name="device-pump")
+
+    def kernels(self) -> dict:
+        """Which implementation each step shape dispatches to on this
+        backend ("pallas" = compiled by Mosaic on a TPU, interpreted
+        elsewhere; "xla" = the jnp twin through XLA). The user dimension
+        moves in buckets of 64, which never changes the answer."""
+        from pushcdn_tpu.ops.delivery_kernel import selects_pallas
+        from pushcdn_tpu.ops.ragged_delivery import ragged_selects_pallas
+        from pushcdn_tpu.parallel import router
+        c = self.config
+        dense = {f"{slots}x{width}B": slots
+                 for width, slots in c.lane_shapes()}
+        dense[f"latency[{c.latency_slots}]"] = c.latency_slots
+        out = {name: ("pallas" if selects_pallas(
+            c.num_user_slots, n, router.USE_PALLAS_DELIVERY) else "xla")
+            for name, n in dense.items()}
+        if self.delivery_impl == "ragged":
+            out["ragged"] = ("pallas" if ragged_selects_pallas(
+                router.RAGGED_USE_PALLAS) else "xla")
+        return out
+
+    def describe(self) -> dict:
+        """The plane's device and state as one JSON-able dict — logged
+        once at start, served under ``/debug/topology``."""
+        from pushcdn_tpu.parallel import runtime
+        dev = runtime.device()
+        return {
+            "platform": dev.platform, "device_kind": dev.kind,
+            "device_count": dev.count,
+            "delivery_impl": self.delivery_impl,
+            "kernels": self.kernels(),
+            "disabled": self.disabled,
+            "warmup_s": self.warmup_s,
+            "steps": self.steps,
+            "frames_staged": self.frames_staged,
+            "messages_routed": self.messages_routed,
+            "mirrored_users": len(self.slots),
+            "unmirrored_users": len(self._unmirrored),
+        }
 
     def _pack_walks(self, batches):
         """Pack one walk list per lane (event-loop only — the index is
@@ -406,24 +462,20 @@ class DevicePlane:
         empty = [r.take_batch() for r in self.rings]
         lat = [slice_batch(b, self.config.latency_slots) for b in empty]
         u0 = effective_users(0, self.config.num_user_slots)
-        try:
-            # compile the only two specializations the pump uses: all lanes
-            # at full shapes (idle lanes ride cached device empties) and
-            # the latency-sliced base lane; wider user buckets compile on
-            # first growth past the mark
-            walks = self._pack_walks(empty) \
-                if self.delivery_impl == "ragged" else None
-            self._run_step(empty, self._owned[:u0].copy(),
-                           self._masks[:u0].copy(), walks=walks,
-                           compile_only=True)
-            self._run_step(lat[:1], self._owned[:u0].copy(),
-                           self._masks[:u0].copy(),
-                           walks=None if walks is None else walks[:1],
-                           compile_only=True)
-            self.steps -= 2  # warmup doesn't count
-        except Exception:
-            logger.exception("device-plane warmup step failed")
-            self.disabled = True
+        # compile the only two specializations the pump uses: all lanes
+        # at full shapes (idle lanes ride cached device empties) and
+        # the latency-sliced base lane; wider user buckets compile on
+        # first growth past the mark
+        walks = self._pack_walks(empty) \
+            if self.delivery_impl == "ragged" else None
+        self._run_step(empty, self._owned[:u0].copy(),
+                       self._masks[:u0].copy(), walks=walks,
+                       compile_only=True)
+        self._run_step(lat[:1], self._owned[:u0].copy(),
+                       self._masks[:u0].copy(),
+                       walks=None if walks is None else walks[:1],
+                       compile_only=True)
+        self.steps -= 2  # warmup doesn't count
 
     async def stop(self) -> None:
         if self._task is not None:
@@ -611,6 +663,12 @@ class DevicePlane:
         result = routing_step_lanes_single(state, batches,
                                            gather_bytes=False)
         self.steps += 1
+        if compile_only:
+            # warm-up: a step that compiles but dies on the device must
+            # fail start-up, not the first real tick
+            for lane in result.lanes:
+                lane.deliver.block_until_ready()
+            return []
         jobs = []
         for li, lane in enumerate(result.lanes):
             if not busy[li]:

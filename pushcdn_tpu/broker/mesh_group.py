@@ -14,9 +14,11 @@ and runs ONE jitted ``shard_map`` routing step per tick, in which
   table.
 
 Host TCP/memory broker links remain as the **fallback plane**: brokers in
-a group still heartbeat/dial each other, and if a device step ever fails
-the staged batches are re-routed over those links and the group disables
-itself (fail-open to the reference's architecture).
+a group still heartbeat/dial each other, and if a device step fails
+mid-run the staged batches are re-routed over those links and the group
+disables itself (fail-open to the reference's architecture; visible as
+``cdn_device_plane_disabled``). A warm-up step that fails is fatal: the
+member broker's ``start`` raises.
 
 Consistency: one process = one source of truth. The group owns the GLOBAL
 user-slot table and mirrors (owner shard, claim version, topic mask per
@@ -166,6 +168,22 @@ class MeshShardPlane:
     async def stop(self) -> None:
         await self.group.on_shard_stopped(self.shard)
 
+    def describe(self) -> dict:
+        """This shard's view of the group for ``/debug/topology`` (the
+        single-shard plane's twin)."""
+        from pushcdn_tpu.parallel import runtime
+        dev = runtime.device()
+        g = self.group
+        return {
+            "platform": dev.platform, "device_kind": dev.kind,
+            "device_count": dev.count,
+            "mesh_shards": g.num_shards, "shard": self.shard,
+            "fused_collective": g.config.fused_collective,
+            "disabled": g.disabled, "steps": g.steps,
+            "frames_staged": g.frames_staged,
+            "messages_routed": g.messages_routed,
+        }
+
     @property
     def disabled(self) -> bool:
         return self.group.disabled
@@ -177,6 +195,10 @@ class MeshShardPlane:
     @property
     def steps(self) -> int:
         return self.group.steps
+
+    @property
+    def frames_staged(self) -> int:
+        return self.group.frames_staged
 
     @property
     def messages_routed(self) -> int:
@@ -243,6 +265,7 @@ class MeshBrokerGroup:
         self._started = False
         self._state_dirty = False  # forces a step with no staged traffic
         self.steps = 0
+        self.frames_staged = 0  # frames accepted into a ring or bucket
         self.messages_routed = 0
         # collectives traced by the most recently COMPILED step
         # specialization (router.trace_collectives delta around the call):
@@ -288,23 +311,21 @@ class MeshBrokerGroup:
         small_d = [[slice_direct_batch(d, lat) for d in lane]
                    for lane in directs]
         u0 = effective_users(0, self.config.num_user_slots)
-        try:
-            # compile the ONLY two specializations the pump needs at first
-            # population (u_eff = first user bucket): all lanes at full
-            # shapes (idle lanes ride cached device-side empties, so
-            # traffic mix never changes the jit key), and the latency-
-            # sliced base lanes (sparse traffic); wider user buckets
-            # compile on first growth past the mark
-            self._run_step(batches, directs, self._owner[:u0].copy(),
-                           self._claim_version[:u0].copy(),
-                           self._masks[:u0].copy())
-            self._run_step(small[:1], small_d[:1], self._owner[:u0].copy(),
-                           self._claim_version[:u0].copy(),
-                           self._masks[:u0].copy())
-            self.steps -= 2  # warmup doesn't count
-        except Exception:
-            logger.exception("mesh-group warmup step failed")
-            self.disabled = True
+        # compile the ONLY two specializations the pump needs at first
+        # population (u_eff = first user bucket): all lanes at full
+        # shapes (idle lanes ride cached device-side empties, so
+        # traffic mix never changes the jit key), and the latency-
+        # sliced base lanes (sparse traffic); wider user buckets
+        # compile on first growth past the mark. A failure here
+        # propagates: a group that cannot step must not come up as a
+        # set of host brokers behind a mesh flag.
+        self._run_step(batches, directs, self._owner[:u0].copy(),
+                       self._claim_version[:u0].copy(),
+                       self._masks[:u0].copy())
+        self._run_step(small[:1], small_d[:1], self._owner[:u0].copy(),
+                       self._claim_version[:u0].copy(),
+                       self._masks[:u0].copy())
+        self.steps -= 2  # warmup doesn't count
 
     async def on_shard_stopped(self, shard: int) -> None:
         self.brokers[shard] = None
@@ -459,6 +480,7 @@ class MeshBrokerGroup:
         else:
             return StageResult.INELIGIBLE
         if ok:
+            self.frames_staged += 1
             self._kick.set()
             return StageResult.STAGED
         return StageResult.FULL
@@ -478,7 +500,7 @@ class MeshBrokerGroup:
         rings = [lane[shard] for lane in self.lane_rings]
         free = [r.free_slots for r in rings]
         widest = rings[-1].frame_bytes
-        staged_any = False
+        staged = 0
         for idx, (message, raw) in enumerate(items):
             frame = bytes(raw.data)
             if len(frame) > widest:
@@ -515,7 +537,7 @@ class MeshBrokerGroup:
                     lambda b: b.push(owner, frame, slot))
                 results[idx] = (StageResult.STAGED if ok
                                 else StageResult.FULL)
-                staged_any = staged_any or ok
+                staged += ok
         from pushcdn_tpu.proto.message import KIND_BROADCAST
         for li, group in groups.items():
             n = rings[li].push_batch(
@@ -523,10 +545,11 @@ class MeshBrokerGroup:
                 [KIND_BROADCAST] * len(group),
                 [g[2] for g in group],
                 [-1] * len(group))
-            staged_any = staged_any or n > 0
+            staged += n
             for idx, *_ in group[n:]:
                 results[idx] = StageResult.FULL
-        if staged_any:
+        if staged:
+            self.frames_staged += staged
             self._kick.set()
         return results
 
@@ -729,6 +752,11 @@ class MeshBrokerGroup:
         if traced:  # this call compiled a fresh specialization
             self.collectives_last_trace = traced
         self.steps += 1
+        if not (any(busy_b) or any(busy_d)):
+            # nothing to read back (warm-up, membership-only tick): wait
+            # for the step anyway, so a device failure raises here and
+            # not at some later tick's readback
+            jax.block_until_ready(result.evictions)
         # ---- egress prep: decisions from the mesh, payloads from host ----
         # (idle lanes can't deliver: skip their D2H entirely)
         jobs = []

@@ -12,6 +12,13 @@ NUM_BROKERS_CONNECTED = Gauge("cdn_num_brokers_connected",
 # updated by broker.update_metrics() from the attached plane's counters
 DEVICE_STEPS = Gauge("cdn_device_steps",
                      "Routing steps executed by the attached device plane")
+DEVICE_FRAMES_STAGED = Gauge(
+    "cdn_device_frames_staged",
+    "Frames accepted into the device plane's staging rings")
 DEVICE_MESSAGES_ROUTED = Gauge(
     "cdn_device_messages_routed",
     "Messages delivered via the device plane's egress")
+DEVICE_PLANE_DISABLED = Gauge(
+    "cdn_device_plane_disabled",
+    "1 once a device step failed mid-run and the plane fell open to the "
+    "host path (staged frames were re-routed; nothing stages since)")

@@ -135,10 +135,9 @@ class MultiHostBrokerGroup(MeshBrokerGroup):
         def per_shard(x):
             return jax.lax.psum(x[0], BROKER_AXIS)[None]
 
-        from pushcdn_tpu.parallel.jax_compat import shard_map as _shard_map_compat
-        sharded = _shard_map_compat(
+        sharded = jax.shard_map(
             per_shard, mesh=mesh, in_specs=(P(BROKER_AXIS),),
-            out_specs=P(BROKER_AXIS))
+            out_specs=P(BROKER_AXIS), check_vma=False)
         return jax.jit(sharded)
 
     def _collective_stop(self, want_stop: bool) -> bool:
@@ -285,19 +284,17 @@ class MultiHostBrokerGroup(MeshBrokerGroup):
                    for rings in self.lane_rings]
         directs = [[b.take_batch() for b in bkts]
                    for bkts in self.lane_buckets]
-        try:
-            self._run_step(batches, directs, self._owner.copy(),
-                           self._claim_version.copy(), self._masks.copy(),
-                           self._liveness.copy())
-            self.steps -= 1
-            # compile + first-rendezvous the stop barrier here too: its
-            # first pump-tick call runs under the collective watchdog,
-            # and paying jit compile inside that window could fail-close
-            # a healthy group at startup on a contended host
-            self._collective_stop(False)
-        except Exception:
-            logger.exception("multi-host warmup step failed")
-            self.disabled = True
+        # a failure here propagates (the member broker's start raises):
+        # a group that cannot step must not serve as plain host brokers
+        self._run_step(batches, directs, self._owner.copy(),
+                       self._claim_version.copy(), self._masks.copy(),
+                       self._liveness.copy())
+        self.steps -= 1
+        # compile + first-rendezvous the stop barrier here too: its
+        # first pump-tick call runs under the collective watchdog,
+        # and paying jit compile inside that window could fail-close
+        # a healthy group at startup on a contended host
+        self._collective_stop(False)
 
     async def on_shard_stopped(self, shard: int) -> None:
         # release local users of the stopped shard (same sweep as the

@@ -4,47 +4,104 @@ The library is compiled on first use (g++ is in the image; pybind11 is
 not, so the ABI is plain C via ctypes) and cached under ``.build/``.
 Everything degrades gracefully: ``available()`` is False if compilation
 fails and callers fall back to the numpy/Python paths.
+
+Every native library of the package is built and cached by one rule,
+:func:`_build_lib`: the cached ``.so`` is named by a hash of the CONTENT
+of the sources it was compiled from (and its flags), so a ``.build/``
+filled on another host, from other sources, or copied without its
+mtimes can never be loaded in place of what ``native/*.cpp`` says now.
 """
 
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
 import threading
-from typing import Optional, Tuple
+from typing import Dict, Optional, Tuple
 
 import numpy as np
 
 _REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 _SRC = os.path.join(_REPO, "native", "framing.cpp")
 _BUILD_DIR = os.path.join(_REPO, ".build")
-_LIB_PATH = os.path.join(_BUILD_DIR, "libpushcdn_framing.so")
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
 
 
-def _build_lib(src: str, lib_path: str, loader, extra_flags: tuple = ()):
-    """Compile ``src`` to ``lib_path`` when stale and load it via
-    ``loader`` (CDLL or PyDLL). Returns None on ANY failure — a missing
-    source next to a cached .so, a compiler error, a load error — so
-    callers always degrade to their Python fallback."""
+def lib_path(name: str, sources: tuple, flags: tuple = (),
+             key_extra: str = "") -> str:
+    """Where library ``name`` built from ``sources`` with ``flags`` lives:
+    ``.build/libpushcdn_<name>-<hash>.so``. The hash covers the bytes of
+    every source (the translation unit first, then each file it
+    ``#include``s from ``native/``), the flags, and ``key_extra`` (a host
+    fingerprint where ``-march=native`` ties the binary to the CPU)."""
+    h = hashlib.sha256()
+    for src in sources:
+        with open(src, "rb") as f:
+            h.update(f.read())
+        h.update(b"\0")
+    h.update("\0".join((*flags, key_extra)).encode())
+    return os.path.join(_BUILD_DIR,
+                        f"libpushcdn_{name}-{h.hexdigest()[:16]}.so")
+
+
+def _build_lib(name: str, sources: tuple, loader, extra_flags: tuple = (),
+               key_extra: str = ""):
+    """Compile ``sources[0]`` into :func:`lib_path` unless that exact
+    file already exists, and load it via ``loader`` (CDLL or PyDLL).
+    g++ writes to a per-process temp name that is renamed into place, so
+    concurrent first starts (marshal, broker and clients of one cluster)
+    never load a half-written library; libraries of the same name built
+    from other content are removed. Returns None on ANY failure — a
+    missing source, a compiler error, a load error — so callers always
+    degrade to their Python fallback."""
     try:
-        os.makedirs(_BUILD_DIR, exist_ok=True)
-        if not os.path.exists(lib_path) or \
-                os.path.getmtime(src) > os.path.getmtime(lib_path):
-            cmd = ["g++", "-O3", "-shared", "-fPIC", *extra_flags,
-                   src, "-o", lib_path]
-            subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        return loader(lib_path)
+        path = lib_path(name, sources, extra_flags, key_extra)
+        if not os.path.exists(path):
+            os.makedirs(_BUILD_DIR, exist_ok=True)
+            tmp = f"{path}.{os.getpid()}.tmp"
+            try:
+                subprocess.run(
+                    ["g++", "-O3", "-shared", "-fPIC", *extra_flags,
+                     sources[0], "-o", tmp],
+                    check=True, capture_output=True, timeout=180)
+                os.replace(tmp, path)
+            finally:
+                if os.path.exists(tmp):
+                    os.remove(tmp)
+            for stale in glob.glob(os.path.join(
+                    _BUILD_DIR, f"libpushcdn_{name}-*.so")):
+                if stale != path:
+                    os.remove(stale)
+        return loader(path)
     except (subprocess.SubprocessError, OSError):
         return None
 
 
+def build_all() -> Dict[str, bool]:
+    """Build (or reuse) and load every native library of the package from
+    the sources present; name -> loaded. For start-up checks that want
+    the build cost up front and a missing library named (the chip
+    smoke); the serving paths keep building lazily on first use."""
+    from pushcdn_tpu.native import bls, pump, routeplan, syscount, uring
+    return {
+        "framing": _get() is not None,
+        "pydecode": pydecode() is not None,
+        "routeplan": routeplan.available(),
+        "uring": uring._get() is not None,
+        "pump": pump.available(),
+        "bls": bls.available(),
+        "syscount": syscount.build() is not None,
+    }
+
+
 def _compile() -> Optional[ctypes.CDLL]:
-    lib = _build_lib(_SRC, _LIB_PATH, ctypes.CDLL)
+    lib = _build_lib("framing", (_SRC,), ctypes.CDLL)
     if lib is None:
         return None
 
@@ -110,20 +167,15 @@ _pydecode_fn = None
 _pydecode_tried = False
 
 
-def _pydecode_lib_path() -> str:
-    """The cached .so name is keyed on the interpreter ABI: unlike the
-    plain-C framing lib, pydecode is a CPython-API library (tp_alloc, slot
-    layouts), and loading a cache built against another interpreter's
-    headers is undefined behavior — a Python minor upgrade must recompile,
-    not reuse."""
+def _compile_pydecode():
+    # The cached .so name is ALSO keyed on the interpreter ABI: unlike the
+    # plain-C framing lib, pydecode is a CPython-API library (tp_alloc,
+    # slot layouts), and loading a cache built against another
+    # interpreter's headers is undefined behavior — a Python minor
+    # upgrade must recompile, not reuse.
     import sysconfig
     abi = sysconfig.get_config_var("SOABI") or "unknown-abi"
-    return os.path.join(_BUILD_DIR, f"libpushcdn_pydecode-{abi}.so")
-
-
-def _compile_pydecode():
-    import sysconfig
-    lib = _build_lib(_PYDECODE_SRC, _pydecode_lib_path(), ctypes.PyDLL,
+    lib = _build_lib(f"pydecode-{abi}", (_PYDECODE_SRC,), ctypes.PyDLL,
                      ("-I", sysconfig.get_paths()["include"]))
     if lib is None:
         return None

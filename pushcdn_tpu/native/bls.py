@@ -14,15 +14,14 @@ from __future__ import annotations
 
 import ctypes
 import os
-import subprocess
 import threading
 from typing import Optional
+
+from pushcdn_tpu.native import _build_lib
 
 _REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 _SRC = os.path.join(_REPO, "native", "bls_bn254.cpp")
 _INC = os.path.join(_REPO, "native", "bls_generated.inc")
-_BUILD_DIR = os.path.join(_REPO, ".build")
-_LIB_PATH = os.path.join(_BUILD_DIR, "libpushcdn_bls.so")
 
 SK_LEN = 32
 PK_LEN = 128
@@ -33,49 +32,33 @@ _lib: Optional[ctypes.CDLL] = None
 _tried = False
 
 
-def _compile() -> Optional[ctypes.CDLL]:
-    os.makedirs(_BUILD_DIR, exist_ok=True)
-    # The cached .so may have been built with -march=native on a DIFFERENT
-    # machine (repo on shared storage / baked into an image): loading it
-    # here could die with an uncatchable SIGILL. Key the cache on a host
-    # fingerprint as well as source mtime and rebuild on mismatch.
+def _host_tag() -> str:
+    """Fingerprint of this machine's CPU: a ``-march=native`` binary built
+    elsewhere (repo on shared storage / baked into an image / copied with
+    its ``.build/``) could die here with an uncatchable SIGILL, so the
+    tag is part of that build's cache key."""
     import hashlib
     import platform
     try:
         with open("/proc/cpuinfo") as f:
-            cpu_src = f.read()
+            # model + ISA flags only: the file also carries live clock
+            # readings, which would make the tag differ between reads
+            cpu_src = "".join(sorted({
+                line for line in f
+                if line.startswith(("model name", "flags", "Features"))}))
     except OSError:
         cpu_src = platform.processor() or platform.machine()
-    host_tag = hashlib.sha256(
+    return hashlib.sha256(
         (platform.machine() + "\n" + cpu_src).encode()).hexdigest()[:16]
-    tag_path = _LIB_PATH + ".hosttag"
-    try:
-        cached_tag = open(tag_path).read().strip()
-    except OSError:
-        cached_tag = ""
-    src_mtime = max(os.path.getmtime(_SRC), os.path.getmtime(_INC))
-    if not os.path.exists(_LIB_PATH) or \
-            src_mtime > os.path.getmtime(_LIB_PATH) or cached_tag != host_tag:
-        # -march=native is worth ~10% on the Montgomery ladder (adx/bmi2);
-        # fall back to the portable build where the flag is unsupported
-        base = ["g++", "-O3", "-shared", "-fPIC", _SRC, "-o", _LIB_PATH]
-        for cmd in (base[:2] + ["-march=native"] + base[2:], base):
-            try:
-                subprocess.run(cmd, check=True, capture_output=True,
-                               timeout=180)
-                break
-            except (subprocess.SubprocessError, OSError):
-                continue
-        else:
-            return None
-        try:
-            with open(tag_path, "w") as f:
-                f.write(host_tag)
-        except OSError:
-            pass
-    try:
-        lib = ctypes.CDLL(_LIB_PATH)
-    except OSError:
+
+
+def _compile() -> Optional[ctypes.CDLL]:
+    # -march=native is worth ~10% on the Montgomery ladder (adx/bmi2);
+    # fall back to the portable build where the flag is unsupported
+    lib = _build_lib("bls", (_SRC, _INC), ctypes.CDLL, ("-march=native",),
+                     key_extra=_host_tag()) \
+        or _build_lib("bls", (_SRC, _INC), ctypes.CDLL)
+    if lib is None:
         return None
 
     u8p = ctypes.POINTER(ctypes.c_uint8)

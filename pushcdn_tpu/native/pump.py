@@ -7,9 +7,9 @@ engine's ``Ring._h`` (a ``pcu_ring*``) and the planner's
 ``RoutePlanner._handle`` (a ``RouteTable*``). That interop is sound
 because the structs carry all state (no library globals), every .so is
 built from the same sources with the same flags, and allocation goes
-through the shared libc — but it does mean THIS module must rebuild its
-cache when *any* of the three sources change, so staleness is checked
-against all of them (``_build_lib`` alone only checks one).
+through the shared libc — but it does mean THIS library must rebuild
+when *any* of the three sources change, so all three are named to
+``_build_lib``, whose cache key hashes the content of each.
 
 Policy (which peers engage, fencing, lease parking, submit scheduling)
 lives in ``proto/transport/pump.py``; this module is the thin typed
@@ -25,13 +25,12 @@ from typing import Optional
 
 import numpy as np
 
-from pushcdn_tpu.native import _build_lib, _BUILD_DIR, _REPO, _ptr
+from pushcdn_tpu.native import _REPO, _build_lib, _ptr
 
 _SRC = os.path.join(_REPO, "native", "pump.cpp")
-_DEPS = (_SRC,
-         os.path.join(_REPO, "native", "io_uring.cpp"),
-         os.path.join(_REPO, "native", "route_plan.cpp"))
-_LIB_PATH = os.path.join(_BUILD_DIR, "libpushcdn_pump.so")
+_SOURCES = (_SRC,
+            os.path.join(_REPO, "native", "io_uring.cpp"),
+            os.path.join(_REPO, "native", "route_plan.cpp"))
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
@@ -73,14 +72,7 @@ STATS_KEYS = ("runs", "chains", "sqes", "cqes", "bytes", "frames",
 
 
 def _compile() -> Optional[ctypes.CDLL]:
-    try:
-        if os.path.exists(_LIB_PATH):
-            newest = max(os.path.getmtime(s) for s in _DEPS)
-            if newest > os.path.getmtime(_LIB_PATH):
-                os.remove(_LIB_PATH)  # _build_lib only watches pump.cpp
-    except OSError:
-        return None
-    lib = _build_lib(_SRC, _LIB_PATH, ctypes.CDLL,
+    lib = _build_lib("pump", _SOURCES, ctypes.CDLL,
                      ("-I", os.path.join(_REPO, "native")))
     if lib is None:
         return None

@@ -23,10 +23,9 @@ from typing import List, Optional, Tuple
 
 import numpy as np
 
-from pushcdn_tpu.native import _BUILD_DIR, _REPO, _build_lib
+from pushcdn_tpu.native import _REPO, _build_lib
 
 _SRC = os.path.join(_REPO, "native", "route_plan.cpp")
-_LIB_PATH = os.path.join(_BUILD_DIR, "libpushcdn_routeplan.so")
 
 _lock = threading.Lock()
 _lib = None
@@ -41,7 +40,7 @@ STOP_CAPACITY = 2  # pair buffer full: call again from the returned index
 
 
 def _compile():
-    lib = _build_lib(_SRC, _LIB_PATH, ctypes.CDLL)
+    lib = _build_lib("routeplan", (_SRC,), ctypes.CDLL)
     if lib is None:
         return None
     u8p = ctypes.POINTER(ctypes.c_uint8)

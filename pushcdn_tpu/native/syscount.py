@@ -14,10 +14,10 @@ import ctypes
 import os
 from typing import Dict, Optional
 
-from pushcdn_tpu.native import _BUILD_DIR, _REPO, _build_lib
+from pushcdn_tpu.native import _REPO, _build_lib, lib_path
 
 _SRC = os.path.join(_REPO, "native", "syscount.cpp")
-_LIB_PATH = os.path.join(_BUILD_DIR, "libpushcdn_syscount.so")
+_FLAGS = ("-ldl",)
 
 # index order must match the C_* enum in native/syscount.cpp
 NAMES = ("write", "writev", "send", "sendto", "sendmsg",
@@ -31,9 +31,8 @@ _lib_tried = False
 def build() -> Optional[str]:
     """Compile (or reuse) the interposer; returns its path or None.
     Called by the bench PARENT, before spawning the preloaded child."""
-    path = _build_lib(_SRC, _LIB_PATH, loader=lambda p: p,
-                      extra_flags=("-ldl",))
-    return path
+    return _build_lib("syscount", (_SRC,), loader=lambda p: p,
+                      extra_flags=_FLAGS)
 
 
 def _load():
@@ -45,7 +44,7 @@ def _load():
     if "libpushcdn_syscount" not in preload:
         return None
     try:
-        lib = ctypes.CDLL(str(_LIB_PATH))
+        lib = ctypes.CDLL(lib_path("syscount", (_SRC,), _FLAGS))
         lib.pcu_syscount.restype = ctypes.c_ulonglong
         lib.pcu_syscount.argtypes = [ctypes.c_int]
         lib.pcu_syscount_n.restype = ctypes.c_int

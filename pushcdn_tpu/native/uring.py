@@ -20,10 +20,9 @@ import os
 import threading
 from typing import Optional
 
-from pushcdn_tpu.native import _build_lib, _BUILD_DIR, _REPO
+from pushcdn_tpu.native import _REPO, _build_lib
 
 _SRC = os.path.join(_REPO, "native", "io_uring.cpp")
-_LIB_PATH = os.path.join(_BUILD_DIR, "libpushcdn_uring.so")
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
@@ -42,7 +41,7 @@ _u32p = ctypes.POINTER(ctypes.c_uint)
 
 
 def _compile() -> Optional[ctypes.CDLL]:
-    lib = _build_lib(_SRC, _LIB_PATH, ctypes.CDLL)
+    lib = _build_lib("uring", (_SRC,), ctypes.CDLL)
     if lib is None:
         return None
     P = ctypes.c_void_p

@@ -18,7 +18,9 @@ broadcast into each tile, so HBM traffic is O(U + N), not O(U×N).
 
 Off-TPU the kernel runs in interpreter mode; the pure-jnp reference
 implementation is exported for equivalence tests and as the XLA-fusion
-baseline the kernel must beat.
+baseline the kernel must beat. The output is ``bool``: Pallas carries it
+as int32 across the kernel boundary (Mosaic has no 1-bit memrefs) and
+converts after the call, so the (8, 128) int32 tiling below holds for it.
 """
 
 from __future__ import annotations
@@ -115,21 +117,32 @@ def delivery_matrix_pallas(user_masks: jax.Array, local: jax.Array,
     )
 
 
+def selects_pallas(U: int, N: int, use_pallas: bool | None = None) -> bool:
+    """The dispatch rule of :func:`delivery_matrix`, readable from outside
+    (the device plane logs it at start): Pallas on a real TPU — or when
+    forced — and only where the shapes tile (``U`` by ``TILE_U``, ``N`` by
+    ``TILE_N``). The served planes' wide lane (N=64) and latency slice
+    (N=8) do not, so they take the XLA-fused jnp reference by this rule
+    on every backend."""
+    if use_pallas is None:
+        use_pallas = jax.default_backend() == "tpu"
+    return bool(use_pallas) and U % TILE_U == 0 and N % TILE_N == 0
+
+
 def delivery_matrix(user_masks, local, frame_tmask, kind, dest,
                     use_pallas: bool | None = None,
                     interpret: bool | None = None) -> jax.Array:
-    """Dispatch: Pallas on real TPU, jnp reference everywhere else (the
-    Pallas CPU interpreter walks the grid tile-by-tile in Python — ~9x
-    slower than the fused XLA reference on an 8-shard CPU mesh step — so
-    auto mode only picks the kernel where it actually wins; pass
-    ``use_pallas=True`` explicitly to test interpreter equivalence)."""
-    backend = jax.default_backend()
-    if use_pallas is None:
-        use_pallas = backend == "tpu"
+    """Dispatch by :func:`selects_pallas`: the Pallas kernel compiled by
+    Mosaic on a real TPU, the jnp reference everywhere else (the Pallas
+    CPU interpreter walks the grid tile-by-tile in Python — ~9x slower
+    than the fused XLA reference on an 8-shard CPU mesh step — so auto
+    mode only picks the kernel where it compiles; pass ``use_pallas=True``
+    explicitly to test interpreter equivalence). On a TPU the kernel is
+    never interpreted, and a kernel that fails to compile raises: there
+    is no retry on the jnp twin."""
     if interpret is None:
-        interpret = backend != "tpu"
-    U, N = user_masks.shape[0], frame_tmask.shape[0]
-    if use_pallas and U % TILE_U == 0 and N % TILE_N == 0:
+        interpret = jax.default_backend() != "tpu"
+    if selects_pallas(user_masks.shape[0], frame_tmask.shape[0], use_pallas):
         return delivery_matrix_pallas(user_masks, local, frame_tmask,
                                       kind, dest, interpret=interpret)
     return delivery_matrix_reference(user_masks, local, frame_tmask,

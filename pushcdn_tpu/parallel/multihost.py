@@ -40,19 +40,6 @@ from jax.sharding import Mesh
 from pushcdn_tpu.parallel.mesh import make_broker_mesh
 
 
-def _distributed_initialized() -> bool:
-    """``jax.distributed.is_initialized`` appeared after 0.4.37; older
-    images expose the same fact via the private global client handle."""
-    checker = getattr(jax.distributed, "is_initialized", None)
-    if checker is not None:
-        return bool(checker())
-    try:
-        from jax._src import distributed as _dist
-        return _dist.global_state.client is not None
-    except Exception:
-        return False
-
-
 def initialize(coordinator_address: Optional[str] = None,
                num_processes: Optional[int] = None,
                process_id: Optional[int] = None) -> None:
@@ -60,7 +47,7 @@ def initialize(coordinator_address: Optional[str] = None,
     auto-detected; elsewhere pass the coordinator's ``host:port``, the
     process count, and this process's rank — the same contract as the
     reference's discovery endpoint + broker identity pair."""
-    if _distributed_initialized():
+    if jax.distributed.is_initialized():
         return  # idempotent: already joined (explicit or auto)
     kwargs = {}
     if coordinator_address is not None:

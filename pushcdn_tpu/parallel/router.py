@@ -36,7 +36,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from pushcdn_tpu.parallel.jax_compat import shard_map as _shard_map_compat
 from pushcdn_tpu.parallel.crdt import (
     ABSENT,
     CrdtState,
@@ -109,14 +108,9 @@ def count_collectives(lowered_text: str) -> int:
     ``jit(step).lower(*args).as_text()`` (StableHLO — one textual op per
     collective); compiled HLO can split one collective into start/done
     pairs and is not a supported input."""
-    ops = ("stablehlo.all_gather", "stablehlo.all_to_all",
-           "stablehlo.all_reduce", "stablehlo.collective_permute")
-    if any(op in lowered_text for op in ops):
-        return sum(lowered_text.count(op) for op in ops)
-    # pre-stablehlo (mhlo) spelling, same one-op-per-collective property
-    return sum(lowered_text.count(op) for op in
-               ("mhlo.all_gather", "mhlo.all_to_all", "mhlo.all_reduce",
-                "mhlo.collective_permute"))
+    return sum(lowered_text.count(op) for op in (
+        "stablehlo.all_gather", "stablehlo.all_to_all",
+        "stablehlo.all_reduce", "stablehlo.collective_permute"))
 
 
 class RouterState(NamedTuple):
@@ -666,11 +660,11 @@ def make_mesh_lane_step(mesh: Mesh, gather_bytes: bool = True,
                                     fused=fused)
         return jax.tree.map(lambda x: x[None], result)
 
-    sharded = _shard_map_compat(
+    sharded = jax.shard_map(
         per_shard, mesh=mesh,
         in_specs=(P(BROKER_AXIS), P(BROKER_AXIS), P(BROKER_AXIS),
                   P(BROKER_AXIS)),
-        out_specs=P(BROKER_AXIS))
+        out_specs=P(BROKER_AXIS), check_vma=False)
 
     @jax.jit
     def step(state, batches, directs, liveness=None):
@@ -705,10 +699,10 @@ def make_mesh_routing_step(mesh: Mesh, with_direct: bool = False):
         return jax.tree.map(lambda x: x[None], tuple(result))
 
     n_in = 3 if with_direct else 2
-    sharded = _shard_map_compat(
+    sharded = jax.shard_map(
         per_shard, mesh=mesh,
         in_specs=tuple(P(BROKER_AXIS) for _ in range(n_in)),
-        out_specs=P(BROKER_AXIS))
+        out_specs=P(BROKER_AXIS), check_vma=False)
 
     def _unpack(out):
         return RouteResult(

@@ -60,6 +60,25 @@ async def _locked_retry(op, what: str):
             raise
         bail(ErrorKind.CONNECTION, f"discovery store busy: {what}", exc)
 
+def _enter_wal(db: sqlite3.Connection) -> None:
+    """Switch the file to WAL journaling. The switch needs an exclusive
+    lock and sqlite does NOT run the busy handler for it: when a marshal
+    and a broker open a fresh store at the same moment (any multi-core
+    host), the loser gets 'database is locked' at once, whatever
+    busy_timeout says. Retry for as long as busy_timeout would have
+    waited; once any opener has succeeded the mode is persistent and the
+    pragma is a read."""
+    deadline = time.monotonic() + BUSY_TIMEOUT_MS / 1000.0
+    while True:
+        try:
+            db.execute("PRAGMA journal_mode=WAL")
+            return
+        except sqlite3.OperationalError as exc:
+            if not _is_locked(exc) or time.monotonic() >= deadline:
+                raise
+        time.sleep(0.01)
+
+
 _SCHEMA = """
 CREATE TABLE IF NOT EXISTS brokers (
     identifier TEXT PRIMARY KEY,
@@ -100,8 +119,8 @@ class Embedded(DiscoveryClient):
         # second process then hits 'database is locked' past busy_timeout)
         self._db = sqlite3.connect(path, check_same_thread=False,
                                    isolation_level=None)
-        self._db.execute("PRAGMA journal_mode=WAL")
         self._db.execute(f"PRAGMA busy_timeout={int(BUSY_TIMEOUT_MS)}")
+        _enter_wal(self._db)
         # Permits/heartbeats are ephemeral (30-60 s TTLs): losing the tail
         # of the WAL on power loss only forces reconnects, so skip the
         # per-commit fsync — it was most of the auth handshake's floor

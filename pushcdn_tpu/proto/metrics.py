@@ -675,8 +675,8 @@ TASK_SAMPLES = Counter(
 
 # Build/runtime identity: one constant-1 series whose labels carry the
 # package version, jax version, and the ACTUAL backend/device kind —
-# so "ALIVE but device_kind=cpu" (TPU_PROBES r5/r6) is visible on every
-# scrape instead of buried in a probes file.
+# so a process alive on a CPU it was not meant to run on is visible on
+# every scrape (the chip smoke asserts backend and device_kind here).
 BUILD_INFO = Gauge("cdn_build_info",
                    "Build/runtime identity (value is always 1)",
                    labels=("version", "jax", "backend", "device_kind"))

@@ -420,8 +420,8 @@ class Connection:
     # -- actor loops --------------------------------------------------------
 
     # Batch small frames into one buffer per flush: per-frame event-loop +
-    # syscall overhead dominates ≤1 KB frames otherwise (BASELINE.md soft
-    # spot). Each flush unit stays under this size so the per-flush 5 s
+    # syscall overhead dominates ≤1 KB frames otherwise. Each flush unit
+    # stays under this size so the per-flush 5 s
     # timeout keeps the same granularity the old per-frame timeout had;
     # frames above the limit are written directly, no extra copy.
     _BATCH_COALESCE_LIMIT = 64 * 1024

@@ -148,6 +148,9 @@ def _soak_snapshot(packs: List[SoakClient]) -> dict:
         "rehomed": sum(1 for p in packs if p.client.rehome_ms),
         "delivered": sum(p.delivered for p in packs),
         "unique": sum(p.unique for p in packs),
+        # per subscriber, in client order: a harness that knows the
+        # subscription table checks each count, not just the sum
+        "unique_by_client": [p.unique for p in packs],
         "gaps": sum(p.gaps for p in packs),
         "reorders": sum(p.reorders for p in packs),
         "hard_reconnects": sum(p.hard_reconnects for p in packs),

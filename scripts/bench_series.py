@@ -26,9 +26,6 @@ the per-section provenance host fingerprint (platform + cpu count,
 recorded since r13) and WAIVES — loudly, not silently — any comparison
 whose baseline ran on a different host or predates provenance. The next
 round on the same host re-engages the gate against the fresh baseline.
-
-Legacy rounds (r01–r05 predate sections) are folded in as a ``legacy``
-section from their single parsed metric line.
 """
 
 from __future__ import annotations
@@ -63,10 +60,6 @@ def direction(metric: str) -> int:
     return 0
 
 
-def _slug(text: str) -> str:
-    return re.sub(r"_+", "_", re.sub(r"\W", "_", text)).strip("_")
-
-
 def load_rounds(root: str) -> dict:
     """{round: {section: {metric: value}}} from every BENCH_r*.json."""
     rounds = {}
@@ -83,21 +76,15 @@ def load_rounds(root: str) -> dict:
                   file=sys.stderr)
             continue
         sections = {}
-        if "round" in doc:                       # modern: per-section headline
-            for name, body in doc.items():
-                if name == "round" or not isinstance(body, dict):
-                    continue
-                headline = body.get("headline") or {}
-                metrics = {k: v for k, v in headline.items()
-                           if isinstance(v, (int, float))
-                           and not isinstance(v, bool)}
-                if metrics:
-                    sections[name] = metrics
-        else:                                    # legacy r01–r05 schema
-            parsed = doc.get("parsed") or {}
-            metric, value = parsed.get("metric"), parsed.get("value")
-            if metric and isinstance(value, (int, float)):
-                sections["legacy"] = {_slug(metric): value}
+        for name, body in doc.items():           # per-section headline
+            if name == "round" or not isinstance(body, dict):
+                continue
+            headline = body.get("headline") or {}
+            metrics = {k: v for k, v in headline.items()
+                       if isinstance(v, (int, float))
+                       and not isinstance(v, bool)}
+            if metrics:
+                sections[name] = metrics
         if sections:
             rounds[rnd] = sections
     return rounds
@@ -117,7 +104,8 @@ def _fingerprint(prov) -> "tuple | None":
 
 def load_fingerprints(root: str) -> dict:
     """{round: {section: fingerprint-or-None}} — the per-section host
-    identity alongside :func:`load_rounds` (legacy rounds get None)."""
+    identity alongside :func:`load_rounds` (rounds without provenance
+    get None)."""
     fps = {}
     for path in sorted(glob.glob(os.path.join(root, "BENCH_r*.json"))):
         m = ROUND_RE.search(path)

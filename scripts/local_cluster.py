@@ -1239,8 +1239,12 @@ def main() -> int:
                     help="0 picks a free contiguous range (CI runs that "
                          "must not collide with other suites)")
     ap.add_argument("--device-plane", action="store_true",
-                    help="brokers route eligible traffic on the attached "
-                         "device (single-shard planes)")
+                    help="broker0 routes eligible traffic on the attached "
+                         "device (single-shard plane). broker1 stays a "
+                         "host broker across the TCP mesh link: a chip "
+                         "belongs to one process at a time, so a second "
+                         "--device-plane broker would fail or hang at "
+                         "start")
     ap.add_argument("--topology", action="store_true",
                     help="render one merged cluster view from every "
                          "broker's /debug/topology once the mesh is up")
@@ -1442,7 +1446,8 @@ def main() -> int:
             "--metrics-bind-endpoint",
             f"127.0.0.1:{metrics_ports[f'broker{i}']}",
             *shard_flags, *chaos_flags, *audit_flags,
-            *(["--device-plane"] if args.device_plane else []),
+            # one chip-owning process per chip: the plane goes to broker0
+            *(["--device-plane"] if args.device_plane and i == 0 else []),
             env_extra=env,
             log_path=os.path.join(logdir, f"broker{i}.log"))
 

@@ -50,15 +50,6 @@ def test_merge_and_markdown(tmp_path):
     assert "`plan_p99_ms`" in md
 
 
-def test_legacy_schema_folds_in(tmp_path):
-    (tmp_path / "BENCH_r1.json").write_text(json.dumps(
-        {"n": 1, "cmd": "bench.py", "rc": 0, "tail": "",
-         "parsed": {"metric": "broadcast msgs/sec/chip",
-                    "value": 42.0, "unit": "msgs/s"}}))
-    rounds = bench_series.load_rounds(str(tmp_path))
-    assert rounds == {1: {"legacy": {"broadcast_msgs_sec_chip": 42.0}}}
-
-
 def test_gate_flags_regression_only(tmp_path):
     # throughput -15% and latency +50%: both the wrong way
     _round(tmp_path / "BENCH_r1.json", 1, "route",

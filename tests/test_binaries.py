@@ -8,24 +8,11 @@ this is the always-on pytest slice of it.)"""
 
 import asyncio
 import os
-import socket
 import subprocess
 import sys
 import urllib.request
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def _free_ports(n: int) -> list:
-    socks, ports = [], []
-    for _ in range(n):
-        s = socket.socket()
-        s.bind(("127.0.0.1", 0))
-        socks.append(s)
-        ports.append(s.getsockname()[1])
-    for s in socks:
-        s.close()
-    return ports
 
 
 def _spawn(name: str, *args: str) -> subprocess.Popen:
@@ -37,7 +24,8 @@ def _spawn(name: str, *args: str) -> subprocess.Popen:
 
 async def test_broker_binary_device_plane_end_to_end(tmp_path):
     db = str(tmp_path / "cdn.sqlite")
-    pub, priv, metrics, marshal_p = _free_ports(4)
+    from pushcdn_tpu.bin.common import free_ports
+    pub, priv, metrics, marshal_p = free_ports(4)
     procs = []
     try:
         procs.append(_spawn(

@@ -184,6 +184,44 @@ async def test_embedded_lock_exhaustion_is_typed(tmp_path, monkeypatch):
         await disc.close()
 
 
+_CONCURRENT_OPEN = """
+import sys, time
+from pushcdn_tpu.proto.discovery.embedded import Embedded
+path, start = sys.argv[1], float(sys.argv[2])
+while time.time() < start:
+    pass
+Embedded(path, None)
+"""
+
+
+def test_embedded_concurrent_first_open_across_processes(tmp_path):
+    """A marshal and a broker opening one fresh store at the same moment
+    (any multi-core host) both run the journal-mode switch, which sqlite
+    fails with 'database is locked' WITHOUT consulting busy_timeout —
+    the race behind the red test_two_process_kill_and_redeploy. More
+    openers than cores, released together, several fresh files."""
+    import os
+    import subprocess
+    import sys
+    import time
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = {**os.environ, "PYTHONPATH": repo}
+    openers = (os.cpu_count() or 2) + 2
+    for rnd in range(3):
+        path = str(tmp_path / f"d{rnd}.sqlite")
+        start = time.time() + 1.0  # past every child's interpreter start
+        procs = [subprocess.Popen(
+            [sys.executable, "-c", _CONCURRENT_OPEN, path, str(start)],
+            env=env, stderr=subprocess.PIPE, text=True)
+            for _ in range(openers)]
+        errors = []
+        for p in procs:
+            _, err = p.communicate(timeout=60)
+            if p.returncode != 0:
+                errors.append(err.strip().splitlines()[-1])
+        assert not errors, errors
+
+
 async def test_deregister_removes_broker_row(tmp_path):
     """Drain step 1: a deregistered broker leaves placement immediately
     and idempotently (every shard worker calls it)."""

@@ -32,7 +32,8 @@ def _ruff_cmd():
 # proto/health.py, scripts/trace_report.py, tests/test_health.py,
 # tests/test_trace_report.py) lives inside them and is asserted present
 # below so a future move out of the linted tree fails loudly
-RUFF_SCOPE = ["pushcdn_tpu", "tests", "benches", "scripts", "bench.py"]
+RUFF_SCOPE = ["pushcdn_tpu", "tests", "benches", "scripts", "bench.py",
+              "chip_smoke.py"]
 
 ISSUE5_FILES = [
     "pushcdn_tpu/proto/health.py",

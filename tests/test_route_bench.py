@@ -4,7 +4,7 @@ tested-artifact treatment ``tests/test_local_cluster.py`` gives the
 deploy recipe. Asserts the JSON rows parse, both implementations emit a
 plan-tier row, and the end-to-end forward tier routed real traffic.
 
-The ≥2x acceptance ratio is a BENCH number (recorded in BASELINE.md), not
+The ≥2x acceptance ratio is a BENCH number (recorded in BENCH_rNN.json), not
 a CI gate: shared-core CI machines throttle unpredictably, and a perf
 assertion here would flake. What IS asserted: the native tier ran (when
 the kernel compiles here) and produced a sane positive rate.
@@ -60,7 +60,7 @@ def test_route_bench_smoke(tmp_path):
                    by_bench.get("route/ratio", [])), rows
     # ISSUE 4: the trace-overhead A/B rows (tracing off vs on at the
     # default 1/1024 sampling) must be present and positive — the ≤2%
-    # budget itself is a BENCH number (BASELINE.md), not a CI gate
+    # budget itself is a BENCH number (BENCH_rNN.json), not a CI gate
     assert "route/trace_overhead" in by_bench, rows
     tr_rows = {r.get("trace"): r for r in by_bench["route/trace_overhead"]
                if r["unit"] == "msgs/s"}
@@ -84,7 +84,7 @@ def test_route_bench_smoke(tmp_path):
         assert {"p50", "p99"} <= e2e_tiers, rows
     # ISSUE 7: the sustained-churn A/B (incremental deltas vs the
     # rebuild-guard baseline) and the synthetic 1M-subscription harness.
-    # The ≥2x ratio is a BENCH number (BASELINE.md), not a CI gate —
+    # The ≥2x ratio is a BENCH number (BENCH_rNN.json), not a CI gate —
     # asserted here: both modes ran, the incremental mode actually
     # applied deltas in place, the baseline actually rebuilt, and the
     # harness stayed inside its memory ceiling with the loop-lag check
@@ -128,7 +128,7 @@ def test_route_bench_smoke(tmp_path):
     # ISSUE 8: the device data plane rows — dense-vs-ragged A/B on the
     # CPU twin (uniform AND zipf popularity, honestly labeled) and the
     # one-collective fused mesh tick (dryrun). The ragged-ahead-at-skew
-    # figure is a BENCH number (BASELINE.md); asserted here: both impls
+    # figure is a BENCH number (BENCH_rNN.json); asserted here: both impls
     # ran per popularity (or a labeled skip), labels are honest, and the
     # fused tick counted EXACTLY one collective.
     assert "device/delivery" in by_bench, rows
