@@ -1,0 +1,363 @@
+"""The benchmark's own arithmetic (ISSUE 22): the log-bucket histogram,
+the (publisher, stream) gap detector, the plain reference router, the
+traffic plan, the trace reduction and the roofline's byte function. All
+on the CPU, none touches a device; nothing here imports the TPU library
+at module import."""
+
+import os
+import random
+import sys
+
+import numpy as np
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+if REPO not in sys.path:
+    sys.path.insert(0, REPO)
+
+from benchmark import peaks, reference, trace_reduce  # noqa: E402
+from benchmark.loadgen import plan  # noqa: E402
+from benchmark.loadgen.gaps import GapDetector, StreamState  # noqa: E402
+from benchmark.loadgen.hist import LogHistogram  # noqa: E402
+
+FIXTURE = os.path.join(REPO, "benchmark", "fixtures",
+                       "global_steady_3s.xplane.pb")
+
+
+# ---- histogram ------------------------------------------------------------
+
+@pytest.mark.parametrize("seed,shape", [
+    (1, "lognormal"), (2, "uniform"), (3, "bimodal"), (4, "heavy-tail")])
+def test_histogram_percentiles_match_numpy(seed, shape):
+    rng = np.random.default_rng(seed)
+    n = 20_000
+    if shape == "lognormal":
+        ns = rng.lognormal(13.0, 1.0, n)
+    elif shape == "uniform":
+        ns = rng.uniform(2e5, 4e7, n)
+    elif shape == "bimodal":
+        ns = np.concatenate([rng.normal(5e5, 2e4, n // 2),
+                             rng.normal(2e7, 1e6, n // 2)])
+    else:
+        ns = 3e5 * (1.0 + rng.pareto(1.5, n))
+    ns = np.maximum(ns, 1.0).astype(np.int64)
+    hist = LogHistogram()
+    for v in ns:
+        hist.add(int(v))
+    assert hist.n == n
+    for q in (50, 90, 99):
+        # buckets are 1 % wide: the geometric middle is within 0.5 % of
+        # any sample in the bucket, and the sample's own percentile moves
+        # by less than that between neighbouring ranks at these sizes
+        assert hist.percentile(q) == pytest.approx(
+            np.percentile(ns, q, method="lower"), rel=0.011), (shape, q)
+
+
+def test_histogram_merge_is_addition():
+    a, b, both = LogHistogram(), LogHistogram(), LogHistogram()
+    rng = random.Random(7)
+    for i in range(5000):
+        v = int(rng.lognormvariate(12, 1.5)) + 1
+        (a if i % 2 else b).add(v)
+        both.add(v)
+    a.merge(LogHistogram(b.counts))  # as the parent does, from a list
+    assert a.counts == both.counts and a.n == both.n == 5000
+    assert LogHistogram().percentile(50) is None
+
+
+# ---- gap detector ---------------------------------------------------------
+
+@pytest.mark.parametrize("seqs,want", [
+    # in order
+    ([0, 1, 2, 3], dict(unique=4, hi=4, holes=0, reorders=0, dups=0)),
+    # a residual gap: 2 never comes
+    ([0, 1, 3, 4], dict(unique=4, hi=5, holes=1, reorders=0, dups=0)),
+    # a stream that opens late has holes from 0 (nothing anchors)
+    ([3, 4], dict(unique=2, hi=5, holes=3, reorders=0, dups=0)),
+    # a reorder heals the hole but is counted
+    ([0, 2, 1, 3], dict(unique=4, hi=4, holes=0, reorders=1, dups=0)),
+    # duplicates: of the newest, of an old one, of a healed one
+    ([0, 1, 1, 0, 3, 2, 2], dict(unique=4, hi=4, holes=0, reorders=1, dups=3)),
+])
+def test_stream_state_hand_made(seqs, want):
+    st = StreamState()
+    flags = [st.observe(s) for s in seqs]
+    assert st.report() == [want["unique"], want["hi"], want["holes"],
+                           want["reorders"], want["dups"]]
+    assert sum(flags) == want["unique"]
+
+
+def test_gap_detector_keys_by_publisher_and_stream():
+    det = GapDetector()
+    # two publishers on one topic, interleaved: the library's detector
+    # (keyed by topic alone) would call every frame a gap or a duplicate
+    for pub, seq in [(0, 0), (1, 0), (0, 1), (1, 1), (1, 2), (0, 2)]:
+        assert det.observe(pub, 7, seq)
+    assert det.observe(0, plan.STREAM_DIRECT, 0)
+    assert not det.observe(0, 7, 2)  # a duplicate
+    assert det.report() == {"0.7": [3, 3, 0, 0, 1], "1.7": [3, 3, 0, 0, 0],
+                            f"0.{plan.STREAM_DIRECT}": [1, 1, 0, 0, 0]}
+
+
+# ---- reference ------------------------------------------------------------
+
+def test_reference_against_a_hand_worked_table():
+    # users: 0 on {0}, 1 on {0, 1} (multi-topic), 2 on {1}, 3 on nothing
+    table = [{0}, {0, 1}, {1}, set()]
+    B, D, P = plan.BROADCAST, plan.DIRECT, plan.PROBE
+    log = [(0, B, 0), (0, B, 0), (0, B, 1),   # publisher 0
+           (1, B, 1), (1, B, 5),              # topic 5 has no subscriber
+           (1, D, 3), (1, D, 3), (0, D, 1),   # directs
+           (0, P, 0)]                         # a probe to user 0
+    owed = reference.route(table, log)
+    direct, probe = plan.STREAM_DIRECT, plan.STREAM_PROBE
+    assert owed == [
+        {(0, 0): 2, (0, probe): 1},
+        {(0, 0): 2, (0, 1): 1, (1, 1): 1, (0, direct): 1},
+        {(0, 1): 1, (1, 1): 1},
+        {(1, direct): 2},
+    ]
+    assert reference.total(owed) == 12
+
+    def rep(user):
+        return {f"{p}.{s}": [n, n, 0, 0, 0] for (p, s), n in owed[user].items()}
+    good = [rep(u) for u in range(4)]
+    assert reference.compare(owed, good) == []
+    # a duplicate is legal
+    good[0]["0.0"][4] = 3
+    assert reference.compare(owed, good) == []
+    # one lost, one reordered, one delivered to a user who is owed nothing
+    bad = [rep(u) for u in range(4)]
+    bad[1]["0.0"] = [1, 2, 1, 0, 0]
+    bad[2]["1.1"] = [1, 1, 0, 1, 0]
+    bad[3]["0.1"] = [1, 1, 0, 0, 0]
+    problems = reference.compare(owed, bad)
+    assert len(problems) == 3
+    assert "user 1 stream (0, 0)" in problems[0]
+    assert "user 3 stream (0, 1): owed 0" in problems[2]
+
+
+# ---- the traffic plan -----------------------------------------------------
+
+FLOWS = [
+    {"name": "a", "publishers": 4,
+     "loop": {"kind": "windowed", "window": 8, "probe_every": 4,
+              "probe_bytes": 64},
+     "mix": [{"share": 0.5, "kind": "broadcast", "bytes": 1000,
+              "topic": {"uniform": 10}},
+             {"share": 0.5, "kind": "direct", "bytes": 256,
+              "to": {"group_offset": 1}}]},
+    {"name": "b", "publishers": 1,
+     "loop": {"kind": "echo", "think_s": 0.001},
+     "mix": [{"share": 1.0, "kind": "direct", "bytes": 10000, "to": "self"}]},
+]
+
+
+def test_layout_covers_every_user_once():
+    layout = plan.Layout(users=103, groups=4, sub_procs=3, pub_procs=2,
+                         flows=FLOWS)
+    # the cell's five publishers, spread evenly, then the harness's own:
+    # the last user, whose flow is the warm-up prelude
+    assert layout.publishers == 6
+    assert layout.pub_users == [0, 20, 41, 61, 82, 102]
+    assert [f["name"] for f in layout.flow_of_pub] == \
+        ["a"] * 4 + ["b", "prelude"]
+    seen = sorted(u for p in range(layout.procs)
+                  for u in layout.users_of_proc(p))
+    assert seen == list(range(103))
+    for g in range(4):
+        assert all(layout.group_of(u) == g for u in layout.group_users(g))
+    assert sum(len(layout.group_users(g)) for g in range(4)) == 103
+    # publishers are spread over the placement groups
+    assert {layout.group_of(u) for u in layout.pub_users} == {0, 1, 2, 3}
+
+
+def test_prelude_flow_is_the_harness_own():
+    """The warm-up prelude is small directs of the last user to itself,
+    the same in every cell; a traffic file cannot ask for its loop."""
+    from benchmark import manifest
+    layout = plan.Layout(users=16, groups=1, sub_procs=2, pub_procs=2,
+                         flows=FLOWS[1:])
+    assert layout.pub_users == [0, 15]
+    frames = plan.frame_plan(3, layout, layout.flow_of_pub[1], 1)
+    assert [next(frames) for _ in range(sum(plan.PRELUDE_BURSTS))] == \
+        [plan.Frame(plan.DIRECT, 15, 64)] * 20
+    assert plan.PRELUDE_FLOW["loop"]["kind"] not in manifest.LOOPS
+    with pytest.raises(ValueError, match="publishers"):
+        plan.Layout(users=1, groups=1, sub_procs=1, pub_procs=1,
+                    flows=FLOWS[1:])
+
+
+def test_frame_plan_is_a_function_of_the_seed():
+    layout = plan.Layout(users=100, groups=4, sub_procs=2, pub_procs=2,
+                         flows=FLOWS)
+
+    def head(seed, pub, n=200):
+        frames = plan.frame_plan(seed, layout, layout.flow_of_pub[pub], pub)
+        return [next(frames) for _ in range(n)]
+
+    assert head(5, 0) == head(5, 0)
+    assert head(5, 0) != head(6, 0) and head(5, 0) != head(5, 1)
+    frames = head(5, 1)
+    me = layout.pub_users[1]
+    # every 4th frame is a probe to the publisher itself
+    assert all((f.kind == plan.PROBE) == ((i + 1) % 4 == 0)
+               for i, f in enumerate(frames))
+    assert all(f.target == me for f in frames if f.kind == plan.PROBE)
+    # directs go to the next placement group, broadcasts stay in range
+    nxt = layout.group_users((layout.group_of(me) + 1) % 4)
+    assert all(f.target in nxt for f in frames if f.kind == plan.DIRECT)
+    assert all(0 <= f.target < 10 for f in frames
+               if f.kind == plan.BROADCAST)
+    kinds = [f.kind for f in frames if f.kind != plan.PROBE]
+    assert 0.3 < kinds.count(plan.BROADCAST) / len(kinds) < 0.7
+
+
+def test_payload_round_trip_and_subscriptions():
+    pool = plan.make_pool(9)
+    assert pool == plan.make_pool(9) != plan.make_pool(10)
+    frame = plan.Frame(plan.DIRECT, 17, 256)
+    body = plan.build_payload(pool, 3, frame, 41, 123456789)
+    assert len(body) == 256
+    assert plan.HEADER.unpack_from(body) == (
+        3, plan.STREAM_DIRECT, 41, 123456789, 17)
+    off = plan.filler_offset(3, plan.STREAM_DIRECT, 41,
+                             256 - plan.HEADER_BYTES)
+    assert body[plan.HEADER_BYTES:] == pool[off:off + 256 - plan.HEADER_BYTES]
+    table = plan.subscriptions(
+        [{"users": "all", "topic": {"mod": 3}},
+         {"users": [0, 2], "topic": {"fixed": 9}}], 5)
+    assert table == [{0, 9}, {1, 9}, {2}, {0}, {1}]
+    gaps = plan.arrival_gaps(1, 0, 100.0)
+    mean_ns = sum(next(gaps) for _ in range(20_000)) / 20_000
+    assert mean_ns == pytest.approx(1e7, rel=0.05)
+
+
+# ---- trace reduction ------------------------------------------------------
+
+def test_interval_arithmetic():
+    u = trace_reduce.union([(5, 7), (0, 2), (1, 3), (7, 8), (10, 11)])
+    assert u == [(0, 3), (5, 8), (10, 11)]
+    assert trace_reduce.covered(u) == 7
+    assert trace_reduce.overlap(u, [(2, 6), (7.5, 10.5)]) == 1 + 1 + 0.5 + 0.5
+
+
+def test_reduce_on_hand_made_events():
+    ms = 1e6
+    trace = {"devices": {
+        "/device:TPU:0": {
+            "ops": [("fusion.1", 0 * ms, 2 * ms), ("fusion.2", 1 * ms, 2 * ms),
+                    # named as on the TPU: by the whole HLO text
+                    ("%all-gather-start.3 = (u32[4,8]) all-gather-start("
+                     "u32[1,8] %fusion.2)", 2 * ms, 4 * ms),
+                    ("copy.9", 10 * ms, 1 * ms)],
+            "modules": [("jit_step(1)", 0, 6 * ms), ("jit_other(2)", 10 * ms, ms)]},
+        "/device:TPU:1": {
+            "ops": [("fusion.1", 0, 4 * ms)],
+            "modules": [("jit_step(1)", 0, 4 * ms)]}},
+        "host": {"python3/1": [("PjitFunction(step)", 6 * ms, 3.5 * ms)]}}
+    r = trace_reduce.reduce(trace, step_modules=["jit_step"],
+                            kernels=["fusion"])
+    assert r["devices"] == 2 and r["steps"] == 1
+    # device 0 is busy [0, 6) and [10, 11), device 1 [0, 4): mean of 7 and 4
+    assert r["busy_s"] == pytest.approx(5.5e-3)
+    assert r["step_device_s"] == pytest.approx((6e-3 + 4e-3) / 2)
+    # the collective runs [2, 6) on device 0; a fusion covers [2, 3) of it
+    assert r["collective_s"] == pytest.approx(4e-3 / 2)
+    assert r["collective_exposed_s"] == pytest.approx(3e-3 / 2)
+    assert r["kernels"]["fusion"] == {
+        "count": 3, "seconds": pytest.approx(8e-3),
+        "calls": {"fusion.1": [2, pytest.approx(6e-3)],
+                  "fusion.2": [1, pytest.approx(2e-3)]}}
+    assert r["device_ops"][0] == ["fusion", pytest.approx(4e-3)]
+    # one gap on device 0, [6, 10) ms, named after the host event in it
+    assert r["idle_gaps"] == [["python3: PjitFunction(step)",
+                               pytest.approx(4e-3)]]
+
+
+def test_reduce_the_recorded_trace():
+    """A trace recorded on the v5e (PR 22, `broker1-1k.global-steady`, the
+    three-second span of a traced run): the reduction finds the device
+    plane, its operations, the routing steps and the Pallas kernel by
+    name, as the benchmark does in every traced run."""
+    trace = trace_reduce.load(FIXTURE)
+    assert list(trace["devices"]) == ["/device:TPU:0"]
+    r = trace_reduce.reduce(
+        trace, step_modules=["jit_routing_step_lanes_single"],
+        kernels=["delivery_matrix_pallas"])
+    assert r["devices"] == 1 and r["steps"] == 15
+    assert r["busy_s"] == pytest.approx(2.425218e-3, rel=1e-6)
+    assert 0 < r["step_device_s"] <= r["busy_s"]
+    assert r["collective_s"] == 0.0
+    # 10 of the 15 steps ran the full lanes (the Pallas kernel at
+    # N=1024), 5 the latency slice, which takes the XLA twin
+    kernel = r["kernels"]["delivery_matrix_pallas"]
+    assert kernel["count"] == 10
+    assert 200e-6 < kernel["seconds"] / kernel["count"] < 260e-6
+    # one distinct call, whose HLO text carries the shapes
+    (hlo, (count, _seconds)), = kernel["calls"].items()
+    assert count == 10 and "s32[1024,1024]" in hlo and "u32[1024,8]" in hlo
+    assert r["device_ops"][0][0] == "delivery_matrix_pallas"
+    assert all(" = " not in name for name, _s in r["device_ops"])
+    assert len(r["idle_gaps"]) == 10
+    assert r["idle_gaps"][0][0] == "python3: np.asarray(jax.Array)"
+
+
+# ---- peaks and bytes ------------------------------------------------------
+
+@pytest.mark.parametrize("frames,want", [
+    # table 1024 x (8 words x 4 B + 4 B) = 36,864; per frame 8 x 4 + 8 = 40;
+    # the bool[1024, N] decision is one byte a cell
+    (1024, 36_864 + 1024 * 40 + 1024 * 1024),
+    (64, 36_864 + 64 * 40 + 1024 * 64),
+    (8, 36_864 + 8 * 40 + 1024 * 8),
+])
+def test_delivery_min_bytes_at_the_served_shapes(frames, want):
+    assert peaks.delivery_min_bytes(1024, frames, 8) == want
+
+
+def test_peaks_table_and_the_floor_at_the_served_shape():
+    # about 1.1 MiB for the full lane, 1.4 us at the v5e's peak
+    least = peaks.delivery_min_bytes(1024, 1024, 8)
+    assert least == 1_126_400
+    assert least / peaks.peak("TPU v5 lite", "hbm_bytes_per_s") == \
+        pytest.approx(1.375e-6, rel=0.001)
+    with pytest.raises(KeyError, match="no published"):
+        peaks.peak("TPU v9 imaginary", "hbm_bytes_per_s")
+
+
+def _roofline(calls, kind="TPU v5 lite"):
+    from types import SimpleNamespace
+    from benchmark import manifest
+    count = sum(c for c, _s in calls.values())
+    seconds = sum(s for _c, s in calls.values())
+    run = SimpleNamespace(
+        window=SimpleNamespace(trace={"kernels": {"k": {
+            "count": count, "seconds": seconds, "calls": calls}}}),
+        config={"kernels": {"delivery": "k"}}, device={"kind": kind})
+    return manifest.layer_metric(REPO, "delivery_kernel_roofline").read(run)
+
+
+def test_roofline_reads_the_kernel_shapes_off_the_trace():
+    """The kernel's shapes come from each call's HLO text in the trace, so
+    a program that changes its ring or user slots changes the floor with
+    it; a call that cannot be read is an error, not a guess."""
+    r = trace_reduce.reduce(trace_reduce.load(FIXTURE),
+                            kernels=["delivery_matrix_pallas"])
+    row = r["kernels"]["delivery_matrix_pallas"]
+    share = _roofline(row["calls"])
+    assert share == pytest.approx(
+        100 * 10 * 1_126_400 / 819e9 / row["seconds"])
+    assert 0.5 < share < 0.7  # 233 us a call against a floor of 1.4 us
+    hlo = next(iter(row["calls"]))
+    halved = hlo.replace("s32[1024,1024]", "s32[1024,512]")
+    mixed = {hlo: [1, 1e-4], halved: [1, 1e-4]}
+    assert _roofline(mixed) == pytest.approx(
+        100 * (1_126_400 + peaks.delivery_min_bytes(1024, 512, 8))
+        / 819e9 / 2e-4)
+    with pytest.raises(ValueError, match="cannot read"):
+        _roofline({"%k = s32[4] custom-call()": [1, 1e-4]})
+    with pytest.raises(KeyError, match="no published"):
+        _roofline(row["calls"], kind="TPU v9 imaginary")
+    assert _roofline({}) is None
