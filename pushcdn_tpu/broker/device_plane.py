@@ -60,6 +60,7 @@ from pushcdn_tpu.broker.pump_common import (
 )
 from pushcdn_tpu.broker.staging import StageResult
 from pushcdn_tpu.broker.tasks.senders import egress_delivery_rows
+from pushcdn_tpu.parallel import spans
 from pushcdn_tpu.parallel.crdt import ABSENT, CrdtState
 from pushcdn_tpu.parallel.frames import (
     TOPIC_WORDS_FULL,
@@ -206,6 +207,10 @@ class DevicePlane:
         self._step_inflight = False
         self.steps = 0
         self.frames_staged = 0      # frames accepted into a ring
+        # monotonic time at which the rings last went from empty to
+        # non-empty (None while empty): the age of the oldest staged
+        # frame at the next take is ``plane.take``'s ``ring_wait_us``
+        self._staged_since: Optional[float] = None
         self.messages_routed = 0
         self.warmup_s: Optional[float] = None
 
@@ -319,6 +324,8 @@ class DevicePlane:
             return StageResult.INELIGIBLE
         if ok:
             self.frames_staged += 1
+            if self._staged_since is None:
+                self._staged_since = time.monotonic()
             self._kick.set()
             return StageResult.STAGED
         return StageResult.FULL
@@ -377,6 +384,8 @@ class DevicePlane:
                 results[idx] = StageResult.FULL
         if staged:
             self.frames_staged += staged
+            if self._staged_since is None:
+                self._staged_since = time.monotonic()
             self._kick.set()
         return results
 
@@ -503,30 +512,37 @@ class DevicePlane:
                 # steady trickle: coalesce one window; bursts after idle
                 # (the latency regime) and saturated pipelines step now
                 await asyncio.sleep(wait)
-            if all(r.free_slots == r.slots for r in self.rings):
+            staged = sum(r.slots - r.free_slots for r in self.rings)
+            if not staged:
                 continue
             lat = c.latency_slots
             small = (all(r.slots - r.free_slots <= lat
                          for r in self.rings[:1])
                      and all(r.free_slots == r.slots
                              for r in self.rings[1:]))
-            # snapshot mirrors + all lane rings in ONE event-loop tick
-            batches_np = [r.take_batch() for r in self.rings]
-            if small:
-                batches_np = [slice_batch(batches_np[0], lat)]
-            u_eff = effective_users(self.slots.high_water,
-                                    c.num_user_slots)
-            owned = self._owned[:u_eff].copy()
-            masks = self._masks[:u_eff].copy()
-            rev = self._state_rev
-            # pack the ragged walk in the SAME event-loop tick as the
-            # snapshot (the page index is observer-mutated on the loop;
-            # pack copies the referenced pool prefix). Overflow demotes
-            # delivery_impl to "dense" (the index stays maintained for
-            # the rebuild-retry path), so gate on the impl, not the index
-            walks = self._pack_walks(batches_np) \
-                if self.delivery_impl == "ragged" else None
-            quarantined, self._quarantine = self._quarantine, []
+            step = self.steps
+            waited = time.monotonic() - self._staged_since
+            self._staged_since = None
+            with spans.span("plane.take", step=step, frames=staged,
+                            ring_wait_us=int(waited * 1e6)):
+                # snapshot mirrors + all lane rings in ONE event-loop tick
+                batches_np = [r.take_batch() for r in self.rings]
+                if small:
+                    batches_np = [slice_batch(batches_np[0], lat)]
+                u_eff = effective_users(self.slots.high_water,
+                                        c.num_user_slots)
+                owned = self._owned[:u_eff].copy()
+                masks = self._masks[:u_eff].copy()
+                rev = self._state_rev
+                # pack the ragged walk in the SAME event-loop tick as the
+                # snapshot (the page index is observer-mutated on the
+                # loop; pack copies the referenced pool prefix). Overflow
+                # demotes delivery_impl to "dense" (the index stays
+                # maintained for the rebuild-retry path), so gate on the
+                # impl, not the index
+                walks = self._pack_walks(batches_np) \
+                    if self.delivery_impl == "ragged" else None
+                quarantined, self._quarantine = self._quarantine, []
             try:
                 self._step_inflight = True
                 try:
@@ -536,12 +552,16 @@ class DevicePlane:
                 finally:
                     self._step_inflight = False
                 gate.stepped(loop.time())
-                for streams, d2, lengths, frames in jobs:
-                    if streams is not None:
-                        self.messages_routed += egress_streams(
-                            self.broker, self.slots, streams)
-                    else:
-                        self._egress(d2, lengths, frames)
+                with spans.span("plane.egress", step=step) as sp:
+                    routed = self.messages_routed
+                    for streams, d2, lengths, frames in jobs:
+                        if streams is not None:
+                            self.messages_routed += egress_streams(
+                                self.broker, self.slots, streams)
+                        else:
+                            self._egress(d2, lengths, frames)
+                    sp.set_metadata(
+                        deliveries=self.messages_routed - routed)
             except asyncio.CancelledError:
                 raise
             except Exception:
@@ -578,9 +598,12 @@ class DevicePlane:
         compact (frame, receiver-run) output feeds
         ``senders.egress_delivery_rows`` directly — no bool[U, N] comes
         back and Python never re-scans one. ``compile_only`` runs every
-        lane regardless of traffic (warmup) and returns no jobs."""
+        lane regardless of traffic (warmup), returns no jobs and emits no
+        profiler spans."""
         import jax.numpy as jnp
         from pushcdn_tpu import native as native_mod
+        span = spans.none if compile_only else spans.span
+        step = self.steps
 
         def build_state():
             return RouterState(
@@ -592,8 +615,6 @@ class DevicePlane:
                         np.where(owned, 0, ABSENT).astype(np.int32)),
                 ),
                 topic_masks=jnp.asarray(masks))
-
-        state = self._state_cache.get(state_rev, build_state)
 
         def stub(n):
             st = self._byte_stubs.get(n)
@@ -628,28 +649,36 @@ class DevicePlane:
                 routing_step_ragged_single
             jobs = []
             routed_ragged = False
+            with span("plane.h2d", step=step):
+                state = self._state_cache.get(state_rev, build_state)
             for li, (b, walk) in enumerate(zip(lane_batches, walks)):
                 if not busy[li] and not compile_only:
                     continue  # an idle lane has no walk entries
-                res = routing_step_ragged_single(
-                    state, to_dev(li, b, busy[li]),
-                    jnp.asarray(walk.pages), jnp.asarray(walk.walk_page),
-                    jnp.asarray(walk.walk_frame))
+                with span("plane.h2d", step=step):
+                    args = (to_dev(li, b, busy[li]),
+                            jnp.asarray(walk.pages),
+                            jnp.asarray(walk.walk_page),
+                            jnp.asarray(walk.walk_frame))
+                with span("plane.dispatch", step=step):
+                    res = routing_step_ragged_single(state, *args)
                 if compile_only:
                     res.counts.block_until_ready()
                     continue
                 routed_ragged = True
-                out_user = np.asarray(res.out_user)
-                if self.config.ragged_relaxed_order:
-                    # per-topic FIFO only (see the config knob's docs)
-                    users, frame_idx = ragged_pairs_grouped(
-                        out_user, walk,
-                        num_users=self.config.num_user_slots)
-                else:
-                    # strict: per-user order identical to the dense plane
-                    users, frame_idx = ragged_pairs(
-                        out_user, walk.walk_frame,
-                        num_users=self.config.num_user_slots)
+                with span("plane.d2h", step=step):
+                    out_user = np.asarray(res.out_user)
+                with span("plane.encode", step=step):
+                    if self.config.ragged_relaxed_order:
+                        # per-topic FIFO only (see the config knob's docs)
+                        users, frame_idx = ragged_pairs_grouped(
+                            out_user, walk,
+                            num_users=self.config.num_user_slots)
+                    else:
+                        # strict: per-user order identical to the dense
+                        # plane
+                        users, frame_idx = ragged_pairs(
+                            out_user, walk.walk_frame,
+                            num_users=self.config.num_user_slots)
                 if len(users):
                     jobs.append((None, (users, frame_idx), b.length,
                                  b.bytes_))
@@ -658,10 +687,13 @@ class DevicePlane:
                 self.ragged_steps += 1
             return jobs
 
-        batches = tuple(to_dev(li, b, busy[li])
-                        for li, b in enumerate(lane_batches))
-        result = routing_step_lanes_single(state, batches,
-                                           gather_bytes=False)
+        with span("plane.h2d", step=step):
+            state = self._state_cache.get(state_rev, build_state)
+            batches = tuple(to_dev(li, b, busy[li])
+                            for li, b in enumerate(lane_batches))
+        with span("plane.dispatch", step=step):
+            result = routing_step_lanes_single(state, batches,
+                                               gather_bytes=False)
         self.steps += 1
         if compile_only:
             # warm-up: a step that compiles but dies on the device must
@@ -673,11 +705,14 @@ class DevicePlane:
         for li, lane in enumerate(result.lanes):
             if not busy[li]:
                 continue  # an idle lane can't deliver: skip its D2H
-            deliver = np.asarray(lane.deliver)
-            if not deliver.any():
-                continue
+            with span("plane.d2h", step=step):
+                deliver = np.asarray(lane.deliver)
             b = lane_batches[li]
-            streams = native_mod.egress_encode(deliver, b.length, [b.bytes_])
+            with span("plane.encode", step=step):
+                if not deliver.any():
+                    continue
+                streams = native_mod.egress_encode(deliver, b.length,
+                                                   [b.bytes_])
             if streams is not None:
                 jobs.append((streams, None, None, None))
             else:

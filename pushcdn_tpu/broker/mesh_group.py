@@ -36,6 +36,7 @@ from __future__ import annotations
 
 import asyncio
 import logging
+import time
 from dataclasses import dataclass
 from typing import TYPE_CHECKING, Dict, List, Optional
 
@@ -51,6 +52,7 @@ from pushcdn_tpu.broker.tasks.senders import (
     egress_delivery_rows,
     egress_streams,
 )
+from pushcdn_tpu.parallel import spans
 from pushcdn_tpu.parallel.crdt import ABSENT, CrdtState
 from pushcdn_tpu.parallel.frames import (
     TOPIC_WORDS_FULL,
@@ -266,6 +268,9 @@ class MeshBrokerGroup:
         self._state_dirty = False  # forces a step with no staged traffic
         self.steps = 0
         self.frames_staged = 0  # frames accepted into a ring or bucket
+        # monotonic time at which rings and buckets last went from empty
+        # to non-empty (None while empty): ``plane.take``'s ring_wait_us
+        self._staged_since: Optional[float] = None
         self.messages_routed = 0
         # collectives traced by the most recently COMPILED step
         # specialization (router.trace_collectives delta around the call):
@@ -481,6 +486,8 @@ class MeshBrokerGroup:
             return StageResult.INELIGIBLE
         if ok:
             self.frames_staged += 1
+            if self._staged_since is None:
+                self._staged_since = time.monotonic()
             self._kick.set()
             return StageResult.STAGED
         return StageResult.FULL
@@ -550,6 +557,8 @@ class MeshBrokerGroup:
                 results[idx] = StageResult.FULL
         if staged:
             self.frames_staged += staged
+            if self._staged_since is None:
+                self._staged_since = time.monotonic()
             self._kick.set()
         return results
 
@@ -594,40 +603,52 @@ class MeshBrokerGroup:
                              for rings in self.lane_rings[1:] for r in rings)
                      and all(b.total_used == 0
                              for bkts in self.lane_buckets[1:] for b in bkts))
-            # one-tick snapshot: all lanes' rings + buckets + mirrors
-            batches = [[r.take_batch() for r in rings]
-                       for rings in self.lane_rings]
-            directs = [[b.take_batch() for b in bkts]
-                       for bkts in self.lane_buckets]
-            if small:
-                batches = [[slice_batch(b, lat) for b in batches[0]]]
-                directs = [[slice_direct_batch(d, lat) for d in directs[0]]]
-            # slice the user table to its high-water mark (rounded up so
-            # the jit key only moves every ``u_round`` users): delivery
-            # matrices, their D2H, and the egress scans all shrink with the
-            # actual population instead of paying for empty slots
-            u_eff = effective_users(self.slots.high_water,
-                                    self.config.num_user_slots)
-            owner = self._owner[:u_eff].copy()
-            versions = self._claim_version[:u_eff].copy()
-            masks = self._masks[:u_eff].copy()
-            liveness = self._liveness.copy()
-            rev = self._state_rev
-            quarantined, self._quarantine = self._quarantine, []
+            step = self.steps
+            waited = (0.0 if self._staged_since is None
+                      else time.monotonic() - self._staged_since)
+            self._staged_since = None
+            with spans.span("plane.take", step=step, frames=staged,
+                            ring_wait_us=int(waited * 1e6)):
+                # one-tick snapshot: all lanes' rings + buckets + mirrors
+                batches = [[r.take_batch() for r in rings]
+                           for rings in self.lane_rings]
+                directs = [[b.take_batch() for b in bkts]
+                           for bkts in self.lane_buckets]
+                if small:
+                    batches = [[slice_batch(b, lat) for b in batches[0]]]
+                    directs = [[slice_direct_batch(d, lat)
+                                for d in directs[0]]]
+                # slice the user table to its high-water mark (rounded up
+                # so the jit key only moves every ``u_round`` users):
+                # delivery matrices, their D2H, and the egress scans all
+                # shrink with the actual population instead of paying for
+                # empty slots
+                u_eff = effective_users(self.slots.high_water,
+                                        self.config.num_user_slots)
+                owner = self._owner[:u_eff].copy()
+                versions = self._claim_version[:u_eff].copy()
+                masks = self._masks[:u_eff].copy()
+                liveness = self._liveness.copy()
+                rev = self._state_rev
+                quarantined, self._quarantine = self._quarantine, []
             try:
                 egress_jobs = await asyncio.to_thread(
                     self._run_step, batches, directs, owner, versions, masks,
-                    liveness, rev)
+                    liveness, rev, step)
                 gate.stepped(loop.time())
-                for shard, streams, d2, lengths, frames in egress_jobs:
-                    broker = self.brokers[shard]
-                    if broker is None:
-                        continue
-                    if streams is not None:
-                        self.messages_routed += egress_streams(
-                            broker, self.slots, streams)
-                    else:
-                        self._egress_py(broker, d2, lengths, frames)
+                with spans.span("plane.egress", step=step) as sp:
+                    routed = self.messages_routed
+                    for shard, streams, d2, lengths, frames in egress_jobs:
+                        broker = self.brokers[shard]
+                        if broker is None:
+                            continue
+                        if streams is not None:
+                            self.messages_routed += egress_streams(
+                                broker, self.slots, streams)
+                        else:
+                            self._egress_py(broker, d2, lengths, frames)
+                    sp.set_metadata(
+                        deliveries=self.messages_routed - routed)
             except asyncio.CancelledError:
                 raise
             except Exception:
@@ -652,7 +673,7 @@ class MeshBrokerGroup:
                     self.slots.free_slot(slot)
 
     def _run_step(self, batches, directs, owner, versions, masks,
-                  liveness=None, state_rev=None):
+                  liveness=None, state_rev=None, step: Optional[int] = None):
         """Blocking multi-shard device step (worker thread). ``batches`` and
         ``directs`` are [lane][shard] host snapshots; busy lanes ride ONE
         jitted shard_map program with one shared CRDT merge. Lanes idle on
@@ -664,9 +685,12 @@ class MeshBrokerGroup:
         from the HOST snapshots when ``gather_frame_bytes`` is off — the
         step returns per-shard egress jobs, each either a native
         :class:`native.EgressStreams` (encoded right here, off the event
-        loop) or the Python-fallback (deliver, lengths, frames) triple."""
+        loop) or the Python-fallback (deliver, lengths, frames) triple.
+
+        ``step`` is the tick number the profiler spans carry; without one
+        (the compile-only warm-up) the step emits no spans."""
         import jax
-        from pushcdn_tpu import native as native_mod
+        span = spans.none if step is None else spans.span
         B = self.num_shards
         put = lambda a: jax.device_put(a, self._sharding)
         live = (np.ones(B, bool) if liveness is None else liveness)
@@ -685,7 +709,6 @@ class MeshBrokerGroup:
                 topic_masks=put(masks_b)),
                 put(np.broadcast_to(live, (B, B))))
 
-        state, live_dev = self._state_cache.get(state_rev, build_state)
         def put_rows(key, rows, busy_rows):
             """Assemble the [B, ...] byte tensor per device: busy shards
             H2D their own block; idle shards reuse a cached device-side
@@ -739,15 +762,21 @@ class MeshBrokerGroup:
 
         busy_b = [any(b.valid.any() for b in lane) for lane in batches]
         busy_d = [any(d.valid.any() for d in lane) for lane in directs]
-        lane_batches = tuple(
-            lane_to_dev(("b", li, lane[0].valid.shape[0]), lane, busy_b[li])
-            for li, lane in enumerate(batches))
-        lane_directs = tuple(
-            lane_to_dev(("d", li, lane[0].valid.shape[1]), lane, busy_d[li])
-            for li, lane in enumerate(directs))
+        with span("plane.h2d", step=step):
+            state, live_dev = self._state_cache.get(state_rev, build_state)
+            lane_batches = tuple(
+                lane_to_dev(("b", li, lane[0].valid.shape[0]), lane,
+                            busy_b[li])
+                for li, lane in enumerate(batches))
+            lane_directs = tuple(
+                lane_to_dev(("d", li, lane[0].valid.shape[1]), lane,
+                            busy_d[li])
+                for li, lane in enumerate(directs))
         from pushcdn_tpu.parallel import router as router_mod
         before = router_mod.trace_collectives()
-        result = self.step_fn(state, lane_batches, lane_directs, live_dev)
+        with span("plane.dispatch", step=step):
+            result = self.step_fn(state, lane_batches, lane_directs,
+                                  live_dev)
         traced = router_mod.trace_collectives() - before
         if traced:  # this call compiled a fresh specialization
             self.collectives_last_trace = traced
@@ -756,40 +785,50 @@ class MeshBrokerGroup:
             # nothing to read back (warm-up, membership-only tick): wait
             # for the step anyway, so a device failure raises here and
             # not at some later tick's readback
-            jax.block_until_ready(result.evictions)
+            with span("plane.d2h", step=step):
+                jax.block_until_ready(result.evictions)
         # ---- egress prep: decisions from the mesh, payloads from host ----
         # (idle lanes can't deliver: skip their D2H entirely)
         jobs = []
         for li, l in enumerate(result.lanes):
             if not busy_b[li]:
                 continue
-            deliver = np.asarray(l.deliver)          # bool[B, U, N]
-            if self.config.gather_frame_bytes:
-                lengths = np.asarray(l.gathered_length[0])
-                blocks = [np.asarray(l.gathered_bytes[0])]
-                per_shard = None
-            else:
-                lane = batches[li]
-                lengths = np.concatenate([b.length for b in lane])
-                blocks = [b.bytes_ for b in lane]
-                per_shard = None
-            jobs.append((deliver, lengths, blocks, per_shard))
+            with span("plane.d2h", step=step):
+                deliver = np.asarray(l.deliver)      # bool[B, U, N]
+                if self.config.gather_frame_bytes:
+                    lengths = np.asarray(l.gathered_length[0])
+                    blocks = [np.asarray(l.gathered_bytes[0])]
+                else:
+                    lane = batches[li]
+                    lengths = np.concatenate([b.length for b in lane])
+                    blocks = [b.bytes_ for b in lane]
+            jobs.append((deliver, lengths, blocks, None))
         for li, l in enumerate(result.direct_lanes):
             if not busy_d[li]:
                 continue
-            deliver = np.asarray(l.deliver)          # bool[B, U, B*C]
-            if self.config.gather_frame_bytes:
-                # all_to_all output DIFFERS per shard (unlike the broadcast
-                # all_gather): each shard's received bytes/lengths must pair
-                # with that shard's own delivery mask
-                lengths = np.asarray(l.gathered_length)   # [B, B*C]
-                blocks = np.asarray(l.gathered_bytes)     # [B, B*C, F]
-                jobs.append((deliver, lengths, blocks, "per-shard"))
-            else:
-                # the all_to_all transposes buckets: shard j receives, from
-                # each source shard, that source's bucket FOR j
-                lane = directs[li]
-                jobs.append((deliver, None, None, lane))
+            with span("plane.d2h", step=step):
+                deliver = np.asarray(l.deliver)      # bool[B, U, B*C]
+                if self.config.gather_frame_bytes:
+                    # all_to_all output DIFFERS per shard (unlike the
+                    # broadcast all_gather): each shard's received
+                    # bytes/lengths must pair with that shard's own
+                    # delivery mask
+                    lengths = np.asarray(l.gathered_length)  # [B, B*C]
+                    blocks = np.asarray(l.gathered_bytes)    # [B, B*C, F]
+                    jobs.append((deliver, lengths, blocks, "per-shard"))
+                else:
+                    # the all_to_all transposes buckets: shard j receives,
+                    # from each source shard, that source's bucket FOR j
+                    lane = directs[li]
+                    jobs.append((deliver, None, None, lane))
+        with span("plane.encode", step=step):
+            return self._encode_jobs(jobs)
+
+    def _encode_jobs(self, jobs) -> list:
+        """Per-shard egress jobs from one step's read-back decisions
+        (worker thread; the step's ``plane.encode`` span)."""
+        from pushcdn_tpu import native as native_mod
+        B = self.num_shards
         out = []
         for deliver, lengths, blocks, direct_lane in jobs:
             for shard in range(B):

@@ -30,6 +30,7 @@ import time
 from typing import TYPE_CHECKING, List, Optional, Sequence
 
 from pushcdn_tpu.broker.staging import StageResult
+from pushcdn_tpu.parallel.spans import span
 from pushcdn_tpu.proto import flowclass
 from pushcdn_tpu.proto import ledger as ledger_mod
 from pushcdn_tpu.proto import metrics as metrics_mod
@@ -275,15 +276,14 @@ class EgressBatch:
 def _emit_staged_trace(message) -> None:
     """Span emission for a traced message the DEVICE plane accepted: the
     frame rides the staging ring and the device egress verbatim (flag +
-    trace block intact — the receiver still emits ``delivery``), so the
-    broker-side hops collapse to the stage handoff: ``plan`` = the stage
-    decision, ``egress`` = handed to the device pump's egress (the pump
-    itself is a batched jitted step with no per-message seam)."""
+    trace block intact — the receiver still emits ``delivery``). Only
+    ``ingress`` has happened at staging time; the step that plans and
+    egresses the frame runs later, batched, with no per-message seam, so
+    the chain has no broker-side ``plan`` / ``egress`` hop: the step's
+    timeline is in the profiler spans (``parallel/spans.py``)."""
     tr = message.trace
     if tr is not None:
-        trace_mod.emit("ingress", tr, "device")
-        trace_mod.emit("plan", tr, "device-staged")
-        trace_mod.emit("egress", tr, "device-staged")
+        trace_mod.emit("ingress", tr, "device-staged")
 
 
 def _emit_scalar_trace(message, egress: EgressBatch, before: int) -> None:
@@ -575,115 +575,123 @@ async def user_receive_loop(broker: "Broker", public_key: bytes,
             stage_items: list = []
             device = broker.device_plane
             try:
-                for raw in raws:
-                    try:
-                        message = deserialize(raw.data)
-                    except Error:
-                        # malformed frame ⇒ disconnect
-                        # (user/handler.rs:106-118)
-                        logger.info(
-                            "user %s sent malformed frame; disconnecting",
-                            mnemonic(public_key))
-                        connection.flightrec.record("malformed-frame",
-                                                    abnormal=True)
-                        ledger_mod.record_fate("dropped", "malformed",
-                                               flowclass.CLASS_NONE)
-                        alive = False
-                        break
-                    ledger_mod.note_ingress(_ingress_class(message))
-                    result = hook(public_key, message)
-                    if result == HookResult.SKIP:
-                        continue
-                    if result == HookResult.DISCONNECT:
-                        alive = False
-                        break
-
-                    if isinstance(message, Direct):
-                        # device path covers local-recipient delivery (and,
-                        # for a mesh-group plane, any recipient in the
-                        # group); host path covers the rest
-                        if device is not None:
-                            stage_items.append((message, raw, None))
+                with span("ingress.scan", frames=len(raws)):
+                    for raw in raws:
+                        try:
+                            message = deserialize(raw.data)
+                        except Error:
+                            # malformed frame ⇒ disconnect
+                            # (user/handler.rs:106-118)
+                            logger.info(
+                                "user %s sent malformed frame; disconnecting",
+                                mnemonic(public_key))
+                            connection.flightrec.record("malformed-frame",
+                                                        abnormal=True)
+                            ledger_mod.record_fate("dropped", "malformed",
+                                                   flowclass.CLASS_NONE)
+                            alive = False
+                            break
+                        ledger_mod.note_ingress(_ingress_class(message))
+                        result = hook(public_key, message)
+                        if result == HookResult.SKIP:
                             continue
-                        a0 = egress.appended
-                        route_direct(broker, message.recipient, raw,
-                                     to_user_only=False, egress=egress)
-                        _emit_scalar_trace(message, egress, a0)
-                    elif isinstance(message, Broadcast):
-                        pruned, _bad = topics.prune(message.topics)
-                        if pruned:
-                            # durable topics (ISSUE 14): retention stamp in
-                            # the same synchronous block as the route
-                            # decision; a False return means the owning
-                            # shard fans out through its ordered drainer
-                            durable = broker.durable
-                            if durable is not None and not durable.on_publish(
-                                    pruned, message, raw,
-                                    to_users_only=False):
-                                continue
+                        if result == HookResult.DISCONNECT:
+                            alive = False
+                            break
+
+                        if isinstance(message, Direct):
+                            # device path covers local-recipient delivery (and,
+                            # for a mesh-group plane, any recipient in the
+                            # group); host path covers the rest
                             if device is not None:
-                                stage_items.append((message, raw, pruned))
+                                stage_items.append((message, raw, None))
                                 continue
                             a0 = egress.appended
-                            route_broadcast(
-                                broker, pruned, raw, to_users_only=False,
-                                egress=egress,
-                                interest_cache=interest_cache,
-                                raw_topics=message.topics)
+                            route_direct(broker, message.recipient, raw,
+                                         to_user_only=False, egress=egress)
                             _emit_scalar_trace(message, egress, a0)
-                    elif isinstance(message, Subscribe):
-                        pruned, bad = topics.prune(message.topics)
-                        if bad:
-                            # unknown topic ⇒ disconnect (subscribe.rs test
-                            # behavior: invalid-topic subscriptions kick)
-                            alive = False
-                            break
-                        adm = broker.admission
-                        if adm is not None and \
-                                not adm.allow_subscribe(connection):
-                            # over-rate: drop the mutation, notify typed
-                            # through the ordered egress path (ISSUE 7)
-                            adm.shed_subscribe(public_key, connection,
-                                               egress)
-                            continue
-                        broker.connections.subscribe_user_to(public_key,
-                                                             pruned)
-                    elif isinstance(message, Unsubscribe):
-                        adm = broker.admission
-                        if adm is not None and \
-                                not adm.allow_subscribe(connection):
-                            adm.shed_subscribe(public_key, connection,
-                                               egress)
-                            continue
-                        pruned, _bad = topics.prune(message.topics)
-                        broker.connections.unsubscribe_user_from(public_key,
+                        elif isinstance(message, Broadcast):
+                            pruned, _bad = topics.prune(message.topics)
+                            if pruned:
+                                # durable topics (ISSUE 14): retention stamp in
+                                # the same synchronous block as the route
+                                # decision; a False return means the owning
+                                # shard fans out through its ordered drainer
+                                durable = broker.durable
+                                if durable is not None and \
+                                        not durable.on_publish(
+                                            pruned, message, raw,
+                                            to_users_only=False):
+                                    continue
+                                if device is not None:
+                                    stage_items.append((message, raw, pruned))
+                                    continue
+                                a0 = egress.appended
+                                route_broadcast(
+                                    broker, pruned, raw, to_users_only=False,
+                                    egress=egress,
+                                    interest_cache=interest_cache,
+                                    raw_topics=message.topics)
+                                _emit_scalar_trace(message, egress, a0)
+                        elif isinstance(message, Subscribe):
+                            pruned, bad = topics.prune(message.topics)
+                            if bad:
+                                # unknown topic ⇒ disconnect (subscribe.rs
+                                # test behavior: invalid-topic
+                                # subscriptions kick)
+                                alive = False
+                                break
+                            adm = broker.admission
+                            if adm is not None and \
+                                    not adm.allow_subscribe(connection):
+                                # over-rate: drop the mutation, notify typed
+                                # through the ordered egress path (ISSUE 7)
+                                adm.shed_subscribe(public_key, connection,
+                                                   egress)
+                                continue
+                            broker.connections.subscribe_user_to(public_key,
                                                                  pruned)
-                    elif isinstance(message, SubscribeFrom):
-                        # durable replay subscribe (ISSUE 14): registration
-                        # + ring snapshot + replay enqueue in one
-                        # synchronous block (the handover invariant)
-                        adm = broker.admission
-                        if adm is not None and \
-                                not adm.allow_subscribe(connection):
-                            adm.shed_subscribe(public_key, connection,
-                                               egress)
-                            continue
-                        durable = broker.durable
-                        if durable is None or not durable.handle_subscribe_from(
-                                public_key, message, connection):
+                        elif isinstance(message, Unsubscribe):
+                            adm = broker.admission
+                            if adm is not None and \
+                                    not adm.allow_subscribe(connection):
+                                adm.shed_subscribe(public_key, connection,
+                                                   egress)
+                                continue
+                            pruned, _bad = topics.prune(message.topics)
+                            broker.connections.unsubscribe_user_from(
+                                public_key, pruned)
+                        elif isinstance(message, SubscribeFrom):
+                            # durable replay subscribe (ISSUE 14): registration
+                            # + ring snapshot + replay enqueue in one
+                            # synchronous block (the handover invariant)
+                            adm = broker.admission
+                            if adm is not None and \
+                                    not adm.allow_subscribe(connection):
+                                adm.shed_subscribe(public_key, connection,
+                                                   egress)
+                                continue
+                            durable = broker.durable
+                            if durable is None or \
+                                    not durable.handle_subscribe_from(
+                                        public_key, message, connection):
+                                alive = False
+                                break
+                        else:
+                            # users may not send auth or sync messages
+                            # post-handshake
                             alive = False
                             break
-                    else:
-                        # users may not send auth or sync messages
-                        # post-handshake
-                        alive = False
-                        break
 
                 # phase 2: batch-stage the collected device-eligible
                 # messages, then host-route whatever the device didn't take
                 if stage_items:
-                    results = device.stage_batch(
-                        [(m, r) for m, r, _ in stage_items])
+                    with span("ingress.stage",
+                              frames=len(stage_items)) as sp:
+                        results = device.stage_batch(
+                            [(m, r) for m, r, _ in stage_items])
+                        sp.set_metadata(
+                            staged=results.count(StageResult.STAGED))
                     for (message, raw, pruned), res in zip(stage_items,
                                                            results):
                         if res == StageResult.FULL:

@@ -3,7 +3,7 @@
 ``__graft_entry__.py`` and the chip smoke's JAX children all call
 :func:`init` before their first array.
 
-It settles three things once, in one place:
+It settles four things once, in one place:
 
 - **Where compiled programs are kept.** If ``JAX_COMPILATION_CACHE_DIR``
   is set, JAX reads it itself and nothing is set in code. Otherwise the
@@ -27,6 +27,8 @@ It settles three things once, in one place:
   spent obtaining executables (backend compile, or a persistent-cache
   load) and counts cache hits and misses, from JAX's own monitoring
   events.
+- **Where host spans go.** :func:`pushcdn_tpu.parallel.spans.span` is a
+  no-op until here; from here on it is ``jax.profiler.TraceAnnotation``.
 """
 
 from __future__ import annotations
@@ -101,10 +103,13 @@ def device() -> Device:
 
 
 def init(who: str) -> Runtime:
-    """Place the compile cache, start compile accounting, initialise the
-    backend and refuse a CPU nobody asked for. ``who`` names the caller
-    in the log line and the refusal."""
+    """Place the compile cache, start compile accounting, bind the host
+    spans to the profiler, initialise the backend and refuse a CPU nobody
+    asked for. ``who`` names the caller in the log line and the refusal."""
     import jax
+
+    from pushcdn_tpu.parallel import spans
+    spans.bind()
     cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR") or None
     if cache_dir is None and not cpu_requested():
         cache_dir = DEFAULT_CACHE_DIR

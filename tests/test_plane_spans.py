@@ -1,0 +1,173 @@
+"""The profiler spans around the device step and scalar ingress
+(ISSUE 24, ``pushcdn_tpu/parallel/spans.py``): a no-op that imports no
+JAX until ``runtime.init``; under a profiler session, all eight names,
+flat on every thread, joined by ``step``, and conserving the plane's own
+counters."""
+
+import asyncio
+import glob
+import os
+import subprocess
+import sys
+
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if REPO not in sys.path:
+    sys.path.insert(0, REPO)
+PLANE = ("plane.take", "plane.h2d", "plane.dispatch", "plane.d2h",
+         "plane.encode", "plane.egress")
+INGRESS = ("ingress.scan", "ingress.stage")
+
+
+def test_spans_are_a_noop_that_imports_no_jax_until_runtime_init():
+    code = """
+import sys
+from pushcdn_tpu.parallel import spans
+import pushcdn_tpu.broker.tasks.handlers  # the host broker's span user
+with spans.span("ingress.stage", frames=3) as sp:
+    sp.set_metadata(staged=2)
+assert spans.span("a") is spans.span("b") is spans.none("c")
+assert "jax" not in sys.modules, "spans imported jax"
+from pushcdn_tpu.parallel import runtime
+runtime.init("test_plane_spans")
+from jax.profiler import TraceAnnotation
+assert isinstance(spans.span("plane.h2d", step=1), TraceAnnotation)
+assert spans.none("plane.h2d", step=1) is spans._NO_SPAN
+print("ok")
+"""
+    env = {k: v for k, v in os.environ.items() if k != "PYTHONPATH"}
+    proc = subprocess.run([sys.executable, "-c", code], cwd=REPO, env=env,
+                          capture_output=True, text=True, timeout=120)
+    assert proc.returncode == 0 and proc.stdout.strip() == "ok", \
+        proc.stdout[-2000:] + proc.stderr[-2000:]
+
+
+def _program_spans(trace_dir):
+    """``{thread: [(name, start_ns, end_ns, stats), ...]}`` of the
+    program's spans, read as the benchmark reads them, and the length in
+    ns of the part of the trace that holds them."""
+    from benchmark import span_reduce
+    path, = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    spans = span_reduce.load(path)
+    threads = {}
+    for s in spans:
+        threads.setdefault(s.thread, []).append(
+            (s.name, s.start, s.end, s.stats))
+    return threads, (max(s.end for s in spans)
+                     - min(s.start for s in spans))
+
+
+async def _burst(client, n, tag):
+    """``n`` self-directs written back to back, all read back."""
+    await asyncio.gather(*(
+        client.send_direct_message(client.public_key, b"%s %d" % (tag, i))
+        for i in range(n)))
+    got = 0
+    async with asyncio.timeout(30):
+        while got < n:
+            got += len(await client.receive_messages(n - got))
+
+
+async def _single_plane():
+    from pushcdn_tpu.broker.device_plane import DevicePlaneConfig
+    from pushcdn_tpu.testing import Cluster
+    cluster = await Cluster(num_brokers=1, device_plane=DevicePlaneConfig(
+        num_user_slots=32, ring_slots=64, frame_bytes=1024,
+        batch_window_s=0.002, bypass_max_items=0)).start()
+    client = cluster.client(seed=2400, topics=[0])
+    await client.ensure_initialized()
+    return cluster, client, cluster.brokers[0].device_plane
+
+
+async def _mesh_group():
+    import jax
+
+    from pushcdn_tpu.testing.mesh_cluster import MeshCluster
+    cluster = await MeshCluster(num_shards=4,
+                                devices=jax.devices()[:4]).start()
+    client = await cluster.place_client(seed=2401, shard=0, topics=[0])
+    return cluster, client, cluster.group
+
+
+@pytest.mark.parametrize("deploy", [_single_plane, _mesh_group],
+                         ids=["device_plane", "mesh_group"])
+async def test_traced_steps_yield_flat_joined_conserving_spans(
+        deploy, tmp_path):
+    import jax
+
+    from pushcdn_tpu.parallel import spans
+    spans.bind()  # what runtime.init does in a device-owning process
+    cluster, client, plane = await deploy()
+    try:
+        # no session: the same code path records nothing (the sums below
+        # would be off by this burst if it did)
+        await _burst(client, 16, b"untraced")
+        staged0, routed0, steps0 = (plane.frames_staged,
+                                    plane.messages_routed, plane.steps)
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        options.host_tracer_level = 2
+        jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+        try:
+            for round_ in range(3):
+                await _burst(client, 16, b"round%d" % round_)
+        finally:
+            jax.profiler.stop_trace()
+        staged = plane.frames_staged - staged0
+        routed = plane.messages_routed - routed0
+        steps = plane.steps - steps0
+    finally:
+        client.close()
+        await cluster.stop()
+    assert staged == routed == 48 and steps >= 3
+
+    threads, trace_ns = _program_spans(str(tmp_path))
+    events = [e for evs in threads.values() for e in evs]
+    assert {e[0] for e in events} == set(PLANE + INGRESS)
+
+    # flat: on one thread no two of the program's spans overlap
+    for evs in threads.values():
+        evs.sort(key=lambda e: e[1])
+        for (a, _s, a_end, _), (b, b_start, _e, _) in zip(evs, evs[1:]):
+            assert a_end <= b_start, (a, b)
+    # the two sides of the step run on different threads
+    side = {name: {i for i, evs in threads.items()
+                   for e in evs if e[0] == name} for name in PLANE + INGRESS}
+    loop_thread = side["plane.take"]
+    assert len(loop_thread) == 1
+    assert side["plane.egress"] == side["ingress.scan"] == \
+        side["ingress.stage"] == loop_thread
+    for name in ("plane.h2d", "plane.dispatch", "plane.d2h", "plane.encode"):
+        assert side[name] and not side[name] & loop_thread, name
+
+    # one step number per step: take, worker phases, egress, in that
+    # order, and steps of one plane never overlap
+    by_step = {}
+    for e in events:
+        if e[0] in PLANE:
+            by_step.setdefault(e[3]["step"], []).append(e)
+    assert sorted(by_step) == list(range(steps0, steps0 + steps))
+    last_end = 0.0
+    for step in sorted(by_step):
+        evs = sorted(by_step[step], key=lambda e: e[1])
+        names = [e[0] for e in evs]
+        assert names[0] == "plane.take" and names[-1] == "plane.egress"
+        assert names.count("plane.take") == names.count("plane.egress") == 1
+        assert names.count("plane.dispatch") == 1
+        order = [names.index(n) for n in PLANE]
+        assert order == sorted(order), names
+        assert evs[0][1] >= last_end
+        last_end = evs[-1][2]
+
+    # the stats conserve the plane's own counters
+    def total(name, stat):
+        return sum(e[3][stat] for e in events if e[0] == name)
+    assert total("plane.egress", "deliveries") == routed
+    assert total("ingress.stage", "staged") == staged
+    assert total("plane.take", "frames") == staged
+    assert total("ingress.scan", "frames") >= \
+        total("ingress.stage", "frames") >= staged
+    waits = [e[3]["ring_wait_us"] for e in events if e[0] == "plane.take"]
+    assert all(0 <= w < trace_ns / 1e3 for w in waits), waits
