@@ -212,6 +212,11 @@ class DevicePlane:
         # frame at the next take is ``plane.take``'s ``ring_wait_us``
         self._staged_since: Optional[float] = None
         self.messages_routed = 0
+        # per-user stream hand-offs of the native egress, by how each
+        # went: written by the pump on an idle link, or queued for the
+        # user's writer task (senders.egress_streams tallies all three)
+        self.egress_inline = 0
+        self.egress_queued = 0
         self.warmup_s: Optional[float] = None
 
     # ---- user lifecycle (Connections observer; event-loop only) ----------
@@ -443,6 +448,8 @@ class DevicePlane:
             "steps": self.steps,
             "frames_staged": self.frames_staged,
             "messages_routed": self.messages_routed,
+            "egress_inline": self.egress_inline,
+            "egress_queued": self.egress_queued,
             "mirrored_users": len(self.slots),
             "unmirrored_users": len(self._unmirrored),
         }
@@ -553,15 +560,18 @@ class DevicePlane:
                     self._step_inflight = False
                 gate.stepped(loop.time())
                 with spans.span("plane.egress", step=step) as sp:
-                    routed = self.messages_routed
+                    routed, inline, queued = (
+                        self.messages_routed, self.egress_inline,
+                        self.egress_queued)
                     for streams, d2, lengths, frames in jobs:
                         if streams is not None:
-                            self.messages_routed += egress_streams(
-                                self.broker, self.slots, streams)
+                            egress_streams(self, self.broker, streams)
                         else:
                             self._egress(d2, lengths, frames)
                     sp.set_metadata(
-                        deliveries=self.messages_routed - routed)
+                        deliveries=self.messages_routed - routed,
+                        inline=self.egress_inline - inline,
+                        queued=self.egress_queued - queued)
             except asyncio.CancelledError:
                 raise
             except Exception:

@@ -184,6 +184,8 @@ class MeshShardPlane:
             "disabled": g.disabled, "steps": g.steps,
             "frames_staged": g.frames_staged,
             "messages_routed": g.messages_routed,
+            "egress_inline": g.egress_inline,
+            "egress_queued": g.egress_queued,
         }
 
     @property
@@ -272,6 +274,10 @@ class MeshBrokerGroup:
         # to non-empty (None while empty): ``plane.take``'s ring_wait_us
         self._staged_since: Optional[float] = None
         self.messages_routed = 0
+        # per-user stream hand-offs by how each went (DevicePlane's twin;
+        # senders.egress_streams tallies all three)
+        self.egress_inline = 0
+        self.egress_queued = 0
         # collectives traced by the most recently COMPILED step
         # specialization (router.trace_collectives delta around the call):
         # the counted one-collective-per-tick invariant, asserted by the
@@ -637,18 +643,21 @@ class MeshBrokerGroup:
                     liveness, rev, step)
                 gate.stepped(loop.time())
                 with spans.span("plane.egress", step=step) as sp:
-                    routed = self.messages_routed
+                    routed, inline, queued = (
+                        self.messages_routed, self.egress_inline,
+                        self.egress_queued)
                     for shard, streams, d2, lengths, frames in egress_jobs:
                         broker = self.brokers[shard]
                         if broker is None:
                             continue
                         if streams is not None:
-                            self.messages_routed += egress_streams(
-                                broker, self.slots, streams)
+                            egress_streams(self, broker, streams)
                         else:
                             self._egress_py(broker, d2, lengths, frames)
                     sp.set_metadata(
-                        deliveries=self.messages_routed - routed)
+                        deliveries=self.messages_routed - routed,
+                        inline=self.egress_inline - inline,
+                        queued=self.egress_queued - queued)
             except asyncio.CancelledError:
                 raise
             except Exception:

@@ -384,8 +384,7 @@ class MultiHostBrokerGroup(MeshBrokerGroup):
                     if broker is None:
                         continue
                     if streams is not None:
-                        self.messages_routed += egress_streams(
-                            broker, self.slots, streams)
+                        egress_streams(self, broker, streams)
                     else:
                         self._egress_py(broker, d2, lengths, frames)
             except asyncio.CancelledError:

@@ -43,6 +43,10 @@ def _pumped(connection) -> str:
 _PRE_ENCODE_MAX_FRAME = 64 * 1024
 _PRE_ENCODE_MAX_TOTAL = 1 << 20
 
+# how try_send_encoded_to_user_nowait handed a stream over (both truthy)
+INLINE = 1
+QUEUED = 2
+
 
 def pre_encode_frames(raws) -> Optional[bytearray]:
     """Length-delimit a batch of small ``bytes`` frames into ONE owned
@@ -131,40 +135,53 @@ def try_send_frames_to_user_nowait(broker: "Broker", public_key: bytes,
 
 def try_send_encoded_to_user_nowait(broker: "Broker", public_key: bytes,
                                     data, owner=None,
-                                    nframes: int = 0) -> bool:
-    """Queue a pre-framed egress stream (native.egress_encode output) to
-    one user — zero per-frame work here or in the writer; a failure
-    removes the user (failure-is-removal, as everywhere). ``owner`` keeps
-    a pooled egress buffer alive until the flush completes. ``nframes``
-    feeds the writer's class accounting (the stream itself is opaque)."""
+                                    nframes: int = 0) -> int:
+    """Hand a pre-framed egress stream (native.egress_encode output) to
+    one user — zero per-frame work here or in the writer. An idle link
+    takes it there and then, from the caller's task
+    (``Connection.try_send_encoded_inline``: no writer-task wake-up);
+    any other link queues it for its writer, behind what is queued.
+    Returns ``INLINE`` or ``QUEUED``, or 0 after a failure, which removes
+    the user (failure-is-removal, as everywhere). ``owner`` keeps a
+    pooled egress buffer alive until a queued flush completes.
+    ``nframes`` feeds the class accounting (the stream itself is
+    opaque)."""
     connection = broker.connections.get_user_connection(public_key)
     if connection is None:
-        return False
+        return 0
     try:
+        if connection.try_send_encoded_inline(data, nframes=nframes):
+            return INLINE
         connection.send_encoded_nowait(data, owner, nframes=nframes)
-        return True
+        return QUEUED
     except Exception as exc:
         logger.info("encoded send to user %s failed (%r)%s; removing",
                     mnemonic(public_key), exc, _pumped(connection))
         broker.connections.remove_user(public_key, reason="send failed")
         broker.update_metrics()
-        return False
+        return 0
 
 
-def egress_streams(broker: "Broker", slots, streams) -> int:
+def egress_streams(plane, broker: "Broker", streams) -> None:
     """Deliver one step's native egress (:class:`native.EgressStreams`):
-    one pre-framed stream handoff per user with deliveries. Returns the
-    number of messages queued."""
-    routed = 0
+    one pre-framed stream hand-off per user with deliveries, tallied on
+    ``plane`` (a ``DevicePlane`` or a broker group): ``messages_routed``,
+    and how each hand-off went, ``egress_inline`` or ``egress_queued``."""
+    slots = plane.slots
     for slot in streams.users:
         key = slots.key_of(int(slot))
         if key is None:  # released mid-step: user is gone, drop
             continue
-        if try_send_encoded_to_user_nowait(broker, key, streams.stream(slot),
-                                           owner=streams,
-                                           nframes=int(streams.msgs[slot])):
-            routed += int(streams.msgs[slot])
-    return routed
+        nframes = int(streams.msgs[slot])
+        how = try_send_encoded_to_user_nowait(
+            broker, key, streams.stream(slot), owner=streams,
+            nframes=nframes)
+        if how:
+            plane.messages_routed += nframes
+            if how == INLINE:
+                plane.egress_inline += 1
+            else:
+                plane.egress_queued += 1
 
 
 def egress_delivery_rows(broker: "Broker", slots, users, frame_idx,

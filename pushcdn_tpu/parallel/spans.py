@@ -34,8 +34,10 @@ The spans (the names are what ``benchmark/span_reduce.py`` reads):
 ``plane.dispatch``    worker      the jitted step's call; ``step``
 ``plane.d2h``         worker      each read-back of a decision; ``step``
 ``plane.encode``      worker      decisions to egress streams; ``step``
-``plane.egress``      event loop  the pump's hand-off to the users' writers;
-                                  ``step``, ``deliveries``
+``plane.egress``      event loop  the pump's hand-off of the users' streams:
+                                  written there on an idle link, else queued
+                                  for the writer; ``step``, ``deliveries``,
+                                  ``inline``, ``queued``
 ====================  ==========  ===========================================
 
 ``step`` is the plane's own step number; the two thread hops of a step

@@ -224,6 +224,14 @@ class RawStream(abc.ABC):
         for b in bufs:
             await self.write(b)
 
+    def write_nowait(self, data) -> bool:
+        """Accept ``data`` now iff :meth:`write`'s flush would not have
+        waited; never awaits, and False means nothing was written (the
+        caller queues for the writer task instead). Optional: a stream
+        without a cheap way to tell keeps this default and every send
+        takes the writer."""
+        return False
+
     @abc.abstractmethod
     async def close(self) -> None:
         """Flush and close the write side gracefully."""
@@ -256,6 +264,18 @@ class AsyncioStream(RawStream):
         # its lease drops — the transport must own a private copy
         self.writer.write(bytes(data) if isinstance(data, memoryview) else data)
         await self.writer.drain()
+
+    def write_nowait(self, data) -> bool:
+        # asyncio pauses the protocol above the high-water mark and
+        # resumes it only at the low one, so at or under the low mark
+        # ``drain()`` is certain to return at once — the one state in
+        # which ``write()`` above is ``write()`` alone
+        transport = self.writer.transport
+        if transport.is_closing() or transport.get_write_buffer_size() \
+                > transport.get_write_buffer_limits()[0]:
+            return False
+        self.writer.write(bytes(data) if isinstance(data, memoryview) else data)
+        return True
 
     async def writev(self, bufs) -> None:
         # one gather handoff: writelines joins the run into a single
@@ -1261,6 +1281,49 @@ class Connection:
         self._ensure_writer()
         if self._error is not None:
             raise self._error
+
+    def try_send_encoded_inline(self, data, cls: int = 2,
+                                nframes: int = 0) -> bool:
+        """Write an already length-delimited stream from the CALLER's
+        task, now, iff it is no longer than one flush unit
+        (``_BATCH_COALESCE_LIMIT``) and the link is idle: nothing queued,
+        no write section open, and the stream takes the bytes without
+        waiting (:meth:`RawStream.write_nowait`). False means nothing
+        happened and the caller queues (:meth:`send_encoded_nowait`) —
+        also on a poisoned or closed link, where that call raises.
+
+        The unit bounds what one caller pushes into one link in one loop
+        pass, as it bounds every other flush. A longer stream is mostly
+        bytes: the writer's fixed cost is a small part of its send, and
+        only the writer tasks let those sends run beside the caller's
+        next step instead of holding it up.
+
+        The non-awaiting twin of :meth:`send_raw`'s inline fast path, on
+        the same argument: on the single loop nothing runs between the
+        checks and the write, and an entry a woken writer has not yet
+        dequeued still sits in the queue, so bytes can neither reorder
+        nor interleave. A write that raises poisons the link. Accounting
+        is that path's: zero queue delay, so only the volume counters and
+        the ledger's transit move."""
+        if self._error is not None or self._closed \
+                or not self._send_q.empty() or self._write_mutex.locked() \
+                or len(data) > self._BATCH_COALESCE_LIMIT:
+            return False
+        try:
+            if not self._stream.write_nowait(data):
+                return False
+        except Exception as exc:
+            err = Error(ErrorKind.CONNECTION, f"write failed: {exc!r}", exc)
+            self._poison(err)
+            raise err
+        cls &= 3
+        nbytes = len(data)
+        self._m_sent.inc(nbytes)
+        if nframes:
+            metrics_mod.CLASS_FRAMES_OUT[cls].inc(nframes)
+        metrics_mod.CLASS_BYTES_OUT[cls].inc(nbytes)
+        ledger_mod.on_transit(cls, nframes, self.ledger_peer)
+        return True
 
     async def send_encoded(self, data, owner=None, flush: bool = False,
                            cls: int = 2, nframes: int = 0,

@@ -3,6 +3,8 @@ churn under traffic, slot-table exhaustion fallback."""
 
 import asyncio
 
+import pytest
+
 from pushcdn_tpu.parallel.frames import UserSlots
 from tests.test_integration import Cluster, wait_until
 
@@ -302,3 +304,174 @@ async def test_ragged_page_pool_exhaustion_falls_back_then_recovers():
             c.close()
     finally:
         await cluster.stop()
+
+
+# ---------------------------------------------------------------------------
+# served differential: the device plane's egress (idle links written by the
+# pump, the rest by their writers) against the plain host router, over real
+# TCP links, one of them to a reader that stalls
+# ---------------------------------------------------------------------------
+
+_N_USERS, _PUBLISHERS, _FRAMES, _SLOW = 6, 3, 150, 5
+
+
+def _mixed_traffic(seed: int):
+    """Per publisher its frames in order, ``(kind, target, payload)``, and
+    per user what it is owed: ``{(publisher, stream): [seq, ...]}``. User
+    ``u`` subscribes to topic ``u % 2``, the slow reader to both."""
+    import random
+    rng = random.Random(seed)
+    topics = [{u % 2} for u in range(_N_USERS)]
+    topics[_SLOW] = {0, 1}
+    plan = [[] for _ in range(_PUBLISHERS)]
+    owed = [{} for _ in range(_N_USERS)]
+    for p in range(_PUBLISHERS):
+        seqs = {}
+        for _ in range(_FRAMES):
+            if rng.random() < 0.75:
+                kind, target = "broadcast", rng.randrange(2)
+                stream, to = "t%d" % target, [
+                    u for u in range(_N_USERS) if target in topics[u]]
+            else:
+                kind, target = "direct", rng.randrange(_N_USERS)
+                stream, to = "direct", [target]
+            seq = seqs[stream] = seqs.get(stream, -1) + 1
+            payload = (b"%d|%s|%d|" % (p, stream.encode(), seq)).ljust(
+                rng.choice((64, 600, 900)), b".")
+            plan[p].append((kind, target, payload))
+            for u in to:
+                owed[u].setdefault((p, stream), []).append(seq)
+    return topics, plan, owed
+
+
+async def _serve_mixed_traffic(seed: int, device_plane):
+    """Run the seeded traffic through one broker with real TCP user links;
+    returns what each user received, what it was owed, and the plane (or
+    None)."""
+    import os
+    import socket
+    import tempfile
+
+    from pushcdn_tpu.bin.common import free_ports
+    from pushcdn_tpu.broker.broker import Broker, BrokerConfig
+    from pushcdn_tpu.broker.tasks.heartbeat import heartbeat_once
+    from pushcdn_tpu.client import Client, ClientConfig
+    from pushcdn_tpu.marshal import Marshal, MarshalConfig
+    from pushcdn_tpu.proto.crypto.signature import DEFAULT_SCHEME
+    from pushcdn_tpu.proto.def_ import testing_run_def
+    from pushcdn_tpu.proto.transport import Tcp
+
+    run_def = testing_run_def(user_protocol=Tcp)
+    db = os.path.join(tempfile.mkdtemp(prefix="pushcdn-diff-"), "d.sqlite")
+    pub, marshal_port = free_ports(2)
+    tag = f"diff-{seed}-{'dev' if device_plane else 'host'}"
+    broker = await Broker.new(BrokerConfig(
+        run_def=run_def, keypair=DEFAULT_SCHEME.generate_keypair(seed=seed),
+        discovery_endpoint=db,
+        public_advertise_endpoint=f"127.0.0.1:{pub}",
+        public_bind_endpoint=f"127.0.0.1:{pub}",
+        private_advertise_endpoint=f"{tag}-priv",
+        private_bind_endpoint=f"{tag}-priv",
+        heartbeat_interval_s=3600, sync_interval_s=3600,
+        whitelist_interval_s=3600, device_plane=device_plane))
+    await broker.start()
+    await heartbeat_once(broker)
+    marshal = await Marshal.new(MarshalConfig(
+        run_def=run_def, discovery_endpoint=db,
+        bind_endpoint=f"127.0.0.1:{marshal_port}"))
+    await marshal.start()
+    keypairs = [DEFAULT_SCHEME.generate_keypair(seed=seed + 1 + u)
+                for u in range(_N_USERS)]
+    topics, plan, owed = _mixed_traffic(seed)
+    clients = [Client(ClientConfig(
+        marshal_endpoint=f"127.0.0.1:{marshal_port}", keypair=keypairs[u],
+        protocol=Tcp, subscribed_topics=topics[u]))
+        for u in range(_N_USERS)]
+    got = [{} for _ in range(_N_USERS)]
+    counts = [0] * _N_USERS
+
+    async def drain(u):
+        while True:
+            for m in await clients[u].receive_messages():
+                p, stream, seq, _ = bytes(m.message).split(b"|", 3)
+                got[u].setdefault((int(p), stream.decode()), []).append(
+                    int(seq))
+                counts[u] += 1
+
+    async def publish(p):
+        for kind, target, payload in plan[p]:
+            if kind == "broadcast":
+                await clients[p].send_broadcast_message([target], payload)
+            else:
+                await clients[p].send_direct_message(
+                    clients[target].public_key, payload)
+
+    drains = []
+    try:
+        for c in clients:
+            await c.ensure_initialized()
+        await wait_until(
+            lambda: broker.connections.num_users == _N_USERS)
+        # the slow reader: it stops reading, behind socket buffers small
+        # enough that its link backs up within the traffic
+        slow = clients[_SLOW]._connection._stream
+        slow.writer.get_extra_info("socket").setsockopt(
+            socket.SOL_SOCKET, socket.SO_RCVBUF, 4096)
+        broker.connections.get_user_connection(clients[_SLOW].public_key) \
+            ._stream.writer.get_extra_info("socket").setsockopt(
+                socket.SOL_SOCKET, socket.SO_SNDBUF, 4096)
+        slow.reader._transport.pause_reading()
+        drains = [asyncio.create_task(drain(u)) for u in range(_N_USERS)]
+        await asyncio.gather(*(publish(p) for p in range(_PUBLISHERS)))
+        fast = [u for u in range(_N_USERS) if u != _SLOW]
+        await wait_until(lambda: all(
+            counts[u] == sum(map(len, owed[u].values())) for u in fast),
+            timeout=30)
+        slow.reader._transport.resume_reading()  # well inside the timeout
+        await wait_until(
+            lambda: counts[_SLOW] == sum(map(len, owed[_SLOW].values())),
+            timeout=30)
+        assert broker.connections.num_users == _N_USERS  # nobody removed
+    finally:
+        for t in drains:
+            t.cancel()
+        for c in clients:
+            c.close()
+        await marshal.stop()
+        await broker.stop()
+    return got, owed, broker.device_plane
+
+
+@pytest.mark.parametrize("seed", [2601, 2602])
+async def test_served_egress_matches_the_host_router_with_a_slow_reader(
+        seed, monkeypatch):
+    from pushcdn_tpu.broker.device_plane import DevicePlaneConfig
+    from pushcdn_tpu.broker.tasks import senders
+
+    handoffs = []  # every per-user stream hand-off that did not fail
+    real = senders.try_send_encoded_to_user_nowait
+
+    def counted(*args, **kwargs):
+        how = real(*args, **kwargs)
+        if how:
+            handoffs.append(how)
+        return how
+    monkeypatch.setattr(senders, "try_send_encoded_to_user_nowait", counted)
+
+    by_host, owed, no_plane = await _serve_mixed_traffic(seed, None)
+    assert no_plane is None and not handoffs
+    by_device, _, plane = await _serve_mixed_traffic(
+        seed, DevicePlaneConfig(
+            num_user_slots=32, ring_slots=64, frame_bytes=1024,
+            batch_window_s=0.002, bypass_max_items=0))
+    # every user, every (publisher, stream): the same sequence both ways,
+    # and it is the publisher's own order with nothing lost or repeated
+    assert by_device == by_host == owed
+    assert not plane.disabled and plane.steps >= 1
+    # idle links were written by the pump, the stalled one by its writer,
+    # and the two tallies are all the hand-offs there were
+    assert plane.egress_inline > 0 and plane.egress_queued > 0
+    assert plane.egress_inline + plane.egress_queued == len(handoffs)
+    described = plane.describe()
+    assert (described["egress_inline"], described["egress_queued"]) == \
+        (plane.egress_inline, plane.egress_queued)

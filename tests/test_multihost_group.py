@@ -64,6 +64,9 @@ async def test_single_process_group_routes_and_directory(tmp_path):
         def __init__(self):
             self.streams = []
 
+        def try_send_encoded_inline(self, data, cls=2, nframes=0):
+            return False  # never idle: every stream takes the queued call
+
         def send_encoded_nowait(self, data, owner=None, cls=2, nframes=0):
             self.streams.append(bytes(data))
 

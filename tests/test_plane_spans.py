@@ -106,6 +106,7 @@ async def test_traced_steps_yield_flat_joined_conserving_spans(
         await _burst(client, 16, b"untraced")
         staged0, routed0, steps0 = (plane.frames_staged,
                                     plane.messages_routed, plane.steps)
+        handoffs0 = (plane.egress_inline, plane.egress_queued)
         options = jax.profiler.ProfileOptions()
         options.python_tracer_level = 0
         options.host_tracer_level = 2
@@ -118,6 +119,8 @@ async def test_traced_steps_yield_flat_joined_conserving_spans(
         staged = plane.frames_staged - staged0
         routed = plane.messages_routed - routed0
         steps = plane.steps - steps0
+        inline, queued = (plane.egress_inline - handoffs0[0],
+                          plane.egress_queued - handoffs0[1])
     finally:
         client.close()
         await cluster.stop()
@@ -165,6 +168,13 @@ async def test_traced_steps_yield_flat_joined_conserving_spans(
     def total(name, stat):
         return sum(e[3][stat] for e in events if e[0] == name)
     assert total("plane.egress", "deliveries") == routed
+    # one hand-off per user with deliveries per step, each either written
+    # by the pump or queued for the writer (all queued here: the Memory
+    # transport's stream has no ``write_nowait``)
+    assert (total("plane.egress", "inline"),
+            total("plane.egress", "queued")) == (inline, queued)
+    assert inline + queued == sum(
+        1 for e in events if e[0] == "plane.egress" and e[3]["deliveries"])
     assert total("ingress.stage", "staged") == staged
     assert total("plane.take", "frames") == staged
     assert total("ingress.scan", "frames") >= \

@@ -27,7 +27,7 @@ from pushcdn_tpu.proto.message import (
     deserialize_owned,
     serialize,
 )
-from pushcdn_tpu.proto.transport import Memory
+from pushcdn_tpu.proto.transport import Memory, Tcp
 
 _LEN = struct.Struct(">I")
 
@@ -320,3 +320,265 @@ async def test_bounded_queue_send_order_is_fifo_under_saturation():
     client.close()
     server.close()
     await listener.close()
+
+
+# ---------------------------------------------------------------------------
+# the inline step flush: Connection.try_send_encoded_inline /
+# RawStream.write_nowait (an idle link is written by the caller's task;
+# anything else takes the writer, in order)
+# ---------------------------------------------------------------------------
+
+async def _tcp_pair():
+    listener = await Tcp.bind("127.0.0.1:0")
+    connect = asyncio.create_task(
+        Tcp.connect(f"127.0.0.1:{listener.bound_port}"))
+    server = await (await listener.accept()).finalize()
+    client = await connect
+    return listener, client, server
+
+
+def _stream_of(*payloads: bytes) -> bytes:
+    return b"".join(_LEN.pack(len(p)) + p for p in payloads)
+
+
+async def _recv_n(conn, n: int) -> list:
+    got = []
+    async with asyncio.timeout(10):
+        while len(got) < n:
+            for raw in await conn.recv_raw_many():
+                got.append(bytes(raw.data))
+                raw.release()
+    return got
+
+
+async def test_inline_then_queued_then_inline_keeps_the_wire_fifo():
+    listener, client, server = await _tcp_pair()
+    try:
+        # idle link: written there and then, no writer task at all
+        assert server.try_send_encoded_inline(
+            memoryview(_stream_of(b"a0", b"a1")), nframes=2)
+        assert server._writer_task is None
+        # a queued entry makes the link non-idle: the next stream may not
+        # overtake it, so the caller's fallback queues behind it
+        server.send_encoded_nowait(_stream_of(b"b0"), nframes=1)
+        assert not server.try_send_encoded_inline(_stream_of(b"c0"),
+                                                  nframes=1)
+        server.send_encoded_nowait(_stream_of(b"c0"), nframes=1)
+        assert await _recv_n(client, 4) == [b"a0", b"a1", b"b0", b"c0"]
+        # drained: idle again, inline again
+        assert server._send_q.empty() and not server._write_mutex.locked()
+        assert server.try_send_encoded_inline(_stream_of(b"d0", b"d1"),
+                                              nframes=2)
+        assert await _recv_n(client, 2) == [b"d0", b"d1"]
+    finally:
+        client.close()
+        server.close()
+        await listener.close()
+
+
+async def _hold_mutex(server):
+    await server._write_mutex.acquire()
+
+
+async def _queue_entry(server):
+    server.send_encoded_nowait(_stream_of(b"queued"), nframes=1)
+
+
+async def _over_the_mark(server):
+    # a peer that does not read: streams of just under one flush unit
+    # until the socket is full and one is left waiting in the
+    # transport's buffer, far over the marks
+    server._stream.writer.transport.set_write_buffer_limits(high=8192)
+    big = _stream_of(b"x" * 65000)
+    for _ in range(1024):
+        if not server.try_send_encoded_inline(big, nframes=1):
+            break
+    assert server._stream.writer.transport.get_write_buffer_size() > 8192
+
+
+async def _a_long_stream(server):
+    # an idle link, but more than one flush unit
+    return _stream_of(*[b"l" * 40000] * 2)
+
+
+async def _poison(server):
+    server._poison(Error(ErrorKind.CONNECTION, "test"))
+
+
+async def _close(server):
+    server.close()
+
+
+@pytest.mark.parametrize("prepare, dead", [
+    (_queue_entry, False), (_hold_mutex, False), (_over_the_mark, False),
+    (_poison, True), (_close, True), (_a_long_stream, False),
+], ids=["queue_holds_an_entry", "write_mutex_held", "over_the_water_mark",
+        "poisoned", "closed", "longer_than_one_flush_unit"])
+async def test_inline_is_refused_on_a_link_that_is_not_idle(prepare, dead):
+    from pushcdn_tpu.proto import metrics as metrics_mod
+    listener, client, server = await _tcp_pair()
+    if prepare is _over_the_mark:
+        client._reader_task.cancel()  # the peer stops reading
+    try:
+        stream = await prepare(server) or _stream_of(b"late")
+        sent = metrics_mod.BYTES_SENT.labels(transport="tcp").value
+        assert server.try_send_encoded_inline(stream, nframes=1) is False
+        assert metrics_mod.BYTES_SENT.labels(transport="tcp").value == sent
+        if dead:  # and the fallback is what reports the dead link
+            with pytest.raises(Error):
+                server.send_encoded_nowait(_stream_of(b"late"), nframes=1)
+    finally:
+        client.close()
+        server.close()
+        await listener.close()
+
+
+async def test_inline_is_refused_by_a_stream_without_the_capability():
+    listener, client, server = await _pair("sem-inline-memory")
+    try:
+        assert server._stream.write_nowait(b"") is False
+        assert not server.try_send_encoded_inline(_stream_of(b"m0"),
+                                                  nframes=1)
+        server.send_encoded_nowait(_stream_of(b"m0"), nframes=1)
+        assert await _recv_n(client, 1) == [b"m0"]
+    finally:
+        client.close()
+        server.close()
+        await listener.close()
+
+
+class _Broker:
+    """What the send helpers use of a broker: its connections (one object
+    plays both) and ``update_metrics``."""
+
+    def __init__(self, users: dict):
+        self.users = dict(users)
+        self.removed = []
+        self.connections = self
+
+    def get_user_connection(self, key):
+        return self.users.get(key)
+
+    def remove_user(self, key, reason=""):
+        self.removed.append((key, reason))
+        self.users.pop(key).close()
+
+    def update_metrics(self):
+        pass
+
+
+class _Plane:
+    """What ``senders.egress_streams`` uses of a plane: the slot table
+    (one user per slot) and the three tallies."""
+
+    def __init__(self, keys):
+        self.keys = list(keys)
+        self.slots = self
+        self.messages_routed = self.egress_inline = self.egress_queued = 0
+
+    def key_of(self, slot):
+        return self.keys[slot]
+
+
+class _Streams:
+    """A step's native egress: the same ``nframes``-frame stream for each
+    of ``n`` users (``native.EgressStreams``' face)."""
+
+    def __init__(self, n: int, stream: bytes, nframes: int):
+        self.users = range(n)
+        self.msgs = [nframes] * n
+        self._stream = stream
+
+    def stream(self, slot):
+        return memoryview(self._stream)
+
+
+async def test_a_raising_inline_write_poisons_and_egress_removes_the_user():
+    """A step's egress over a faulty link and a healthy one: the one is
+    poisoned, removed and counts for nothing, the other is served."""
+    from pushcdn_tpu.broker.tasks.senders import egress_streams
+    listener, client, faulty = await _tcp_pair()
+    listener2, client2, fine = await _tcp_pair()
+    try:
+        def boom(data):
+            raise OSError("wire fault")
+        faulty._stream.write_nowait = boom
+        broker = _Broker({b"faulty": faulty, b"fine": fine})
+        plane = _Plane([b"faulty", b"fine"])
+        egress_streams(plane, broker, _Streams(2, _stream_of(b"y0", b"y1"), 2))
+        assert faulty._error is not None  # as after a failed writer flush
+        assert broker.removed == [(b"faulty", "send failed")]
+        assert (plane.messages_routed, plane.egress_inline,
+                plane.egress_queued) == (2, 1, 0)
+        assert await _recv_n(client2, 2) == [b"y0", b"y1"]
+    finally:
+        for c in (client, faulty, client2, fine):
+            c.close()
+        await listener.close()
+        await listener2.close()
+
+
+async def test_inline_and_queued_hand_offs_close_the_ledger_identity():
+    from pushcdn_tpu.broker.tasks.senders import egress_streams
+    from pushcdn_tpu.proto import ledger as ledger_mod
+    ledger_mod.reset_for_tests()
+    listener, client, server = await _tcp_pair()
+    try:
+        broker, plane = _Broker({b"u": server}), _Plane([b"u"])
+        streams = _Streams(1, _stream_of(b"f0", b"f1", b"f2"), 3)
+        egress_streams(plane, broker, streams)
+        egress_streams(plane, broker, streams)
+        # a host-routed frame queued just before the third step's egress:
+        # that hand-off goes behind it (note_queued, then on_dequeued)
+        server.send_encoded_nowait(_stream_of(b"g0"), nframes=1)
+        egress_streams(plane, broker, streams)
+        assert (plane.messages_routed, plane.egress_inline,
+                plane.egress_queued) == (9, 2, 1)
+        assert await _recv_n(client, 10) == [b"f0", b"f1", b"f2"] * 2 \
+            + [b"g0", b"f0", b"f1", b"f2"]
+        L = ledger_mod.LEDGER
+        assert sum(L.queued) == 10
+        assert sum(L.fates[("delivered", "egress")]) == 10
+        assert sum(L.derived_in_queue()) == L.walk_live_queues() == 0
+        for _ in range(3):
+            L.check_conservation()
+        assert L.violations == 0
+    finally:
+        client.close()
+        server.close()
+        await listener.close()
+        ledger_mod.reset_for_tests()
+
+
+async def test_a_peer_that_stops_reading_is_still_removed_by_the_timeout(
+        monkeypatch):
+    """Inline writes only ever fill an idle link; once the peer stops
+    reading, the next hand-off queues, the writer's ``drain()`` waits, and
+    the write timeout poisons the link: the hand-off after that removes
+    the user. Slow-consumer detection is the writer's, unchanged."""
+    from pushcdn_tpu.broker.tasks.senders import egress_streams
+    from pushcdn_tpu.proto.transport import base as base_mod
+    monkeypatch.setattr(base_mod, "WRITE_TIMEOUT_S", 0.3)
+    listener = await Tcp.bind("127.0.0.1:0")
+    # a raw peer that never reads (a Connection's reader always does)
+    _reader, peer = await asyncio.open_connection("127.0.0.1",
+                                                  listener.bound_port)
+    server = await (await listener.accept()).finalize()
+    broker, plane = _Broker({b"slow": server}), _Plane([b"slow"])
+    streams = _Streams(1, _stream_of(b"s" * 65000), 1)
+    try:
+        tallies = []
+        async with asyncio.timeout(10):
+            while not broker.removed:
+                egress_streams(plane, broker, streams)
+                tallies.append((plane.egress_inline, plane.egress_queued))
+                if plane.egress_queued:  # backed up: now give it time
+                    await asyncio.sleep(0.05)
+        assert tallies[0] == (1, 0)      # the idle link took the first
+        assert tallies[-1][1] >= 1       # then the writer, which waited
+        assert tallies[-1] == tallies[-2]  # the last hand-off: removal
+        assert broker.removed == [(b"slow", "send failed")]
+    finally:
+        peer.close()
+        server.close()
+        await listener.close()
