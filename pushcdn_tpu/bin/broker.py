@@ -21,6 +21,7 @@ from pushcdn_tpu.bin.common import (
     init_logging,
     install_drain_signals,
     keypair_from_seed,
+    raise_nofile_limit,
     run_def_from_args,
     tune_gc,
 )
@@ -343,6 +344,7 @@ async def amain(args: argparse.Namespace) -> None:
 def main() -> None:
     args = build_parser().parse_args()
     init_logging(args.verbose)
+    raise_nofile_limit()
     apply_io_impl(args)
     apply_pump(args)
     tune_gc()

@@ -109,6 +109,23 @@ def init_logging(verbosity: int = 0) -> None:
     logging.basicConfig(level=level, handlers=[handler], force=True)
 
 
+def raise_nofile_limit() -> None:
+    """Raise this process's soft ``RLIMIT_NOFILE`` to its hard limit and
+    log both: a server holds one socket a user, and a default soft limit
+    of 1,024 sits just above a 1,000-user broker."""
+    import resource
+    log = logging.getLogger("pushcdn.bin")
+    soft, hard = resource.getrlimit(resource.RLIMIT_NOFILE)
+    if soft != hard:
+        try:
+            resource.setrlimit(resource.RLIMIT_NOFILE, (hard, hard))
+        except (ValueError, OSError) as exc:
+            log.warning("RLIMIT_NOFILE stays at soft %d (hard %d): %r",
+                        soft, hard, exc)
+            return
+    log.info("RLIMIT_NOFILE: soft %d -> %d (hard %d)", soft, hard, hard)
+
+
 def drain_grace_s() -> float:
     """How long a binary keeps serving (with /readyz already 503) between
     receiving SIGINT/SIGTERM and tearing its listeners down —

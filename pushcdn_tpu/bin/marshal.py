@@ -10,6 +10,7 @@ from pushcdn_tpu.bin.common import (
     drain_grace_s,
     init_logging,
     install_drain_signals,
+    raise_nofile_limit,
     run_def_from_args,
     tune_gc,
 )
@@ -62,6 +63,7 @@ async def amain(args: argparse.Namespace) -> None:
 def main() -> None:
     args = build_parser().parse_args()
     init_logging(args.verbose)
+    raise_nofile_limit()
     tune_gc()
     try:
         asyncio.run(amain(args))

@@ -515,6 +515,7 @@ class Broker:
         plane = self.device_plane
         if plane is not None:
             broker_metrics.DEVICE_STEPS.set(plane.steps)
+            broker_metrics.DEVICE_USER_SLOTS.set(plane.user_slots)
             broker_metrics.DEVICE_FRAMES_STAGED.set(plane.frames_staged)
             broker_metrics.DEVICE_MESSAGES_ROUTED.set(plane.messages_routed)
             broker_metrics.DEVICE_PLANE_DISABLED.set(int(plane.disabled))

@@ -26,6 +26,17 @@ Consistency design (single-writer, snapshot-per-step):
 - **Slot quarantine**: a released user slot is not reusable until the step
   that might still carry frames addressed to it has completed — prevents a
   recycled slot from leaking one user's messages to another.
+- **The table follows who connects**: when a connection finds the slot
+  table full, table and mirrors double on the event loop, in the observer
+  hook (``MAX_USER_SLOTS`` is the ceiling; users beyond it are host-routed
+  and keep every broadcast on the host path). A step in flight keeps the
+  copies it took; no binding moves and no quarantined slot is freed, so
+  both guarantees above hold across a growth. A step runs at the
+  smallest power of two that holds the slot high-water mark
+  (``effective_users``: a 1,000-user broker runs the 1,024-row programs,
+  one of 5,000 the 8,192-row ones: ``_step_users``), and only the pump
+  runs steps: a growth wakes it to load the new size's programs beside
+  the connects that caused it (``_load_programs``).
 - **A failed warm-up is fatal**: if the first compile-and-run raises
   (no usable device, a kernel Mosaic refuses), ``start`` raises and the
   broker exits non-zero — a broker asked for a device plane never serves
@@ -89,9 +100,22 @@ if TYPE_CHECKING:
 
 logger = logging.getLogger("pushcdn.broker.device")
 
+# The user table follows who connects: it starts at
+# ``DevicePlaneConfig.num_user_slots`` and doubles when a connection finds
+# it full, up to this many slots; users beyond it are host-routed
+# (``_unmirrored``). No representation of a slot id stops here — ids are
+# int32 in the ``dest`` column, the ragged pages and the native egress,
+# and the ragged extractor's u16 radix pass (``ragged_pairs``) is a fast
+# path that falls back to a comparison sort. What bounds the table is
+# what a step brings back: the dense decision is bool[users, ring_slots]
+# per busy lane, 64 MiB through D2H and the egress scan at 65,536 x 1,024.
+MAX_USER_SLOTS = 65536
+
 
 @dataclass
 class DevicePlaneConfig:
+    # the user table's size at start; it grows with the connections
+    # (MAX_USER_SLOTS)
     num_user_slots: int = 1024
     ring_slots: int = 1024
     frame_bytes: int = 2048
@@ -164,9 +188,12 @@ class DevicePlane:
         self._masks = np.zeros(
             mask_mirror_shape(c.num_user_slots, c.topic_words), np.uint32)
         self._quarantine: List[int] = []   # slots awaiting step completion
-        # users the slot table couldn't hold: broadcasts must stay on the
-        # host path while any exist (they'd miss device-only fan-out)
+        # users beyond MAX_USER_SLOTS: broadcasts must stay on the host
+        # path while any exist (they'd miss device-only fan-out)
         self._unmirrored: set[bytes] = set()
+        self.table_grows = 0        # times the user table doubled
+        # the user dimension whose two step programs are loaded
+        self._loaded_users = 0
         # mirror revision: device state re-uploads only when it changed
         # (pump_common.RevCache holds the device copy)
         self._state_rev = 0
@@ -251,16 +278,70 @@ class DevicePlane:
             else:  # still too big: wait for a further halving
                 self._ragged_retry_below = max(len(self._ragged) // 2, 1)
 
+    @property
+    def user_slots(self) -> int:
+        """The user table's live capacity (``cdn_device_user_slots``)."""
+        return self.slots.capacity
+
+    def _grow_table(self) -> bool:
+        """Double the user table and its mirrors (event loop only). A
+        step in flight keeps the copies it took and the slot ids it
+        carries: growth moves no binding and frees no quarantined slot.
+        False at the ceiling."""
+        old = self.slots.capacity
+        if old >= MAX_USER_SLOTS:
+            return False
+        new = min(2 * old, MAX_USER_SLOTS)
+        self.slots.grow(new)
+        for name in ("_owned", "_masks"):
+            mirror = getattr(self, name)
+            grown = np.zeros((new,) + mirror.shape[1:], mirror.dtype)
+            grown[:old] = mirror
+            setattr(self, name, grown)
+        self.table_grows += 1
+        self._state_rev += 1
+        logger.info("device user-slot table grew from %d to %d slots",
+                    old, new)
+        self._kick.set()  # the pump loads the new size's programs now
+        return True
+
+    def _step_users(self) -> int:
+        """The user dimension a step runs at."""
+        return effective_users(self.slots.high_water, self.slots.capacity)
+
+    async def _load_programs(self) -> None:
+        """Compile (or load) both step programs of the user dimension the
+        next step runs at, unless they are loaded (the pump only). The
+        connection that doubles the table is the one that moves that
+        dimension past the old capacity, and it wakes the pump: the
+        programs compile beside the rest of the connect storm (1.8 s at
+        8,192 rows on a cold cache), not inside the first burst after it.
+        A table that grows again meanwhile goes round once more."""
+        while (users := self._step_users()) != self._loaded_users:
+            # the walks are packed here: the page index belongs to the loop
+            lanes = self._compile_lanes()
+            try:
+                await asyncio.to_thread(self._compile_for, users, *lanes)
+            except Exception:
+                # the step that needs them compiles them itself, and a
+                # failure there disables the plane loudly
+                logger.exception("compiling the %d-row step programs "
+                                 "failed", users)
+                self._loaded_users = users
+
     def on_user_added(self, public_key: bytes, topics) -> None:
-        try:
-            slot = self.slots.assign(public_key)
-        except Error:
-            # table full: this user is host-routed only; never fail the
-            # registration over the mirror
+        slots = self.slots
+        if slots.slot_of(public_key) is None and slots.full \
+                and not self._grow_table():
+            # past the ceiling: this user is host-routed only; never fail
+            # the registration over the mirror
             self._unmirrored.add(public_key)
-            logger.warning("device user-slot table full; %d unmirrored users",
-                           len(self._unmirrored))
+            logger.warning(
+                "device user-slot table full at its ceiling of %d slots; "
+                "%d unmirrored users keep every broadcast on the host path",
+                MAX_USER_SLOTS, len(self._unmirrored))
             return
+        slot = slots.assign(public_key)
         self._owned[slot] = True
         self._masks[slot] = mask_row_of(topics, self.config.topic_words)
         self._ragged_set_mask(slot, topics)
@@ -416,8 +497,9 @@ class DevicePlane:
     def kernels(self) -> dict:
         """Which implementation each step shape dispatches to on this
         backend ("pallas" = compiled by Mosaic on a TPU, interpreted
-        elsewhere; "xla" = the jnp twin through XLA). The user dimension
-        moves in buckets of 64, which never changes the answer."""
+        elsewhere; "xla" = the jnp twin through XLA), at the table's live
+        capacity. The user dimension moves in powers of two from 64,
+        which never changes the answer."""
         from pushcdn_tpu.ops.delivery_kernel import selects_pallas
         from pushcdn_tpu.ops.ragged_delivery import ragged_selects_pallas
         from pushcdn_tpu.parallel import router
@@ -426,7 +508,7 @@ class DevicePlane:
                  for width, slots in c.lane_shapes()}
         dense[f"latency[{c.latency_slots}]"] = c.latency_slots
         out = {name: ("pallas" if selects_pallas(
-            c.num_user_slots, n, router.USE_PALLAS_DELIVERY) else "xla")
+            self.slots.capacity, n, router.USE_PALLAS_DELIVERY) else "xla")
             for name, n in dense.items()}
         if self.delivery_impl == "ragged":
             out["ragged"] = ("pallas" if ragged_selects_pallas(
@@ -452,6 +534,9 @@ class DevicePlane:
             "egress_queued": self.egress_queued,
             "mirrored_users": len(self.slots),
             "unmirrored_users": len(self._unmirrored),
+            "user_slots": self.user_slots,
+            "user_high_water": self.slots.high_water,
+            "table_grows": self.table_grows,
         }
 
     def _pack_walks(self, batches):
@@ -473,25 +558,33 @@ class DevicePlane:
             return None
         return walks
 
-    def _warmup(self) -> None:
-        from pushcdn_tpu.parallel.frames import slice_batch
-        empty = [r.take_batch() for r in self.rings]
-        lat = [slice_batch(b, self.config.latency_slots) for b in empty]
-        u0 = effective_users(0, self.config.num_user_slots)
-        # compile the only two specializations the pump uses: all lanes
-        # at full shapes (idle lanes ride cached device empties) and
-        # the latency-sliced base lane; wider user buckets compile on
-        # first growth past the mark
+    def _compile_lanes(self) -> tuple:
+        """Empty lane batches, and their walks on a ragged plane: what a
+        compile-only step runs on."""
+        from pushcdn_tpu.parallel.frames import empty_batch
+        empty = [empty_batch(r.slots, r.frame_bytes, r.topic_words)
+                 for r in self.rings]
         walks = self._pack_walks(empty) \
             if self.delivery_impl == "ragged" else None
-        self._run_step(empty, self._owned[:u0].copy(),
-                       self._masks[:u0].copy(), walks=walks,
-                       compile_only=True)
-        self._run_step(lat[:1], self._owned[:u0].copy(),
-                       self._masks[:u0].copy(),
+        return empty, walks
+
+    def _warmup(self) -> None:
+        self._compile_for(self._step_users(), *self._compile_lanes())
+
+    def _compile_for(self, users: int, empty: list, walks) -> None:
+        """Compile (or load) the only two specializations the pump uses
+        at ``users`` rows: all lanes at full shapes (idle lanes ride
+        cached device empties) and the latency-sliced base lane."""
+        from pushcdn_tpu.parallel.frames import slice_batch
+        c = self.config
+        lat = slice_batch(empty[0], c.latency_slots)
+        owned = np.zeros(users, bool)
+        masks = np.zeros(mask_mirror_shape(users, c.topic_words), np.uint32)
+        self._run_step(empty, owned, masks, walks=walks, compile_only=True)
+        self._run_step([lat], owned, masks,
                        walks=None if walks is None else walks[:1],
                        compile_only=True)
-        self.steps -= 2  # warmup doesn't count
+        self._loaded_users = users
 
     async def stop(self) -> None:
         if self._task is not None:
@@ -512,6 +605,7 @@ class DevicePlane:
         while True:
             await self._kick.wait()
             self._kick.clear()
+            await self._load_programs()
             await asyncio.sleep(0)  # let same-tick stagers land
             staged = sum(r.slots - r.free_slots for r in self.rings)
             wait = gate.wait_s(staged, loop.time())
@@ -530,14 +624,13 @@ class DevicePlane:
             step = self.steps
             waited = time.monotonic() - self._staged_since
             self._staged_since = None
+            u_eff = self._step_users()
             with spans.span("plane.take", step=step, frames=staged,
-                            ring_wait_us=int(waited * 1e6)):
+                            ring_wait_us=int(waited * 1e6), users=u_eff):
                 # snapshot mirrors + all lane rings in ONE event-loop tick
                 batches_np = [r.take_batch() for r in self.rings]
                 if small:
                     batches_np = [slice_batch(batches_np[0], lat)]
-                u_eff = effective_users(self.slots.high_water,
-                                        c.num_user_slots)
                 owned = self._owned[:u_eff].copy()
                 masks = self._masks[:u_eff].copy()
                 rev = self._state_rev
@@ -681,18 +774,18 @@ class DevicePlane:
                     if self.config.ragged_relaxed_order:
                         # per-topic FIFO only (see the config knob's docs)
                         users, frame_idx = ragged_pairs_grouped(
-                            out_user, walk,
-                            num_users=self.config.num_user_slots)
+                            out_user, walk, num_users=len(owned))
                     else:
                         # strict: per-user order identical to the dense
                         # plane
                         users, frame_idx = ragged_pairs(
                             out_user, walk.walk_frame,
-                            num_users=self.config.num_user_slots)
+                            num_users=len(owned))
                 if len(users):
                     jobs.append((None, (users, frame_idx), b.length,
                                  b.bytes_))
-            self.steps += 1
+            if not compile_only:
+                self.steps += 1
             if routed_ragged:  # warmup compile runs don't count as ticks
                 self.ragged_steps += 1
             return jobs
@@ -704,13 +797,13 @@ class DevicePlane:
         with span("plane.dispatch", step=step):
             result = routing_step_lanes_single(state, batches,
                                                gather_bytes=False)
-        self.steps += 1
         if compile_only:
             # warm-up: a step that compiles but dies on the device must
             # fail start-up, not the first real tick
             for lane in result.lanes:
                 lane.deliver.block_until_ready()
             return []
+        self.steps += 1
         jobs = []
         for li, lane in enumerate(result.lanes):
             if not busy[li]:

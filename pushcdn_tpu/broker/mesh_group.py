@@ -201,6 +201,10 @@ class MeshShardPlane:
         return self.group.steps
 
     @property
+    def user_slots(self) -> int:
+        return self.group.slots.capacity
+
+    @property
     def frames_staged(self) -> int:
         return self.group.frames_staged
 

@@ -12,6 +12,10 @@ NUM_BROKERS_CONNECTED = Gauge("cdn_num_brokers_connected",
 # updated by broker.update_metrics() from the attached plane's counters
 DEVICE_STEPS = Gauge("cdn_device_steps",
                      "Routing steps executed by the attached device plane")
+DEVICE_USER_SLOTS = Gauge(
+    "cdn_device_user_slots",
+    "Capacity of the device plane's user table (it doubles when a "
+    "connection finds it full)")
 DEVICE_FRAMES_STAGED = Gauge(
     "cdn_device_frames_staged",
     "Frames accepted into the device plane's staging rings")

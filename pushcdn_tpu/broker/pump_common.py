@@ -5,9 +5,9 @@ one home keeps them in lockstep):
 - the adaptive coalescing gate (step immediately on bursts-after-idle and
   saturated pipelines; wait one window for a steady sub-threshold
   trickle),
-- the user-table slice mark (round the slot high-water up to a bucket so
-  delivery matrices, their D2H, and the egress scans pay for the actual
-  population, while the jit key only moves once per bucket),
+- the user-table slice mark (round the slot high-water up to a power of
+  two so delivery matrices, their D2H, and the egress scans pay for the
+  actual population, while the jit key only moves when it doubles),
 - the revision-keyed device-state cache (steady state pays zero H2D for
   the user table).
 """
@@ -18,16 +18,18 @@ from typing import Any, Callable, Optional
 
 from pushcdn_tpu.parallel.frames import mask_of_topics
 
-# user-table slice granularity (jit keys move once per bucket)
+# the smallest user-table slice
 U_ROUND = 64
 
 
 def effective_users(high_water: int, capacity: int,
                     round_to: int = U_ROUND) -> int:
-    """Slice mark for the user table: ``high_water`` rounded up to a
-    bucket, clamped to capacity, at least one bucket."""
-    return min(capacity, max(round_to,
-                             -(-high_water // round_to) * round_to))
+    """Slice mark for the user table: the smallest power of two that
+    holds ``high_water``, at least ``round_to``, clamped to capacity. A
+    table that doubles when it is full crosses a mark exactly when it
+    grows, so the shape a connect storm ends at is known (and can be
+    compiled) half the storm before its last user arrives."""
+    return min(capacity, max(round_to, 1 << (high_water - 1).bit_length()))
 
 
 class CoalesceGate:

@@ -100,6 +100,22 @@ class UserSlots:
         # matrices, not 1024-user ones
         self.high_water = 0
 
+    @property
+    def full(self) -> bool:
+        """No free slot (quarantined ones do not count as free)."""
+        return not self._free
+
+    def grow(self, capacity: int) -> None:
+        """Extend the table to ``capacity`` slots. Bindings, recycled free
+        slots and quarantined ones (unmapped, not yet freed) keep their
+        indices; the new range goes UNDER the free list, so recycled low
+        slots are still handed out first and ``high_water`` stays tight."""
+        if capacity <= self.capacity:
+            return
+        self._slot_to_key.extend([None] * (capacity - self.capacity))
+        self._free[:0] = range(capacity - 1, self.capacity - 1, -1)
+        self.capacity = capacity
+
     def assign(self, public_key: bytes) -> int:
         slot = self._key_to_slot.get(public_key)
         if slot is not None:

@@ -29,7 +29,9 @@ The spans (the names are what ``benchmark/span_reduce.py`` reads):
 ``ingress.stage``     event loop  its one ``stage_batch`` call; ``frames``,
                                   ``staged``
 ``plane.take``        event loop  the pump's snapshot of rings and mirrors;
-                                  ``step``, ``frames``, ``ring_wait_us``
+                                  ``step``, ``frames``, ``ring_wait_us``,
+                                  ``users`` (the step's user dimension;
+                                  single-shard plane)
 ``plane.h2d``         worker      state and lane batches to the device; ``step``
 ``plane.dispatch``    worker      the jitted step's call; ``step``
 ``plane.d2h``         worker      each read-back of a decision; ``step``

@@ -36,6 +36,7 @@ from pushcdn_tpu.proto.def_ import testing_run_def
 from pushcdn_tpu.proto.discovery.base import BrokerIdentifier
 from pushcdn_tpu.proto.discovery.embedded import Embedded
 from pushcdn_tpu.proto.transport import Tcp
+from pushcdn_tpu.testing.ports import free_port_block
 
 N_SHARDS = 8
 
@@ -147,15 +148,12 @@ async def make_two_host_node(rank: int, base: int, db: str, *,
 def spawn_worker_pair(worker_path: str, extra_args: List[str],
                       cwd: Optional[str] = None, pipe: bool = True,
                       log_dir: Optional[str] = None):
-    """Parent-side harness: pick a free coordinator port, spawn the two
+    """Parent-side harness: pick a free block of ports, spawn the two
     ranked worker processes with a jax-clean env, and return
     ``(procs, base_port)``. Callers own communicate()/asserts.
     ``log_dir`` redirects each worker to ``rank<N>.log`` there instead
     of a pipe (full output survives even when a worker is killed)."""
-    import socket
-    with socket.socket() as s:
-        s.bind(("127.0.0.1", 0))
-        base = s.getsockname()[1]
+    base = free_port_block()
     env = {k: v for k, v in os.environ.items()
            if k not in ("JAX_PLATFORMS", "XLA_FLAGS")}
     procs = []

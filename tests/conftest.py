@@ -40,3 +40,16 @@ def pytest_pyfunc_call(pyfuncitem):
         asyncio.run(asyncio.wait_for(fn(**kwargs), timeout=120))
         return True
     return None
+
+
+@pytest.fixture(autouse=True)
+def _io_impl_env_stays_with_its_test():
+    """``uring.set_io_impl`` writes ``PUSHCDN_IO_IMPL`` into the environment
+    (for child processes): a test that selects io_uring must not hand it
+    to whichever test the worker runs next (xdist's file order moves)."""
+    saved = os.environ.get("PUSHCDN_IO_IMPL")
+    yield
+    if saved is None:
+        os.environ.pop("PUSHCDN_IO_IMPL", None)
+    else:
+        os.environ["PUSHCDN_IO_IMPL"] = saved

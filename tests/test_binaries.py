@@ -99,3 +99,28 @@ def test_every_binary_parses_help():
         out, _ = p.communicate(timeout=60)
         assert p.returncode == 0, f"{name} --help failed:\n{out}"
         assert "usage" in out.lower(), out[:200]
+
+
+def test_servers_raise_their_soft_fd_limit_to_the_hard_one():
+    """``bin/broker`` and ``bin/marshal`` call ``raise_nofile_limit`` at
+    start (ISSUE 27): a child started under a soft limit of 256 ends with
+    soft == hard and says both in its log."""
+    code = (
+        "import logging, resource, sys\n"
+        "logging.basicConfig(level=logging.INFO, stream=sys.stdout)\n"
+        "soft, hard = resource.getrlimit(resource.RLIMIT_NOFILE)\n"
+        "resource.setrlimit(resource.RLIMIT_NOFILE, (256, hard))\n"
+        "from pushcdn_tpu.bin.common import raise_nofile_limit\n"
+        "raise_nofile_limit()\n"
+        "now = resource.getrlimit(resource.RLIMIT_NOFILE)\n"
+        "print('LIMITS', now[0] == now[1] == hard, hard)\n")
+    proc = subprocess.run(
+        [sys.executable, "-c", code], capture_output=True, text=True,
+        timeout=60, cwd=REPO, env={**os.environ, "PYTHONPATH": REPO})
+    assert proc.returncode == 0, proc.stdout + proc.stderr
+    assert "LIMITS True" in proc.stdout, proc.stdout
+    hard = proc.stdout.split("LIMITS True ")[1].split()[0]
+    assert f"RLIMIT_NOFILE: soft 256 -> {hard} (hard {hard})" in proc.stdout
+    for name in ("broker", "marshal"):
+        with open(os.path.join(REPO, "pushcdn_tpu", "bin", f"{name}.py")) as f:
+            assert "    raise_nofile_limit()\n" in f.read(), name

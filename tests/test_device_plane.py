@@ -23,6 +23,30 @@ def test_user_slots_quarantine():
     assert c == a  # recycled only after explicit free
 
 
+def test_user_slots_grow_keeps_bindings_order_and_quarantine():
+    """grow() adds slots under the free list: bindings stay, a recycled
+    low slot is still handed out before any new one, a quarantined slot
+    stays out until freed, and high_water moves only with assignments."""
+    s = UserSlots(4)
+    slots = [s.assign(b"u%d" % i) for i in range(4)]
+    assert slots == [0, 1, 2, 3] and s.full
+    quarantined = s.unmap(b"u1")          # in flight: not reusable yet
+    s.release(b"u2")                      # recycled: reusable now
+    assert not s.full
+    s.grow(8)
+    s.grow(6)                             # never shrinks
+    assert s.capacity == 8 and s.high_water == 4
+    assert [s.slot_of(b"u0"), s.slot_of(b"u3")] == [0, 3]
+    assert s.key_of(7) is None
+    assert s.assign(b"v0") == 2           # the recycled slot first
+    assert s.assign(b"v1") == 4           # then the new range, lowest first
+    assert s.assign(b"v2") == 5 and s.high_water == 6
+    s.free_slot(quarantined)
+    assert s.assign(b"v3") == 1           # back in circulation only now
+    assert [s.assign(b"v%d" % i) for i in (4, 5)] == [6, 7] and s.full
+    assert len(s) == 8
+
+
 async def test_churn_during_device_traffic():
     """Users joining/leaving while steps are in flight never lose messages
     for connected users (the snapshot-per-step design)."""
@@ -61,11 +85,13 @@ async def test_churn_during_device_traffic():
         await cluster.stop()
 
 
-async def test_slot_table_exhaustion_falls_back_to_host():
-    """More users than device slots: registration still succeeds and
-    broadcasts take the host path (no silent misses)."""
+async def test_slot_table_exhaustion_falls_back_to_host(monkeypatch):
+    """More users than the table's ceiling holds: registration still
+    succeeds and broadcasts take the host path (no silent misses)."""
+    from pushcdn_tpu.broker import device_plane
     from pushcdn_tpu.broker.device_plane import DevicePlaneConfig
 
+    monkeypatch.setattr(device_plane, "MAX_USER_SLOTS", 2)
     cluster = await Cluster(num_brokers=1, device_plane=DevicePlaneConfig(
         num_user_slots=2, ring_slots=16, frame_bytes=1024,
         batch_window_s=0.002)).start()
@@ -79,6 +105,7 @@ async def test_slot_table_exhaustion_falls_back_to_host():
             lambda: cluster.brokers[0].connections.num_users == 4)
         device = cluster.brokers[0].device_plane
         assert len(device._unmirrored) == 2
+        assert device.table_grows == 0 and device.user_slots == 2
 
         # a broadcast must reach ALL FOUR users (host path because of the
         # unmirrored users)
@@ -139,13 +166,21 @@ def test_pump_common_helpers():
     from pushcdn_tpu.broker.pump_common import (
         CoalesceGate, RevCache, effective_users)
 
-    # user-table slice mark: bucket-rounded, clamped, never zero
+    # user-table slice mark: the power of two that holds the high-water
+    # mark, clamped, never under 64
     assert effective_users(0, 1024) == 64
     assert effective_users(1, 1024) == 64
     assert effective_users(64, 1024) == 64
     assert effective_users(65, 1024) == 128
+    assert effective_users(129, 1024) == 256
+    assert effective_users(1000, 1024) == 1024
     assert effective_users(5000, 1024) == 1024
-    assert effective_users(10, 32) == 32  # capacity below one bucket
+    assert effective_users(10, 32) == 32  # capacity below the least mark
+    # a table that doubles when full crosses a mark exactly when it grows
+    assert effective_users(1024, 1024) == 1024
+    assert effective_users(1025, 2048) == 2048
+    assert effective_users(4096, 4096) == 4096
+    assert effective_users(5000, 8192) == 8192
 
     # coalescing gate: burst-after-idle and saturation step immediately,
     # a recent-step trickle waits one window

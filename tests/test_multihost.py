@@ -20,6 +20,7 @@ from pushcdn_tpu.parallel.multihost import (
     local_shard_indices,
     pod_broker_mesh,
 )
+from pushcdn_tpu.testing.ports import free_port_block
 
 
 _PROBE = r"""
@@ -131,9 +132,7 @@ def test_two_process_multihost_deployment():
     throughout (see tests/_multihost_worker.py)."""
     _require_two_process_runtime()
     import tempfile
-    with socket.socket() as s:
-        s.bind(("127.0.0.1", 0))
-        base = s.getsockname()[1]
+    base = free_port_block()
     db = os.path.join(tempfile.mkdtemp(prefix="pushcdn-mh-"), "d.sqlite")
     worker = os.path.join(os.path.dirname(__file__), "_multihost_worker.py")
     env = {k: v for k, v in os.environ.items()
@@ -173,9 +172,7 @@ def test_two_process_stall_and_redeploy():
     import time as _time
 
     tmp = tempfile.mkdtemp(prefix="pushcdn-stall-")
-    with socket.socket() as s:
-        s.bind(("127.0.0.1", 0))
-        base = s.getsockname()[1]
+    base = free_port_block()
     db = os.path.join(tmp, "d.sqlite")
     worker = os.path.join(os.path.dirname(__file__),
                           "_multihost_stall_worker.py")
@@ -226,9 +223,7 @@ def test_two_process_stall_and_redeploy():
                 p.communicate(timeout=30)
 
     # ---- phase 2: a fresh group redeploys WITHOUT the stalled host -------
-    with socket.socket() as s:
-        s.bind(("127.0.0.1", 0))
-        base2 = s.getsockname()[1]
+    base2 = free_port_block()
     db2 = os.path.join(tmp, "d2.sqlite")
     worker2 = os.path.join(os.path.dirname(__file__), "_multihost_worker.py")
     procs2 = [
@@ -273,9 +268,7 @@ def test_two_process_kill_and_redeploy():
     import time as _time
 
     tmp = tempfile.mkdtemp(prefix="pushcdn-kill-")
-    with socket.socket() as s:
-        s.bind(("127.0.0.1", 0))
-        base = s.getsockname()[1]
+    base = free_port_block()
     db = os.path.join(tmp, "d.sqlite")
     worker = os.path.join(os.path.dirname(__file__),
                           "_multihost_kill_worker.py")
@@ -320,9 +313,7 @@ def test_two_process_kill_and_redeploy():
                 p.communicate(timeout=30)
 
     # ---- phase 2: redeployment heals the deployment ----------------------
-    with socket.socket() as s:
-        s.bind(("127.0.0.1", 0))
-        base2 = s.getsockname()[1]
+    base2 = free_port_block()
     db2 = os.path.join(tmp, "d2.sqlite")
     worker2 = os.path.join(os.path.dirname(__file__), "_multihost_worker.py")
     procs2 = [

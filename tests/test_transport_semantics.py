@@ -142,6 +142,31 @@ def test_frame_encoder_overflow_returns_none():
     assert enc.encode([b"c" * 200, b"d" * 200]) is None
 
 
+def test_frame_encoder_buffer_grows_with_the_batches_up_to_capacity():
+    """An encoder costs its owner (every connection's writer) 4 KiB until
+    a batch asks for more; up to ``capacity`` the stream is the same
+    bytes, beyond it the encoder still refuses."""
+    import struct
+    enc = native.FrameEncoder.create(capacity=256 * 1024)
+    if enc is None:
+        pytest.skip("native library unavailable")
+    assert len(enc._out) == 4096
+
+    def framed(payloads):
+        return b"".join(struct.pack(">I", len(p)) + p for p in payloads)
+
+    for payloads in ([b"a" * 1000], [b"b" * 3000, b"c" * 3000],
+                     [bytes([i]) * 9000 for i in range(20)], [b"d" * 10]):
+        view = enc.encode(payloads)
+        assert bytes(view) == framed(payloads)
+        view.release()
+    assert 180_080 <= len(enc._out) <= 256 * 1024
+    assert enc.encode([b"e" * 9000] * 30) is None      # 270 KB: refused
+    view = enc.encode([b"f" * 262_140])                # exactly capacity
+    assert len(view) == 256 * 1024 == len(enc._out)
+    view.release()
+
+
 async def test_writer_falls_back_when_batch_exceeds_encoder_capacity():
     # a queued batch far beyond the native encoder capacity must still
     # arrive intact via the Python coalescing fallback
