@@ -51,6 +51,8 @@ Flow per step:
   ingress: user_receive_loop → try_stage() → FrameRing (slot credits)
   compute: snapshot + take_batch → routing_step_lanes_single (jitted)
   egress:  deliver[u, f] → per-user non-blocking send of the frame bytes
+  drain:   after an egress the pump wrote itself (the loop stood still),
+           the receive loops stage what the sockets hold before the take
 """
 
 from __future__ import annotations
@@ -110,6 +112,16 @@ logger = logging.getLogger("pushcdn.broker.device")
 # what a step brings back: the dense decision is bool[users, ring_slots]
 # per busy lane, 64 MiB through D2H and the egress scan at 65,536 x 1,024.
 MAX_USER_SLOTS = 65536
+
+# Loop passes the pump yields to let the receive loops stage what the
+# sockets hold before it takes its batch (``DevicePlane._drain``). A frame
+# in a socket is three passes from the rings: the selector's reader
+# callback feeds the stream, the connection's reader task parses it, the
+# user's receive loop stages it. The pump's own handle runs first in every
+# pass, so it sees that frame in its fourth. No more than the chain: what
+# arrives while the pump drains has no claim on this step, and waiting for
+# it is coalescing, ``CoalesceGate``'s decision.
+_DRAIN_PASSES = 4
 
 
 @dataclass
@@ -232,8 +244,15 @@ class DevicePlane:
         self._kick = asyncio.Event()
         self._task: Optional[asyncio.Task] = None
         self._step_inflight = False
+        # True from the end of a ``plane.egress`` in which the pump wrote
+        # streams itself (the loop stood still: the sockets hold that
+        # time's frames) until the drain before the next take is over
+        self._between_steps = False
         self.steps = 0
         self.frames_staged = 0      # frames accepted into a ring
+        # of those, staged while the pump drained the sockets into the
+        # rings between an egress it wrote itself and its next take
+        self.frames_drained = 0
         # monotonic time at which the rings last went from empty to
         # non-empty (None while empty): the age of the oldest staged
         # frame at the next take is ``plane.take``'s ``ring_wait_us``
@@ -372,10 +391,17 @@ class DevicePlane:
 
     def _idle_bypass(self, n_items: int) -> bool:
         """True when the latency regime should skip the device entirely:
-        nothing staged, no step in flight, and the arriving batch is
-        small — host-routing now beats waiting a step dispatch."""
+        the arriving batch is small and the plane is idle — host-routing
+        now beats waiting a step dispatch. Idle is exactly: the pump is
+        parked on ``_kick.wait()`` with empty rings. It is not idle while
+        a step is in flight, nor between an egress the pump wrote itself
+        and its next take: the loop stood still all through those
+        ``send()``s, a period's frames sit in the sockets, and the first
+        receive loop to run would else host-route its lone frame to every
+        subscriber beside the step that is about to carry the rest."""
         return (n_items <= self.config.bypass_max_items
                 and not self._step_inflight
+                and not self._between_steps
                 and all(r.free_slots == r.slots for r in self.rings))
 
     def try_stage(self, message, raw: Bytes) -> StageResult:
@@ -529,6 +555,7 @@ class DevicePlane:
             "warmup_s": self.warmup_s,
             "steps": self.steps,
             "frames_staged": self.frames_staged,
+            "frames_drained": self.frames_drained,
             "messages_routed": self.messages_routed,
             "egress_inline": self.egress_inline,
             "egress_queued": self.egress_queued,
@@ -596,6 +623,31 @@ class DevicePlane:
             except Exception:
                 logger.exception("device pump died during stop")
 
+    async def _drain(self) -> int:
+        """Yield to the loop until the receive loops have staged what the
+        sockets held when ``plane.egress`` returned; the number of frames
+        staged meanwhile. While the pump writes a step's streams itself
+        the loop does not turn, so that time's frames wait in socket
+        buffers, and one ``sleep(0)`` does not fetch them: the pump's
+        handle is queued before the selector's reader callbacks of the
+        next pass and runs first, and a frame is three passes from the
+        rings (``_DRAIN_PASSES``). Without the drain the take snapshots
+        rings that hold only what came during the short worker phase, and
+        the rest rides the step after next. Passes, never a timer, and
+        none once the base lane is full (its stagers are back-pressured:
+        nothing can join the step). It changes when a frame is staged,
+        not the order: one receive loop per connection stages a
+        publisher's frames as they came."""
+        first = self.frames_staged
+        try:
+            for _ in range(_DRAIN_PASSES):
+                if not self.rings[0].free_slots:
+                    break
+                await asyncio.sleep(0)
+        finally:
+            self._between_steps = False
+        return self.frames_staged - first
+
     async def _pump(self) -> None:
         from pushcdn_tpu.broker.tasks.senders import egress_streams
         from pushcdn_tpu.parallel.frames import slice_batch
@@ -603,10 +655,16 @@ class DevicePlane:
         loop = asyncio.get_running_loop()
         gate = CoalesceGate(c.batch_window_s, c.coalesce_min_frames)
         while True:
+            drained = await self._drain() if self._between_steps else 0
             await self._kick.wait()
             self._kick.clear()
             await self._load_programs()
-            await asyncio.sleep(0)  # let same-tick stagers land
+            # From a parked pump: let the stagers of this pass land (a
+            # burst's receive loops are already runnable). The pump's
+            # handle runs BEFORE the reader callbacks the selector adds
+            # to the pass it resumes in, so this fetches nothing that
+            # still sits in a socket: that is ``_drain``'s work.
+            await asyncio.sleep(0)
             staged = sum(r.slots - r.free_slots for r in self.rings)
             wait = gate.wait_s(staged, loop.time())
             if wait:
@@ -625,8 +683,10 @@ class DevicePlane:
             waited = time.monotonic() - self._staged_since
             self._staged_since = None
             u_eff = self._step_users()
+            self.frames_drained += drained
             with spans.span("plane.take", step=step, frames=staged,
-                            ring_wait_us=int(waited * 1e6), users=u_eff):
+                            ring_wait_us=int(waited * 1e6), users=u_eff,
+                            drained=drained):
                 # snapshot mirrors + all lane rings in ONE event-loop tick
                 batches_np = [r.take_batch() for r in self.rings]
                 if small:
@@ -665,6 +725,11 @@ class DevicePlane:
                         deliveries=self.messages_routed - routed,
                         inline=self.egress_inline - inline,
                         queued=self.egress_queued - queued)
+                # the pump's own ``send()``s held the loop: drain the
+                # sockets before the next take. Streams that were all
+                # queued for their writers were no such hold (the loop
+                # turned beside the step)
+                self._between_steps = self.egress_inline != inline
             except asyncio.CancelledError:
                 raise
             except Exception:

@@ -2,6 +2,8 @@
 churn under traffic, slot-table exhaustion fallback."""
 
 import asyncio
+import contextlib
+import os
 
 import pytest
 
@@ -379,12 +381,11 @@ def _mixed_traffic(seed: int):
     return topics, plan, owed
 
 
-async def _serve_mixed_traffic(seed: int, device_plane):
-    """Run the seeded traffic through one broker with real TCP user links;
-    returns what each user received, what it was owed, and the plane (or
-    None)."""
-    import os
-    import socket
+@contextlib.asynccontextmanager
+async def _served_over_tcp(seed: int, device_plane, topics):
+    """One broker (with ``device_plane``, or the plain host router) and a
+    marshal, with one connected client per entry of ``topics`` over real
+    TCP user links: ``(broker, clients)``."""
     import tempfile
 
     from pushcdn_tpu.bin.common import free_ports
@@ -415,13 +416,31 @@ async def _serve_mixed_traffic(seed: int, device_plane):
         run_def=run_def, discovery_endpoint=db,
         bind_endpoint=f"127.0.0.1:{marshal_port}"))
     await marshal.start()
-    keypairs = [DEFAULT_SCHEME.generate_keypair(seed=seed + 1 + u)
-                for u in range(_N_USERS)]
-    topics, plan, owed = _mixed_traffic(seed)
     clients = [Client(ClientConfig(
-        marshal_endpoint=f"127.0.0.1:{marshal_port}", keypair=keypairs[u],
-        protocol=Tcp, subscribed_topics=topics[u]))
-        for u in range(_N_USERS)]
+        marshal_endpoint=f"127.0.0.1:{marshal_port}",
+        keypair=DEFAULT_SCHEME.generate_keypair(seed=seed + 1 + u),
+        protocol=Tcp, subscribed_topics=set(topics[u])))
+        for u in range(len(topics))]
+    try:
+        for c in clients:
+            await c.ensure_initialized()
+        await wait_until(
+            lambda: broker.connections.num_users == len(clients))
+        yield broker, clients
+    finally:
+        for c in clients:
+            c.close()
+        await marshal.stop()
+        await broker.stop()
+
+
+async def _serve_mixed_traffic(seed: int, device_plane):
+    """Run the seeded traffic through one broker with real TCP user links;
+    returns what each user received, what it was owed, and the plane (or
+    None)."""
+    import socket
+
+    topics, plan, owed = _mixed_traffic(seed)
     got = [{} for _ in range(_N_USERS)]
     counts = [0] * _N_USERS
 
@@ -442,38 +461,34 @@ async def _serve_mixed_traffic(seed: int, device_plane):
                     clients[target].public_key, payload)
 
     drains = []
-    try:
-        for c in clients:
-            await c.ensure_initialized()
-        await wait_until(
-            lambda: broker.connections.num_users == _N_USERS)
-        # the slow reader: it stops reading, behind socket buffers small
-        # enough that its link backs up within the traffic
-        slow = clients[_SLOW]._connection._stream
-        slow.writer.get_extra_info("socket").setsockopt(
-            socket.SOL_SOCKET, socket.SO_RCVBUF, 4096)
-        broker.connections.get_user_connection(clients[_SLOW].public_key) \
-            ._stream.writer.get_extra_info("socket").setsockopt(
-                socket.SOL_SOCKET, socket.SO_SNDBUF, 4096)
-        slow.reader._transport.pause_reading()
-        drains = [asyncio.create_task(drain(u)) for u in range(_N_USERS)]
-        await asyncio.gather(*(publish(p) for p in range(_PUBLISHERS)))
-        fast = [u for u in range(_N_USERS) if u != _SLOW]
-        await wait_until(lambda: all(
-            counts[u] == sum(map(len, owed[u].values())) for u in fast),
-            timeout=30)
-        slow.reader._transport.resume_reading()  # well inside the timeout
-        await wait_until(
-            lambda: counts[_SLOW] == sum(map(len, owed[_SLOW].values())),
-            timeout=30)
-        assert broker.connections.num_users == _N_USERS  # nobody removed
-    finally:
-        for t in drains:
-            t.cancel()
-        for c in clients:
-            c.close()
-        await marshal.stop()
-        await broker.stop()
+    async with _served_over_tcp(seed, device_plane, topics) as (broker,
+                                                                clients):
+        try:
+            # the slow reader: it stops reading, behind socket buffers
+            # small enough that its link backs up within the traffic
+            slow = clients[_SLOW]._connection._stream
+            slow.writer.get_extra_info("socket").setsockopt(
+                socket.SOL_SOCKET, socket.SO_RCVBUF, 4096)
+            broker.connections.get_user_connection(
+                clients[_SLOW].public_key) \
+                ._stream.writer.get_extra_info("socket").setsockopt(
+                    socket.SOL_SOCKET, socket.SO_SNDBUF, 4096)
+            slow.reader._transport.pause_reading()
+            drains = [asyncio.create_task(drain(u))
+                      for u in range(_N_USERS)]
+            await asyncio.gather(*(publish(p) for p in range(_PUBLISHERS)))
+            fast = [u for u in range(_N_USERS) if u != _SLOW]
+            await wait_until(lambda: all(
+                counts[u] == sum(map(len, owed[u].values())) for u in fast),
+                timeout=30)
+            slow.reader._transport.resume_reading()  # well inside the timeout
+            await wait_until(
+                lambda: counts[_SLOW] == sum(map(len, owed[_SLOW].values())),
+                timeout=30)
+            assert broker.connections.num_users == _N_USERS  # nobody removed
+        finally:
+            for t in drains:
+                t.cancel()
     return got, owed, broker.device_plane
 
 
@@ -510,3 +525,267 @@ async def test_served_egress_matches_the_host_router_with_a_slow_reader(
     described = plane.describe()
     assert (described["egress_inline"], described["egress_queued"]) == \
         (plane.egress_inline, plane.egress_queued)
+
+
+# ---------------------------------------------------------------------------
+# the pump's drain (ISSUE 28): after a ``plane.egress`` in which the pump
+# wrote streams itself the loop has stood still, so what publishers sent
+# meanwhile sits in their sockets. It is staged before the next take and
+# rides that step, and the plane does not count as idle meanwhile.
+# ---------------------------------------------------------------------------
+
+_SMALL_PLANE = dict(num_user_slots=32, ring_slots=64, frame_bytes=1024,
+                    batch_window_s=0.002)
+
+
+def _wire(*payloads: bytes, topic: int = 0) -> bytes:
+    """Broadcasts on ``topic`` as a publisher's kernel holds them: each
+    frame behind the transport's u32 length."""
+    import struct
+
+    from pushcdn_tpu.proto.message import Broadcast, serialize
+    frames = [serialize(Broadcast(topics=[topic], message=p))
+              for p in payloads]
+    return b"".join(struct.pack(">I", len(f)) + f for f in frames)
+
+
+def _socket_of(client) -> int:
+    """The file descriptor of a client's TCP socket (asyncio's own handle
+    on it has no ``send``)."""
+    return client._connection._stream.writer.get_extra_info(
+        "socket").fileno()
+
+
+def _write_during_egress(monkeypatch, sock: int, armed: list):
+    """While ``armed`` holds wire bytes, the pump's next hand-off of a
+    stream first writes them into ``sock``: a remote publisher's frames
+    reaching the broker's socket while ``plane.egress`` holds the loop
+    (clients of this process share that loop and cannot write then), and
+    that egress holds it past the coalescing gate's memory of a step (4 x
+    ``batch_window_s``), as the ``send()``s of a wide fan-out do."""
+    import time
+
+    from pushcdn_tpu.broker.tasks import senders
+    real = senders.try_send_encoded_to_user_nowait
+
+    def hand_off(*args, **kwargs):
+        if armed:
+            os.write(sock, armed.pop())
+            time.sleep(5 * _SMALL_PLANE["batch_window_s"])
+        return real(*args, **kwargs)
+    monkeypatch.setattr(senders, "try_send_encoded_to_user_nowait", hand_off)
+
+
+def _record_takes(plane, in_worker=lambda n_taken: None) -> list:
+    """Per step, the payloads its take held, in ring order. ``in_worker``
+    runs on the worker thread at the end of each step's device phase."""
+    from pushcdn_tpu.proto.message import deserialize
+    taken = []
+    real = plane._run_step
+
+    def run_step(batches, *args, **kwargs):
+        if kwargs.get("compile_only"):
+            return real(batches, *args, **kwargs)
+        taken.append([
+            bytes(deserialize(b.bytes_[i, :b.length[i]].tobytes()).message)
+            for b in batches for i in range(len(b.valid)) if b.valid[i]])
+        jobs = real(batches, *args, **kwargs)
+        in_worker(len(taken))
+        return jobs
+    plane._run_step = run_step
+    return taken
+
+
+async def _receive_all(clients, n: int) -> list:
+    """What each client received once each has ``n`` messages, and for a
+    moment after (a duplicate would land right behind)."""
+    got = [[] for _ in clients]
+
+    async def read(u):
+        while True:
+            for m in await clients[u].receive_messages():
+                got[u].append(bytes(m.message))
+    readers = [asyncio.create_task(read(u)) for u in range(len(clients))]
+    try:
+        await wait_until(lambda: all(len(g) >= n for g in got), timeout=20)
+        await asyncio.sleep(0.05)
+    finally:
+        for t in readers:
+            t.cancel()
+    return got
+
+
+async def test_frames_that_came_during_an_inline_egress_ride_the_next_step(
+        monkeypatch):
+    """Step N's worker phase stages one frame (so the pump does not park
+    after it), and five more reach the publisher's socket while step N's
+    egress holds the loop: they are in the take of step N+1, not N+2, and
+    every subscriber gets the publisher's order, each frame once."""
+    import threading
+
+    from pushcdn_tpu.broker.device_plane import DevicePlaneConfig
+
+    during = [b"during %d" % i for i in range(5)]
+    async with _served_over_tcp(
+            2801, DevicePlaneConfig(bypass_max_items=0, **_SMALL_PLANE),
+            [{0}] * 4) as (broker, clients):
+        plane = broker.device_plane
+        publisher = clients[0]
+        armed = [_wire(*during)]
+        _write_during_egress(monkeypatch, _socket_of(publisher), armed)
+        in_worker, go = threading.Event(), threading.Event()
+
+        def hold_step_0(n_taken):   # until "mid" is staged
+            if n_taken == 1:
+                in_worker.set()
+                go.wait(10)
+        taken = _record_takes(plane, hold_step_0)
+
+        await publisher.send_broadcast_message([0], b"first")
+        await wait_until(in_worker.is_set)
+        await publisher.send_broadcast_message([0], b"mid")
+        await wait_until(lambda: plane.frames_staged == 2)
+        go.set()
+        got = await _receive_all(clients, 7)
+
+        assert not armed and plane.egress_inline > 0
+        assert taken[:2] == [[b"first"], [b"mid"] + during], taken
+        assert got == [[b"first", b"mid"] + during] * 4
+        assert plane.frames_staged == 7 and plane.frames_drained == 5
+        assert plane.describe()["frames_drained"] == 5
+        assert not plane.disabled and not plane._between_steps
+
+
+@pytest.mark.parametrize("when", ["draining", "parked"])
+async def test_the_plane_is_idle_only_while_the_pump_is_parked(
+        when, monkeypatch):
+    """A lone frame (within ``bypass_max_items``) on empty rings with no
+    step in flight: between an egress the pump wrote itself and its next
+    take the plane is not idle and the frame is STAGED; with the pump
+    parked it is idle and the frame is host-routed (INELIGIBLE), the
+    contract ``echo-sparse`` runs on."""
+    from pushcdn_tpu.broker.device_plane import DevicePlaneConfig
+    from pushcdn_tpu.broker.staging import StageResult
+
+    async with _served_over_tcp(
+            2810, DevicePlaneConfig(bypass_max_items=2, **_SMALL_PLANE),
+            [{0}] * 3) as (broker, clients):
+        plane = broker.device_plane
+        sock = _socket_of(clients[0])
+        armed = [_wire(b"lone")] if when == "draining" else []
+        _write_during_egress(monkeypatch, sock, armed)
+        seen = []   # (batch size, was the plane idle, results) per batch
+        real = plane.stage_batch
+
+        def stage_batch(items):
+            idle = plane._idle_bypass(len(items))
+            results = real(items)
+            seen.append((len(items), idle, results))
+            return results
+        plane.stage_batch = stage_batch
+
+        burst = [b"burst %d" % i for i in range(4)]
+        os.write(sock, _wire(*burst))   # one read, one batch over the bypass
+        if when == "parked":
+            await wait_until(lambda: plane.messages_routed == 12)
+            await asyncio.sleep(0.05)   # the drain is over, the pump parked
+            assert not plane._between_steps and not plane._step_inflight
+            os.write(sock, _wire(b"lone"))
+        got = await _receive_all(clients, 5)
+
+        assert got == [burst + [b"lone"]] * 3 and not armed
+        assert plane.egress_inline > 0
+        assert seen[0] == (4, False, [StageResult.STAGED] * 4)
+        if when == "draining":
+            assert seen[1:] == [(1, False, [StageResult.STAGED])]
+            assert (plane.frames_staged, plane.frames_drained,
+                    plane.steps) == (5, 1, 2)
+        else:
+            assert seen[1:] == [(1, True, [StageResult.INELIGIBLE])]
+            assert (plane.frames_staged, plane.frames_drained,
+                    plane.steps) == (4, 0, 1)
+
+
+async def test_the_drain_is_skipped_after_an_all_queued_egress():
+    """Streams that all went to their writers did not hold the loop (the
+    Memory transport has no ``write_nowait``): the pump goes straight back
+    to ``_kick.wait()``."""
+    from pushcdn_tpu.broker.device_plane import DevicePlaneConfig
+
+    cluster = await Cluster(num_brokers=1, device_plane=DevicePlaneConfig(
+        bypass_max_items=0, **_SMALL_PLANE)).start()
+    try:
+        plane = cluster.brokers[0].device_plane
+        drains = []
+        real = plane._drain
+
+        async def drain():
+            drains.append(plane.steps)
+            return await real()
+        plane._drain = drain
+        c = cluster.client(seed=2820, topics=[0])
+        await c.ensure_initialized()
+        for round_ in range(3):
+            await asyncio.gather(*(
+                c.send_direct_message(c.public_key, b"%d %d" % (round_, i))
+                for i in range(8)))
+            got = 0
+            async with asyncio.timeout(20):
+                while got < 8:
+                    got += len(await c.receive_messages(8 - got))
+        assert plane.steps >= 3 and plane.egress_queued >= 3
+        assert plane.egress_inline == 0 and not drains
+        assert plane.frames_drained == 0 and not plane._between_steps
+        c.close()
+    finally:
+        await cluster.stop()
+
+
+@pytest.mark.parametrize("sockets", ["quiet", "endless", "full_lane"])
+async def test_the_drain_is_bounded_in_loop_passes(sockets):
+    """The chain's length in loop passes whether nothing comes (quiet) or a
+    publisher never stops writing (endless: a frame staged every pass),
+    and no pass at all on a full base lane (its stagers are
+    back-pressured), though the wide lane has room."""
+    from pushcdn_tpu.broker import device_plane
+    from pushcdn_tpu.broker.device_plane import DevicePlane, DevicePlaneConfig
+    from pushcdn_tpu.broker.staging import StageResult
+    from pushcdn_tpu.proto.limiter import Bytes
+    from pushcdn_tpu.proto.message import Broadcast, serialize
+
+    plane = DevicePlane(None, DevicePlaneConfig(
+        num_user_slots=32, ring_slots=16, frame_bytes=1024,
+        extra_lanes=((4096, 4),), bypass_max_items=0))
+    message = Broadcast(topics=[0], message=b"x")
+    frame = serialize(message)
+    passes = 0
+
+    def stage():
+        return plane.try_stage(message, Bytes(frame))
+
+    async def each_pass():
+        nonlocal passes
+        while True:
+            passes += 1
+            if sockets == "endless":
+                stage()
+            await asyncio.sleep(0)
+
+    if sockets == "full_lane":
+        while plane.rings[0].free_slots:
+            assert stage() == StageResult.STAGED
+        assert plane.rings[1].free_slots
+    ticker = asyncio.create_task(each_pass())
+    try:
+        await asyncio.sleep(0)   # the ticker is running
+        plane._between_steps = True
+        assert not plane._idle_bypass(1)
+        before = passes
+        drained = await asyncio.wait_for(plane._drain(), 10)
+        spent = passes - before
+    finally:
+        ticker.cancel()
+    assert spent == (0 if sockets == "full_lane"
+                     else device_plane._DRAIN_PASSES)
+    assert drained == (spent if sockets == "endless" else 0)
+    assert not plane._between_steps

@@ -107,6 +107,7 @@ async def test_traced_steps_yield_flat_joined_conserving_spans(
         staged0, routed0, steps0 = (plane.frames_staged,
                                     plane.messages_routed, plane.steps)
         handoffs0 = (plane.egress_inline, plane.egress_queued)
+        drained0 = getattr(plane, "frames_drained", 0)  # no group has it
         options = jax.profiler.ProfileOptions()
         options.python_tracer_level = 0
         options.host_tracer_level = 2
@@ -121,6 +122,7 @@ async def test_traced_steps_yield_flat_joined_conserving_spans(
         steps = plane.steps - steps0
         inline, queued = (plane.egress_inline - handoffs0[0],
                           plane.egress_queued - handoffs0[1])
+        drained = getattr(plane, "frames_drained", 0) - drained0
     finally:
         client.close()
         await cluster.stop()
@@ -181,3 +183,64 @@ async def test_traced_steps_yield_flat_joined_conserving_spans(
         total("ingress.stage", "frames") >= staged
     waits = [e[3]["ring_wait_us"] for e in events if e[0] == "plane.take"]
     assert all(0 <= w < trace_ns / 1e3 for w in waits), waits
+    _drained_conserves(events, drained)
+
+
+def _drained_conserves(events, drained: int) -> None:
+    """``plane.take``'s ``drained`` (frames the pump's drain staged before
+    that take) sums to the plane's ``frames_drained`` and never exceeds
+    the take's ``frames``."""
+    takes = [e[3] for e in events if e[0] == "plane.take"]
+    assert all(0 <= t.get("drained", 0) <= t["frames"] for t in takes), takes
+    assert sum(t.get("drained", 0) for t in takes) == drained
+
+
+async def test_traced_takes_report_what_the_drain_staged(
+        tmp_path, monkeypatch):
+    """Over real TCP links the pump writes the streams itself, so the
+    drain engages: frames that reach a socket during ``plane.egress`` are
+    the next take's ``drained``, all queued hand-offs leave it 0."""
+    import jax
+
+    from pushcdn_tpu.broker.device_plane import DevicePlaneConfig
+    from pushcdn_tpu.parallel import spans
+    from tests.test_device_plane import (
+        _SMALL_PLANE,
+        _receive_all,
+        _served_over_tcp,
+        _socket_of,
+        _wire,
+        _write_during_egress,
+    )
+    spans.bind()
+    late = [[b"late %d %d" % (r, i) for i in range(r + 1)] for r in range(3)]
+    async with _served_over_tcp(
+            2830, DevicePlaneConfig(bypass_max_items=0, **_SMALL_PLANE),
+            [{0}] * 2) as (broker, clients):
+        plane = broker.device_plane
+        sock = _socket_of(clients[0])
+        armed = []
+        _write_during_egress(monkeypatch, sock, armed)
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        options.host_tracer_level = 2
+        jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+        try:
+            for r, frames in enumerate(late):
+                armed.append(_wire(*frames))
+                os.write(sock, _wire(b"round %d" % r))
+                got = await _receive_all(clients, 1 + len(frames))
+                assert got == [[b"round %d" % r] + frames] * 2
+        finally:
+            jax.profiler.stop_trace()
+        staged, drained, described = (
+            plane.frames_staged, plane.frames_drained, plane.describe())
+    assert (staged, drained) == (9, 6)
+    assert (described["frames_staged"], described["frames_drained"]) == (9, 6)
+    threads, _ = _program_spans(str(tmp_path))
+    events = [e for evs in threads.values() for e in evs]
+    _drained_conserves(events, drained)
+    takes = sorted((e for e in events if e[0] == "plane.take"),
+                   key=lambda e: e[1])
+    assert [(t[3]["frames"], t[3]["drained"]) for t in takes] == [
+        (1, 0), (1, 1), (1, 0), (2, 2), (1, 0), (3, 3)]
