@@ -12,8 +12,14 @@
 //
 // Build: g++ -O3 -march=native -shared -fPIC framing.cpp -o libpushcdn_framing.so
 
+#include <sys/socket.h>
+
+#include <atomic>
+#include <cerrno>
 #include <cstdint>
 #include <cstring>
+#include <thread>
+#include <vector>
 
 extern "C" {
 
@@ -292,6 +298,39 @@ int64_t pushcdn_egress_fill(
     }
   }
   return total;
+}
+
+// One step's per-user sends as one call: entry i is, once,
+// send(fds[i], buf + offsets[i], nbytes[i], MSG_DONTWAIT | MSG_NOSIGNAL),
+// and out[i] its return: the bytes the socket took (fewer than nbytes[i]
+// when its buffer filled) or -errno. The entries are handed out one at a
+// time to `threads` threads, the caller's among them, which are joined
+// before the call returns: a loopback send() runs the receive side and
+// wakes the reader inside the syscall, 40-115 us of kernel work a socket
+// that one thread cannot shrink and several divide. The caller lists an
+// fd once and keeps every fd open and unwritten by others for the call.
+void pushcdn_send_batch(
+    const uint8_t* buf, const int32_t* fds, const int64_t* offsets,
+    const int64_t* nbytes, int32_t n, int32_t threads, int64_t* out) {
+  std::atomic<int32_t> next{0};
+  auto work = [&] {
+    for (int32_t i; (i = next.fetch_add(1, std::memory_order_relaxed)) < n;) {
+      ssize_t r;
+      do {
+        r = send(fds[i], buf + offsets[i], (size_t)nbytes[i],
+                 MSG_DONTWAIT | MSG_NOSIGNAL);
+      } while (r < 0 && errno == EINTR);
+      out[i] = r < 0 ? -(int64_t)errno : (int64_t)r;
+    }
+  };
+  std::vector<std::thread> pool;
+  try {
+    for (int32_t k = 1; k < threads && k < n; ++k) pool.emplace_back(work);
+  } catch (...) {
+    // no thread to be had: the ones there are, and the caller, do it all
+  }
+  work();
+  for (auto& t : pool) t.join();
 }
 
 }  // extern "C"

@@ -263,6 +263,9 @@ class DevicePlane:
         # user's writer task (senders.egress_streams tallies all three)
         self.egress_inline = 0
         self.egress_queued = 0
+        # of the inline ones, those one native call sent for a step whose
+        # take found the base lane full (senders.egress_streams)
+        self.egress_batched = 0
         self.warmup_s: Optional[float] = None
 
     # ---- user lifecycle (Connections observer; event-loop only) ----------
@@ -559,6 +562,7 @@ class DevicePlane:
             "messages_routed": self.messages_routed,
             "egress_inline": self.egress_inline,
             "egress_queued": self.egress_queued,
+            "egress_batched": self.egress_batched,
             "mirrored_users": len(self.slots),
             "unmirrored_users": len(self._unmirrored),
             "user_slots": self.user_slots,
@@ -684,6 +688,9 @@ class DevicePlane:
             self._staged_since = None
             u_eff = self._step_users()
             self.frames_drained += drained
+            # the base lane full at the take: its stagers wait on the
+            # step, so this step's length is the publishers' rate
+            back_pressured = not self.rings[0].free_slots
             with spans.span("plane.take", step=step, frames=staged,
                             ring_wait_us=int(waited * 1e6), users=u_eff,
                             drained=drained):
@@ -713,18 +720,20 @@ class DevicePlane:
                     self._step_inflight = False
                 gate.stepped(loop.time())
                 with spans.span("plane.egress", step=step) as sp:
-                    routed, inline, queued = (
+                    routed, inline, queued, batched = (
                         self.messages_routed, self.egress_inline,
-                        self.egress_queued)
+                        self.egress_queued, self.egress_batched)
                     for streams, d2, lengths, frames in jobs:
                         if streams is not None:
-                            egress_streams(self, self.broker, streams)
+                            egress_streams(self, self.broker, streams,
+                                           back_pressured)
                         else:
                             self._egress(d2, lengths, frames)
                     sp.set_metadata(
                         deliveries=self.messages_routed - routed,
                         inline=self.egress_inline - inline,
-                        queued=self.egress_queued - queued)
+                        queued=self.egress_queued - queued,
+                        batched=self.egress_batched - batched)
                 # the pump's own ``send()``s held the loop: drain the
                 # sockets before the next take. Streams that were all
                 # queued for their writers were no such hold (the loop

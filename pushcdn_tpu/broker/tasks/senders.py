@@ -13,6 +13,8 @@ import logging
 import time
 from typing import TYPE_CHECKING, Iterable, List, Optional
 
+import numpy as np
+
 from pushcdn_tpu import native as native_mod
 from pushcdn_tpu.proto import flowclass
 from pushcdn_tpu.proto import ledger as ledger_mod
@@ -155,20 +157,85 @@ def try_send_encoded_to_user_nowait(broker: "Broker", public_key: bytes,
         connection.send_encoded_nowait(data, owner, nframes=nframes)
         return QUEUED
     except Exception as exc:
-        logger.info("encoded send to user %s failed (%r)%s; removing",
-                    mnemonic(public_key), exc, _pumped(connection))
-        broker.connections.remove_user(public_key, reason="send failed")
-        broker.update_metrics()
+        _send_failed(broker, public_key, connection, exc)
         return 0
 
 
-def egress_streams(plane, broker: "Broker", streams) -> None:
+def _send_failed(broker: "Broker", public_key: bytes, connection,
+                 exc: Exception) -> None:
+    logger.info("encoded send to user %s failed (%r)%s; removing",
+                mnemonic(public_key), exc, _pumped(connection))
+    broker.connections.remove_user(public_key, reason="send failed")
+    broker.update_metrics()
+
+
+def _egress_batched(plane, broker: "Broker", streams) -> list:
+    """Send the streams of one back-pressured step whose links are idle
+    plain sockets (``Connection.idle_fd``) by ONE native call
+    (``native.send_batch``: the sends fanned over a few threads, joined
+    before it returns), straight from the step's pooled buffer; the slots
+    it did not take, for the caller's loop. The event loop stands still
+    for the call, as it does for that loop's ``send()``s, so between a
+    link's check, its send and its settling (``Connection.sent_on_fd``)
+    nothing else can write to, close or reuse its socket, and a user has
+    one stream in ``streams``, so one send: nothing can reorder. A full
+    send is tallied like a stream the pump wrote itself
+    (``egress_inline``) and in ``egress_batched``; so is a short one,
+    whose remainder its transport holds from then on; any other errno
+    removes that user only."""
+    slots = plane.slots
+    nbytes = streams.nbytes
+    user_connection = broker.connections.get_user_connection
+    batch, rest = [], []
+    for slot in streams.users:
+        key = slots.key_of(slot)
+        connection = None if key is None else user_connection(key)
+        fd = None if connection is None \
+            else connection.idle_fd(int(nbytes[slot]))
+        if fd is None:
+            rest.append(slot)
+        else:
+            batch.append((slot, key, connection, fd))
+    if not batch:
+        return rest
+    taken = np.fromiter((b[0] for b in batch), np.int64, len(batch))
+    sent = native_mod.send_batch(
+        streams.buf, np.fromiter((b[3] for b in batch), np.int32, len(batch)),
+        streams.offsets[taken], nbytes[taken]).tolist()
+    for (slot, key, connection, _), n in zip(batch, sent):
+        nframes = int(streams.msgs[slot])
+        try:
+            connection.sent_on_fd(streams.stream(slot), n, nframes=nframes)
+        except Exception as exc:
+            _send_failed(broker, key, connection, exc)
+            continue
+        plane.messages_routed += nframes
+        plane.egress_inline += 1
+        plane.egress_batched += 1
+    return rest
+
+
+def egress_streams(plane, broker: "Broker", streams,
+                   back_pressured: bool = False) -> None:
     """Deliver one step's native egress (:class:`native.EgressStreams`):
     one pre-framed stream hand-off per user with deliveries, tallied on
     ``plane`` (a ``DevicePlane`` or a broker group): ``messages_routed``,
-    and how each hand-off went, ``egress_inline`` or ``egress_queued``."""
+    and how each hand-off went, ``egress_inline`` or ``egress_queued``.
+
+    ``back_pressured`` is the pump's observation that the step's take
+    found the base lane full: its publishers wait on the step, so the
+    next one carries as many frames and makes as many sends however
+    short this one is, and the time of the sends is the rate. Only then
+    do the idle links' sends leave together over several threads
+    (:func:`_egress_batched`); every other hand-off, and every one of a
+    step that is not back-pressured (where shorter sends would buy a
+    faster cadence of smaller steps with cores), goes one by one
+    below."""
     slots = plane.slots
-    for slot in streams.users:
+    users = streams.users
+    if back_pressured:
+        users = _egress_batched(plane, broker, streams)
+    for slot in users:
         key = slots.key_of(int(slot))
         if key is None:  # released mid-step: user is gone, drop
             continue

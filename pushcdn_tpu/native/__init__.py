@@ -101,7 +101,7 @@ def build_all() -> Dict[str, bool]:
 
 
 def _compile() -> Optional[ctypes.CDLL]:
-    lib = _build_lib("framing", (_SRC,), ctypes.CDLL)
+    lib = _build_lib("framing", (_SRC,), ctypes.CDLL, ("-pthread",))
     if lib is None:
         return None
 
@@ -139,6 +139,9 @@ def _compile() -> Optional[ctypes.CDLL]:
         u8p, ctypes.c_int32, ctypes.c_int32, i32p,
         ctypes.POINTER(ctypes.c_void_p), ctypes.c_int32, ctypes.c_int32,
         ctypes.c_int64, i64p, i64p, i32p, u8p, ctypes.c_int64]
+    lib.pushcdn_send_batch.restype = None
+    lib.pushcdn_send_batch.argtypes = [
+        u8p, i32p, i64p, i64p, ctypes.c_int32, ctypes.c_int32, i64p]
     return lib
 
 
@@ -622,6 +625,47 @@ def egress_encode(deliver: np.ndarray, lengths: np.ndarray,
     users = np.nonzero(per_msgs)[0].tolist()
     return EgressStreams(buf, users, offsets, per_bytes, per_msgs,
                          lease=lease)
+
+
+# Threads one :func:`send_batch` fans its sends over, the caller's among
+# them: the largest of 1, 2, 4, 8 at which the routing process's CPU per
+# delivery stayed within 5 % of the one-by-one loop's on the chip's host
+# (13 cores, 16 client processes beside the broker; ``PERF.md`` section 6,
+# PR 31). A batch of ~960 sends of 3.7 KB took 37.2, 19.6, 9.8, 7.1 ms at
+# 1, 2, 4, 8 threads and 38-44 us of process CPU a send at every one
+# (the time is kernel work per socket: it divides, it does not grow), and
+# ``fanout4-sat`` delivered 42k, 58k, 69k, 75k a second at 26-27 us of CPU
+# a delivery (30.8 one by one). Past 8 the host has no cores to give.
+_SEND_THREADS = 8
+
+
+def send_batch(buf, fds: np.ndarray, offsets: np.ndarray,
+               nbytes: np.ndarray) -> np.ndarray:
+    """``send(fds[i], buf[offsets[i]:][:nbytes[i]], MSG_DONTWAIT |
+    MSG_NOSIGNAL)`` once per entry, as one call of the framing library
+    over ``_SEND_THREADS`` threads (no more than the CPUs this process may
+    run on) with the GIL released, joined before it returns; per entry the
+    bytes the socket took, or ``-errno``. ``buf`` is a step's egress
+    buffer (:class:`EgressStreams` exists only where the library loaded).
+    The caller holds the event loop for the call and lists each fd once,
+    so nothing else writes to, closes or reuses a socket meanwhile."""
+    n = len(fds)
+    fds = np.ascontiguousarray(fds, np.int32)
+    offsets = np.ascontiguousarray(offsets, np.int64)
+    nbytes = np.ascontiguousarray(nbytes, np.int64)
+    if not (len(offsets) == len(nbytes) == n):
+        raise ValueError("fds/offsets/nbytes length mismatch")
+    if n and (int(offsets.min()) < 0 or int(nbytes.min()) < 0
+              or int((offsets + nbytes).max()) > len(buf)):
+        raise ValueError("a stream lies outside the egress buffer")
+    out = np.empty(n, np.int64)
+    _get().pushcdn_send_batch(
+        _ptr(np.frombuffer(buf, np.uint8), ctypes.c_uint8),
+        _ptr(fds, ctypes.c_int32), _ptr(offsets, ctypes.c_int64),
+        _ptr(nbytes, ctypes.c_int64), n,
+        min(_SEND_THREADS, len(os.sched_getaffinity(0))),
+        _ptr(out, ctypes.c_int64))
+    return out
 
 
 def encode_frames(payloads: list[bytes]) -> Optional[bytes]:

@@ -39,7 +39,9 @@ The spans (the names are what ``benchmark/span_reduce.py`` reads):
 ``plane.egress``      event loop  the pump's hand-off of the users' streams:
                                   written there on an idle link, else queued
                                   for the writer; ``step``, ``deliveries``,
-                                  ``inline``, ``queued``
+                                  ``inline``, ``queued``, ``batched`` (of
+                                  ``inline``, sent by one native call: a
+                                  ``DevicePlane`` step that was back-pressured)
 ====================  ==========  ===========================================
 
 ``step`` is the plane's own step number; the two thread hops of a step

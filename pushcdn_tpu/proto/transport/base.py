@@ -23,6 +23,8 @@ from __future__ import annotations
 
 import abc
 import asyncio
+import errno
+import os
 import struct
 import time
 import weakref
@@ -232,6 +234,14 @@ class RawStream(abc.ABC):
         takes the writer."""
         return False
 
+    def idle_fd(self) -> Optional[int]:
+        """The socket's file descriptor iff the bytes of this stream are
+        the bytes on that socket and the stream holds none back, so that
+        a ``send()`` on it now is this stream's next bytes; None
+        otherwise. Optional, as :meth:`write_nowait` is: the default is
+        None and every send goes through the stream."""
+        return None
+
     @abc.abstractmethod
     async def close(self) -> None:
         """Flush and close the write side gracefully."""
@@ -276,6 +286,19 @@ class AsyncioStream(RawStream):
             return False
         self.writer.write(bytes(data) if isinstance(data, memoryview) else data)
         return True
+
+    def idle_fd(self) -> Optional[int]:
+        # an EMPTY write buffer, not one under the low-water mark: bytes
+        # sent on the fd must not pass bytes the transport still holds.
+        # A TLS transport sits on a socket too, but what that socket
+        # carries are records, never these bytes
+        transport = self.writer.transport
+        if transport.is_closing() or transport.get_write_buffer_size() \
+                or transport.get_extra_info("sslcontext") is not None:
+            return None
+        sock = transport.get_extra_info("socket")
+        fd = -1 if sock is None else sock.fileno()
+        return fd if fd >= 0 else None
 
     async def writev(self, bufs) -> None:
         # one gather handoff: writelines joins the run into a single
@@ -1305,9 +1328,7 @@ class Connection:
         nor interleave. A write that raises poisons the link. Accounting
         is that path's: zero queue delay, so only the volume counters and
         the ledger's transit move."""
-        if self._error is not None or self._closed \
-                or not self._send_q.empty() or self._write_mutex.locked() \
-                or len(data) > self._BATCH_COALESCE_LIMIT:
+        if not self._inline_ok(len(data)):
             return False
         try:
             if not self._stream.write_nowait(data):
@@ -1316,14 +1337,57 @@ class Connection:
             err = Error(ErrorKind.CONNECTION, f"write failed: {exc!r}", exc)
             self._poison(err)
             raise err
-        cls &= 3
-        nbytes = len(data)
+        self._count_inline(cls & 3, len(data), nframes)
+        return True
+
+    def _inline_ok(self, nbytes: int) -> bool:
+        return self._error is None and not self._closed \
+            and self._send_q.empty() and not self._write_mutex.locked() \
+            and nbytes <= self._BATCH_COALESCE_LIMIT
+
+    def _count_inline(self, cls: int, nbytes: int, nframes: int) -> None:
         self._m_sent.inc(nbytes)
         if nframes:
             metrics_mod.CLASS_FRAMES_OUT[cls].inc(nframes)
         metrics_mod.CLASS_BYTES_OUT[cls].inc(nbytes)
         ledger_mod.on_transit(cls, nframes, self.ledger_peer)
-        return True
+
+    def idle_fd(self, nbytes: int) -> Optional[int]:
+        """The socket on which a caller that HOLDS THE LOOP may ``send()``
+        an already length-delimited stream of ``nbytes`` itself, or None:
+        the link is idle as :meth:`try_send_encoded_inline` wants it and
+        the stream gives its descriptor (:meth:`RawStream.idle_fd`: a
+        plain socket whose transport holds no bytes back). The caller
+        sends once, without blocking, before anything else runs on the
+        loop, and settles with :meth:`sent_on_fd`; that method's argument
+        covers both, with nothing kept on the connection in between."""
+        return self._stream.idle_fd() if self._inline_ok(nbytes) else None
+
+    def sent_on_fd(self, data, sent: int, cls: int = 2,
+                   nframes: int = 0) -> None:
+        """Settle the one ``send()`` of ``data`` a caller made on
+        :meth:`idle_fd`'s socket: ``sent`` is what it returned, bytes
+        taken or ``-errno``. The remainder of a short send (all of it
+        after ``EAGAIN``) goes to the stream now, whose buffer was empty,
+        which is what the transport's own ``write`` does after a short
+        ``send``; any other errno poisons the link and raises, as a write
+        that raises does. Order and lifetime are argued as for
+        :meth:`try_send_encoded_inline`: the loop has not turned since
+        :meth:`idle_fd`, so nothing was queued, written or closed in
+        between, and the descriptor was this link's all through.
+        Accounting is that method's too."""
+        if sent in (-errno.EAGAIN, -errno.EWOULDBLOCK):
+            sent = 0
+        try:
+            if sent < 0:
+                raise OSError(-sent, os.strerror(-sent))
+            if sent < len(data) and not self._stream.write_nowait(data[sent:]):
+                raise OSError(errno.EPIPE, "stream closed under a short send")
+        except Exception as exc:
+            err = Error(ErrorKind.CONNECTION, f"write failed: {exc!r}", exc)
+            self._poison(err)
+            raise err
+        self._count_inline(cls & 3, len(data), nframes)
 
     async def send_encoded(self, data, owner=None, flush: bool = False,
                            cls: int = 2, nframes: int = 0,

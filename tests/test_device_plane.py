@@ -382,10 +382,10 @@ def _mixed_traffic(seed: int):
 
 
 @contextlib.asynccontextmanager
-async def _served_over_tcp(seed: int, device_plane, topics):
+async def _served_over_tcp(seed: int, device_plane, topics, protocol=None):
     """One broker (with ``device_plane``, or the plain host router) and a
     marshal, with one connected client per entry of ``topics`` over real
-    TCP user links: ``(broker, clients)``."""
+    TCP user links (or ``protocol``'s): ``(broker, clients)``."""
     import tempfile
 
     from pushcdn_tpu.bin.common import free_ports
@@ -397,7 +397,8 @@ async def _served_over_tcp(seed: int, device_plane, topics):
     from pushcdn_tpu.proto.def_ import testing_run_def
     from pushcdn_tpu.proto.transport import Tcp
 
-    run_def = testing_run_def(user_protocol=Tcp)
+    protocol = protocol or Tcp
+    run_def = testing_run_def(user_protocol=protocol)
     db = os.path.join(tempfile.mkdtemp(prefix="pushcdn-diff-"), "d.sqlite")
     pub, marshal_port = free_ports(2)
     tag = f"diff-{seed}-{'dev' if device_plane else 'host'}"
@@ -419,7 +420,7 @@ async def _served_over_tcp(seed: int, device_plane, topics):
     clients = [Client(ClientConfig(
         marshal_endpoint=f"127.0.0.1:{marshal_port}",
         keypair=DEFAULT_SCHEME.generate_keypair(seed=seed + 1 + u),
-        protocol=Tcp, subscribed_topics=set(topics[u])))
+        protocol=protocol, subscribed_topics=set(topics[u])))
         for u in range(len(topics))]
     try:
         for c in clients:
@@ -440,6 +441,9 @@ async def _serve_mixed_traffic(seed: int, device_plane):
     None)."""
     import socket
 
+    from pushcdn_tpu.proto import ledger as ledger_mod
+
+    ledger_mod.reset_for_tests()
     topics, plan, owed = _mixed_traffic(seed)
     got = [{} for _ in range(_N_USERS)]
     counts = [0] * _N_USERS
@@ -486,19 +490,28 @@ async def _serve_mixed_traffic(seed: int, device_plane):
                 lambda: counts[_SLOW] == sum(map(len, owed[_SLOW].values())),
                 timeout=30)
             assert broker.connections.num_users == _N_USERS  # nobody removed
+            # the frame-fate ledger closes over every way a stream left:
+            # batched, written by the pump one by one, or queued
+            book = ledger_mod.LEDGER
+            await wait_until(lambda: book.walk_live_queues() == 0)
+            assert book.derived_in_queue() == [0] * len(book.queued), \
+                (book.queued, book.fates)
+            delivered = sum(book.fates[("delivered", "egress")])
+            assert delivered >= sum(sum(map(len, o.values())) for o in owed)
         finally:
             for t in drains:
                 t.cancel()
     return got, owed, broker.device_plane
 
 
-@pytest.mark.parametrize("seed", [2601, 2602])
+@pytest.mark.parametrize("seed, ring_slots", [(2601, 64), (2602, 64),
+                                              (2603, 16)])
 async def test_served_egress_matches_the_host_router_with_a_slow_reader(
-        seed, monkeypatch):
+        seed, ring_slots, monkeypatch):
     from pushcdn_tpu.broker.device_plane import DevicePlaneConfig
     from pushcdn_tpu.broker.tasks import senders
 
-    handoffs = []  # every per-user stream hand-off that did not fail
+    handoffs = []  # every one-by-one stream hand-off that did not fail
     real = senders.try_send_encoded_to_user_nowait
 
     def counted(*args, **kwargs):
@@ -512,19 +525,27 @@ async def test_served_egress_matches_the_host_router_with_a_slow_reader(
     assert no_plane is None and not handoffs
     by_device, _, plane = await _serve_mixed_traffic(
         seed, DevicePlaneConfig(
-            num_user_slots=32, ring_slots=64, frame_bytes=1024,
+            num_user_slots=32, ring_slots=ring_slots, frame_bytes=1024,
             batch_window_s=0.002, bypass_max_items=0))
     # every user, every (publisher, stream): the same sequence both ways,
     # and it is the publisher's own order with nothing lost or repeated
     assert by_device == by_host == owed
     assert not plane.disabled and plane.steps >= 1
-    # idle links were written by the pump, the stalled one by its writer,
-    # and the two tallies are all the hand-offs there were
+    # idle links were written by the pump, the stalled one by its writer;
+    # the sends of a step whose take found the base lane full (three
+    # publishers fill 16 slots at every take) left in one native batch,
+    # the stalled link's short send among them; and the tallies are all
+    # the hand-offs there were
     assert plane.egress_inline > 0 and plane.egress_queued > 0
-    assert plane.egress_inline + plane.egress_queued == len(handoffs)
+    assert plane.egress_batched <= plane.egress_inline
+    if ring_slots == 16:
+        assert plane.egress_batched > 0
+    assert plane.egress_inline + plane.egress_queued == \
+        len(handoffs) + plane.egress_batched
     described = plane.describe()
-    assert (described["egress_inline"], described["egress_queued"]) == \
-        (plane.egress_inline, plane.egress_queued)
+    assert (described["egress_inline"], described["egress_queued"],
+            described["egress_batched"]) == \
+        (plane.egress_inline, plane.egress_queued, plane.egress_batched)
 
 
 # ---------------------------------------------------------------------------
@@ -789,3 +810,218 @@ async def test_the_drain_is_bounded_in_loop_passes(sockets):
                      else device_plane._DRAIN_PASSES)
     assert drained == (spent if sockets == "endless" else 0)
     assert not plane._between_steps
+
+
+# ---------------------------------------------------------------------------
+# the native batch (ISSUE 31): the sends of a step whose take found the base
+# lane full leave in one ``native.send_batch`` call, for the links that are
+# idle plain sockets; every other step, and every other link, goes one by one.
+# ---------------------------------------------------------------------------
+
+_LANE = 16
+_BATCH_PLANE = dict(num_user_slots=32, ring_slots=_LANE, frame_bytes=1024,
+                    batch_window_s=0.002, bypass_max_items=0)
+
+
+def _record_batches(monkeypatch, before=lambda fds: None,
+                    after=lambda fds, sent: sent) -> list:
+    """Every ``native.send_batch`` call as ``(fds, nbytes, sent)`` lists;
+    ``before`` runs just ahead of the real call, ``after`` may rewrite
+    what it returned."""
+    from pushcdn_tpu import native
+    calls = []
+    real = native.send_batch
+
+    def send_batch(buf, fds, offsets, nbytes):
+        before(fds.tolist())
+        sent = after(fds.tolist(), real(buf, fds, offsets, nbytes))
+        calls.append((fds.tolist(), nbytes.tolist(), sent.tolist()))
+        return sent
+    monkeypatch.setattr(native, "send_batch", send_batch)
+    return calls
+
+
+def _broker_fd(broker, client) -> int:
+    """The broker's end of ``client``'s TCP link."""
+    return broker.connections.get_user_connection(client.public_key) \
+        ._stream.writer.get_extra_info("socket").fileno()
+
+
+@pytest.mark.parametrize("frames", [_LANE, _LANE - 1],
+                         ids=["lane_full", "lane_not_full"])
+async def test_only_a_back_pressured_step_is_sent_by_the_native_batch(
+        frames, monkeypatch):
+    from pushcdn_tpu.broker.device_plane import DevicePlaneConfig
+
+    payloads = [b"frame %d" % i for i in range(frames)]
+    calls = _record_batches(monkeypatch)
+    async with _served_over_tcp(
+            3101, DevicePlaneConfig(**_BATCH_PLANE), [{0}] * 4) as (
+                broker, clients):
+        plane = broker.device_plane
+        # one write, one read, one receive batch: the take finds what it
+        # staged, all 16 slots or one short of them
+        os.write(_socket_of(clients[0]), _wire(*payloads))
+        got = await _receive_all(clients, frames)
+        assert got == [payloads] * 4
+        assert (plane.steps, plane.egress_inline, plane.egress_queued) == \
+            (1, 4, 0)
+        if frames == _LANE:
+            assert plane.egress_batched == 4
+            (fds, nbytes, sent), = calls
+            assert sorted(fds) == sorted(_broker_fd(broker, c)
+                                         for c in clients)
+            assert sent == nbytes
+        else:
+            assert plane.egress_batched == 0 and not calls
+        assert plane.describe()["egress_batched"] == plane.egress_batched
+
+
+async def test_a_short_send_keeps_its_order_and_later_steps_queue_behind_it(
+        monkeypatch):
+    """A reader that stops reading: the batch's ``send()`` takes part of
+    its stream, the transport gets the rest, and while it holds bytes the
+    link is not batched again (its later streams join the transport's
+    buffer, then the writer's queue); the others go on in the batch."""
+    import socket
+
+    from pushcdn_tpu.broker.device_plane import DevicePlaneConfig
+
+    # 48 KB a user a step: under one flush unit, over what the stalled
+    # link's socket buffers take
+    lane = 48
+    rounds = [[(b"%d.%d|" % (r, i)).ljust(1000, b".") for i in range(lane)]
+              for r in range(5)]
+    calls = _record_batches(monkeypatch)
+    async with _served_over_tcp(
+            3110, DevicePlaneConfig(**dict(_BATCH_PLANE, ring_slots=lane)),
+            [{0}] * 3) as (broker, clients):
+        plane = broker.device_plane
+        stalled = clients[2]
+        link = broker.connections.get_user_connection(stalled.public_key)
+        stream = stalled._connection._stream
+        stream.writer.get_extra_info("socket").setsockopt(
+            socket.SOL_SOCKET, socket.SO_RCVBUF, 4096)
+        link._stream.writer.get_extra_info("socket").setsockopt(
+            socket.SOL_SOCKET, socket.SO_SNDBUF, 4096)
+        stream.reader._transport.pause_reading()
+        fd = _broker_fd(broker, stalled)
+        transport = link._stream.writer.transport
+        short = unbatched = 0
+        for r, frames in enumerate(rounds):
+            held = transport.get_write_buffer_size()
+            assert (link.idle_fd(1) is None) == bool(held)
+            os.write(_socket_of(clients[0]), _wire(*frames))
+            await wait_until(lambda: plane.steps == r + 1
+                             and not plane._step_inflight)
+            assert await _receive_all(clients[:2], lane) == [frames] * 2
+            fds, nbytes, sent = calls[r]
+            if held:    # bytes on the fd would pass the transport's
+                assert fd not in fds and len(fds) == 2
+                unbatched += 1
+            else:
+                at = fds.index(fd)
+                short += sent[at] < nbytes[at]
+                del nbytes[at], sent[at]
+            assert sent == nbytes       # the readers that read
+        # the socket filled, then the transport's buffer passed its
+        # low-water mark, then the writer's queue took the streams
+        assert short and unbatched and plane.egress_queued >= 1
+        assert plane.egress_batched == sum(len(c[0]) for c in calls)
+        assert plane.egress_inline + plane.egress_queued == 3 * len(rounds)
+        stream.reader._transport.resume_reading()
+        everything = [f for frames in rounds for f in frames]
+        got, = await _receive_all([stalled], len(everything))
+        assert got == everything
+        assert broker.connections.num_users == 3 and not plane.disabled
+
+
+@pytest.mark.parametrize("err", ["EPIPE", "ECONNRESET"])
+async def test_a_send_that_fails_in_the_batch_removes_that_user_only(
+        err, monkeypatch):
+    import errno
+    import socket
+
+    from pushcdn_tpu.broker.device_plane import DevicePlaneConfig
+    from pushcdn_tpu.broker.tasks import senders
+
+    code = getattr(errno, err)
+    victim_fd = []
+
+    def shut_down(fds):     # the peer is gone by the time of the send()
+        if victim_fd:
+            assert victim_fd[0] in fds
+            sock = socket.socket(fileno=os.dup(victim_fd[0]))
+            sock.shutdown(socket.SHUT_RDWR)
+            sock.close()
+
+    def as_err(fds, sent):
+        if victim_fd:
+            at = fds.index(victim_fd.pop())
+            assert sent[at] == -errno.EPIPE
+            sent[at] = -code
+        return sent
+    calls = _record_batches(monkeypatch, shut_down, as_err)
+    failed = []
+    real = senders._send_failed
+
+    def send_failed(broker, key, connection, exc):
+        failed.append((key, exc))
+        real(broker, key, connection, exc)
+    monkeypatch.setattr(senders, "_send_failed", send_failed)
+
+    first = [b"first %d" % i for i in range(_LANE)]
+    second = [b"second %d" % i for i in range(_LANE)]
+    async with _served_over_tcp(
+            3120, DevicePlaneConfig(**_BATCH_PLANE), [{0}] * 4) as (
+                broker, clients):
+        plane = broker.device_plane
+        victim = clients[3]
+        victim_fd.append(_broker_fd(broker, victim))
+        os.write(_socket_of(clients[0]), _wire(*first))
+        got = await _receive_all(clients[:3], _LANE)
+        assert got == [first] * 3
+        (key, exc), = failed
+        assert key == victim.public_key and os.strerror(code) in str(exc)
+        assert broker.connections.get_user_connection(key) is None
+        assert broker.connections.num_users == 3
+        assert (plane.egress_batched, plane.egress_inline,
+                plane.messages_routed) == (3, 3, 3 * _LANE)
+        # the next step: the three that are left, batched again
+        os.write(_socket_of(clients[0]), _wire(*second))
+        got = await _receive_all(clients[:3], _LANE)
+        assert got == [second] * 3
+        assert len(calls) == 2 and len(calls[1][0]) == 3
+        assert plane.egress_batched == 6 and not plane.disabled
+
+
+@pytest.mark.parametrize("link", ["tls", "memory"])
+async def test_links_without_a_plain_idle_socket_are_never_batched(
+        link, monkeypatch):
+    """A TLS transport has a socket, but not one that carries the stream's
+    bytes; the Memory transport has none. Their steps are back-pressured
+    like any other and go one by one (a link whose transport holds bytes:
+    the short send's test above)."""
+    from pushcdn_tpu.broker.device_plane import DevicePlaneConfig
+    from pushcdn_tpu.proto.transport import Memory, TcpTls
+
+    payloads = [b"frame %d" % i for i in range(_LANE)]
+    calls = _record_batches(monkeypatch)
+    async with _served_over_tcp(
+            3130, DevicePlaneConfig(**_BATCH_PLANE), [{0}] * 3,
+            protocol=TcpTls if link == "tls" else Memory) as (
+                broker, clients):
+        plane = broker.device_plane
+        conns = [broker.connections.get_user_connection(c.public_key)
+                 for c in clients]
+        if link == "tls":
+            assert all(c._stream.writer.get_extra_info("socket") is not None
+                       for c in conns)
+        assert [c.idle_fd(1) for c in conns] == [None] * 3
+        # no socket to write into: one pipelined burst fills the lane
+        await asyncio.gather(*(
+            clients[0].send_broadcast_message([0], p) for p in payloads))
+        got = await _receive_all(clients, _LANE)
+        assert got == [payloads] * 3
+        assert plane.egress_batched == 0 and not calls
+        assert plane.egress_inline + plane.egress_queued == 3 * plane.steps

@@ -191,3 +191,81 @@ def test_push_batch_multiword_mask_expansion_and_memo():
     assert (rows == rows[0]).all()
     assert list(rows[0]) == [(big >> (32 * w)) & 0xFFFFFFFF
                              for w in range(W)]
+
+
+def _socket_pairs(n: int):
+    import contextlib
+    import socket
+    stack = contextlib.ExitStack()
+    pairs = [socket.socketpair() for _ in range(n)]
+    for a, b in pairs:
+        stack.callback(a.close)
+        stack.callback(b.close)
+    return stack, pairs
+
+
+def test_send_batch_sends_each_entry_once_from_the_shared_buffer():
+    """300 entries over the library's threads: every socket gets exactly
+    its slice of the buffer, once (an entry handed out twice would show
+    as a doubled stream, one skipped as an empty socket)."""
+    n, size = 300, 700
+    stack, pairs = _socket_pairs(n)
+    with stack:
+        rng = np.random.default_rng(31)
+        buf = bytearray(rng.integers(0, 256, n * size + 64, np.uint8)
+                        .tobytes())
+        order = rng.permutation(n)
+        fds = np.array([pairs[i][0].fileno() for i in order], np.int32)
+        offsets = (order * size + 64).astype(np.int64)
+        nbytes = np.full(n, size, np.int64)
+        nbytes[::7] = 0
+        sent = native.send_batch(buf, fds, offsets, nbytes)
+        assert sent.dtype == np.int64 and sent.tolist() == nbytes.tolist()
+        for k, i in enumerate(order):
+            reader = pairs[i][1]
+            reader.setblocking(False)
+            want = bytes(buf[offsets[k]:offsets[k] + nbytes[k]])
+            got = b""
+            try:
+                got = reader.recv(4 * size)
+            except BlockingIOError:
+                pass
+            assert got == want, int(i)
+
+
+def test_send_batch_reports_short_sends_and_errnos_per_entry():
+    import errno
+    import socket
+    stack, pairs = _socket_pairs(4)
+    with stack:
+        for a, _ in pairs:
+            a.setsockopt(socket.SOL_SOCKET, socket.SO_SNDBUF, 4096)
+        buf = bytearray(b"x" * (1 << 20))
+        fds = np.array([a.fileno() for a, _ in pairs], np.int32)
+        # entry 1: its socket is full
+        while native.send_batch(buf, fds[1:2], np.zeros(1, np.int64),
+                                np.full(1, 1 << 16, np.int64))[0] > 0:
+            pass
+        pairs[2][1].close()                 # entry 2: the peer is gone
+        pairs[3][0].shutdown(socket.SHUT_WR)
+        sent = native.send_batch(buf, fds, np.zeros(4, np.int64),
+                                 np.full(4, 1 << 20, np.int64)).tolist()
+        assert 0 < sent[0] < 1 << 20        # short: the socket filled
+        assert sent[1] in (-errno.EAGAIN, -errno.EWOULDBLOCK)
+        assert sent[2:] == [-errno.EPIPE] * 2   # and no SIGPIPE
+
+
+def test_send_batch_refuses_streams_outside_the_buffer():
+    stack, pairs = _socket_pairs(1)
+    with stack:
+        fds = np.array([pairs[0][0].fileno()], np.int32)
+        buf = bytearray(100)
+        for off, n in ((90, 11), (-1, 5), (0, -1)):
+            with pytest.raises(ValueError):
+                native.send_batch(buf, fds, np.array([off], np.int64),
+                                  np.array([n], np.int64))
+        with pytest.raises(ValueError):
+            native.send_batch(buf, fds, np.zeros(2, np.int64),
+                              np.zeros(2, np.int64))
+        assert native.send_batch(buf, fds, np.array([90], np.int64),
+                                 np.array([10], np.int64)).tolist() == [10]

@@ -108,6 +108,7 @@ async def test_traced_steps_yield_flat_joined_conserving_spans(
                                     plane.messages_routed, plane.steps)
         handoffs0 = (plane.egress_inline, plane.egress_queued)
         drained0 = getattr(plane, "frames_drained", 0)  # no group has it
+        batched0 = getattr(plane, "egress_batched", 0)  # nor this
         options = jax.profiler.ProfileOptions()
         options.python_tracer_level = 0
         options.host_tracer_level = 2
@@ -123,6 +124,7 @@ async def test_traced_steps_yield_flat_joined_conserving_spans(
         inline, queued = (plane.egress_inline - handoffs0[0],
                           plane.egress_queued - handoffs0[1])
         drained = getattr(plane, "frames_drained", 0) - drained0
+        batched = getattr(plane, "egress_batched", 0) - batched0
     finally:
         client.close()
         await cluster.stop()
@@ -184,6 +186,16 @@ async def test_traced_steps_yield_flat_joined_conserving_spans(
     waits = [e[3]["ring_wait_us"] for e in events if e[0] == "plane.take"]
     assert all(0 <= w < trace_ns / 1e3 for w in waits), waits
     _drained_conserves(events, drained)
+    _batched_conserves(events, batched)
+
+
+def _batched_conserves(events, batched: int) -> None:
+    """``plane.egress``'s ``batched`` (hand-offs one native call sent)
+    sums to the plane's ``egress_batched`` and is part of ``inline``."""
+    egresses = [e[3] for e in events if e[0] == "plane.egress"]
+    assert all(0 <= g.get("batched", 0) <= g["inline"] for g in egresses), \
+        egresses
+    assert sum(g.get("batched", 0) for g in egresses) == batched
 
 
 def _drained_conserves(events, drained: int) -> None:
@@ -244,3 +256,48 @@ async def test_traced_takes_report_what_the_drain_staged(
                    key=lambda e: e[1])
     assert [(t[3]["frames"], t[3]["drained"]) for t in takes] == [
         (1, 0), (1, 1), (1, 0), (2, 2), (1, 0), (3, 3)]
+
+
+async def test_traced_egress_reports_what_the_native_batch_sent(tmp_path):
+    """Over real TCP links a step whose take found the base lane full
+    sends its streams in one native batch, and ``plane.egress`` says how
+    many: all of that step's hand-offs, none of a step with room left."""
+    import jax
+
+    from pushcdn_tpu.broker.device_plane import DevicePlaneConfig
+    from pushcdn_tpu.parallel import spans
+    from tests.test_device_plane import (
+        _SMALL_PLANE,
+        _receive_all,
+        _served_over_tcp,
+        _socket_of,
+        _wire,
+    )
+    spans.bind()
+    lane = _SMALL_PLANE["ring_slots"]
+    rounds = [[b"round %d %d" % (r, i) for i in range(n)]
+              for r, n in enumerate((lane, 3, lane))]
+    async with _served_over_tcp(
+            3140, DevicePlaneConfig(bypass_max_items=0, **_SMALL_PLANE),
+            [{0}] * 2) as (broker, clients):
+        plane = broker.device_plane
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        options.host_tracer_level = 2
+        jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+        try:
+            for frames in rounds:
+                os.write(_socket_of(clients[0]), _wire(*frames))
+                got = await _receive_all(clients, len(frames))
+                assert got == [frames] * 2
+        finally:
+            jax.profiler.stop_trace()
+        batched, described = plane.egress_batched, plane.describe()
+    assert batched == described["egress_batched"] == 4
+    threads, _ = _program_spans(str(tmp_path))
+    events = [e for evs in threads.values() for e in evs]
+    _batched_conserves(events, batched)
+    egresses = sorted((e for e in events if e[0] == "plane.egress"),
+                      key=lambda e: e[1])
+    assert [(g[3]["inline"], g[3]["queued"], g[3]["batched"])
+            for g in egresses] == [(2, 0, 2), (2, 0, 0), (2, 0, 2)]
