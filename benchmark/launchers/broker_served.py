@@ -35,13 +35,29 @@ if REPO not in sys.path:
 from benchmark.launchers import control  # noqa: E402
 
 
-def _topology(port: int):
+def _topology(port: int, timeout: float = 10.0):
     try:
         with urllib.request.urlopen(
-                f"http://127.0.0.1:{port}/debug/topology", timeout=10) as r:
+                f"http://127.0.0.1:{port}/debug/topology",
+                timeout=timeout) as r:
             return json.loads(r.read().decode())
     except (urllib.error.URLError, OSError, ValueError):
         return None
+
+
+def counters(port: int, wait_s: float = control.TOPOLOGY_WAIT_S) -> dict:
+    """The ``counters`` event: every number the program says of its device
+    plane, under the program's own names, beside what the launcher adds.
+    A saturated broker answers late: ask again until ``wait_s`` is up."""
+    topo = control.wait_for(
+        lambda left: _topology(port, min(10.0, left)),
+        "the broker's /debug/topology", wait_s)
+    plane = topo["device_plane"]
+    return {**control.scalars(plane),
+            "event": "counters", "t_ns": time.monotonic_ns(),
+            "users": topo["num_users"],
+            "unmirrored": plane["unmirrored_users"],
+            "memory_peak_bytes": control.memory_peak_bytes()}
 
 
 def _accepts(port: int) -> bool:
@@ -65,18 +81,6 @@ def main() -> int:
     children = []
     t_spawn = time.monotonic_ns()
 
-    def counters(_cmd: dict) -> dict:
-        topo = _topology(metrics)
-        plane = topo["device_plane"]
-        return {"event": "counters", "t_ns": time.monotonic_ns(),
-                "users": topo["num_users"],
-                "unmirrored": plane["unmirrored_users"],
-                "memory_peak_bytes": control.memory_peak_bytes(),
-                **{k: plane[k] for k in (
-                    "steps", "frames_staged", "messages_routed", "disabled",
-                    "programs", "cache_hits", "cache_misses", "compile_s",
-                    "warmup_s")}}
-
     def bring_up() -> None:
         plane = None
         while plane is None or plane["warmup_s"] is None:
@@ -96,7 +100,7 @@ def main() -> int:
                 env=env, stdout=log, stderr=subprocess.STDOUT))
         while not _accepts(marshal_port):
             time.sleep(0.05)
-        control.serve({"counters": counters,
+        control.serve({"counters": lambda _cmd: counters(metrics),
                        "place": lambda _cmd: {"event": "placed"},
                        "trace": control.trace_span})
         control.emit(

@@ -19,6 +19,39 @@ import threading
 import time
 from typing import Callable, Dict, Optional
 
+# How long a launcher keeps asking a busy broker for its counters before
+# it gives up (one request of a saturated event loop can take many
+# seconds); the parent waits a little longer for the launcher's answer.
+TOPOLOGY_WAIT_S = 60.0
+
+
+class NoAnswer(Exception):
+    """The deployment did not answer within the limit."""
+
+
+def wait_for(ask: Callable[[float], Optional[dict]], what: str,
+             wait_s: float = TOPOLOGY_WAIT_S, pause_s: float = 0.1) -> dict:
+    """``ask(seconds_left)`` again and again until it returns something,
+    for ``wait_s`` seconds in all; then :class:`NoAnswer`, which
+    :func:`answer` turns into an ``error`` event that says so."""
+    deadline = time.monotonic() + wait_s
+    while True:
+        found = ask(max(deadline - time.monotonic(), 0.05))
+        if found is not None:
+            return found
+        if time.monotonic() + pause_s >= deadline:
+            raise NoAnswer(f"{what}: no answer within {wait_s:.0f} s")
+        time.sleep(pause_s)
+
+
+def scalars(described: dict) -> dict:
+    """What a launcher's ``counters`` passes through of the program's own
+    description (``/debug/topology``'s ``device_plane``, a shard plane's
+    ``describe()``): every key whose value is a number, a bool or None.
+    A reader asks for a key with ``.get``: an older commit lacks it."""
+    return {k: v for k, v in described.items()
+            if v is None or isinstance(v, (bool, int, float))}
+
 
 def emit(event: str, **fields) -> None:
     print(json.dumps({"event": event, **fields}), flush=True)
@@ -30,15 +63,22 @@ def serve(handlers: Dict[str, Callable[[dict], Optional[dict]]]) -> None:
 
     def loop() -> None:
         for line in sys.stdin:
-            try:
-                cmd = json.loads(line)
-                reply = handlers[cmd["cmd"]](cmd)
-            except Exception as exc:  # the parent must hear about it
-                reply = {"event": "error", "what": repr(exc)}
+            reply = answer(handlers, line)
             if reply is not None:
                 print(json.dumps(reply), flush=True)
 
     threading.Thread(target=loop, name="bench-control", daemon=True).start()
+
+
+def answer(handlers: Dict[str, Callable[[dict], Optional[dict]]],
+           line: str) -> Optional[dict]:
+    """One command's reply; a handler that raises gives an ``error``
+    event, because the parent must hear about it."""
+    try:
+        cmd = json.loads(line)
+        return handlers[cmd["cmd"]](cmd)
+    except Exception as exc:
+        return {"event": "error", "what": repr(exc)}
 
 
 def memory_peak_bytes() -> int:

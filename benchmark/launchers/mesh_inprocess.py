@@ -9,8 +9,9 @@ chip cannot run).
 A copy, in the benchmark's directory, of what
 ``pushcdn_tpu/testing/mesh_cluster.MeshCluster`` wires — the same
 constructors, ``MeshGroupConfig`` defaults, no host broker links
-(``form_mesh=False``) — with TCP in place of the Memory transport and
-the whole 256-topic space. Users are placed by steering the load figure
+(``form_mesh=False``) — with TCP (or what the configuration's
+``user_transport`` and ``signature_scheme`` say) in place of the Memory
+transport and the whole 256-topic space. Users are placed by steering the load figure
 in discovery before each group connects, as ``MeshCluster.place_client``
 does; the brokers' own heartbeat and sync are therefore parked at
 3,600 s, as there.
@@ -31,6 +32,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(
 if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
+from benchmark import manifest  # noqa: E402
 from benchmark.launchers import control  # noqa: E402
 
 PARKED_S = 3600.0
@@ -60,7 +62,9 @@ async def amain(cfg: dict, workdir: str) -> int:
               f"shows {rt.device.count}", file=sys.stderr)
         return 3
     db = os.path.join(workdir, "discovery.sqlite")
-    run_def = run_def_from_args("tcp", "tcp", db, 256)
+    clients = manifest.client_settings(cfg)
+    run_def = run_def_from_args("tcp", clients["user_transport"], db, 256,
+                                scheme=clients["signature_scheme"])
     ports = free_ports(2 * shards + 1)
     mesh = make_broker_mesh(shards, devices=jax.devices()[:shards])
     group = MeshBrokerGroup(mesh, MeshGroupConfig())
@@ -69,7 +73,8 @@ async def amain(cfg: dict, workdir: str) -> int:
         public = f"127.0.0.1:{ports[2 * i]}"
         private = f"127.0.0.1:{ports[2 * i + 1]}"
         broker = await Broker.new(BrokerConfig(
-            run_def=run_def, keypair=keypair_from_seed(0),
+            run_def=run_def,
+            keypair=keypair_from_seed(0, clients["signature_scheme"]),
             discovery_endpoint=db,
             public_advertise_endpoint=public, public_bind_endpoint=public,
             private_advertise_endpoint=private, private_bind_endpoint=private,
@@ -101,8 +106,14 @@ async def amain(cfg: dict, workdir: str) -> int:
         """Run a handler's coroutine on the event loop from the control
         thread (group and brokers are event-loop-only objects)."""
         def handler(cmd: dict) -> dict:
-            return asyncio.run_coroutine_threadsafe(
-                coro_fn(cmd), loop).result(timeout=60)
+            future = asyncio.run_coroutine_threadsafe(coro_fn(cmd), loop)
+            try:
+                return future.result(timeout=control.TOPOLOGY_WAIT_S)
+            except TimeoutError:
+                future.cancel()
+                raise control.NoAnswer(
+                    f"the group's event loop: no answer to {cmd['cmd']!r} "
+                    f"within {control.TOPOLOGY_WAIT_S:.0f} s") from None
         return handler
 
     async def do_place(cmd: dict) -> dict:
@@ -110,7 +121,8 @@ async def amain(cfg: dict, workdir: str) -> int:
         return {"event": "placed"}
 
     async def do_counters(_cmd: dict) -> dict:
-        return {"event": "counters", "t_ns": time.monotonic_ns(),
+        return {**control.scalars(brokers[0].device_plane.describe()),
+                "event": "counters", "t_ns": time.monotonic_ns(),
                 "users": sum(b.connections.num_users for b in brokers),
                 "users_by_shard": [b.connections.num_users for b in brokers],
                 "unmirrored": len(group._unmirrored),

@@ -1,6 +1,7 @@
 #!/usr/bin/env python3
-"""One client process of the load generator: real users over real TCP on
-one asyncio loop, through ``pushcdn_tpu.client``'s public API only.
+"""One client process of the load generator: real users over real TCP
+(or TCP+TLS: ``--transport``, ``--scheme`` from the configuration) on one
+asyncio loop, through ``pushcdn_tpu.client``'s public API only.
 
 Copied from ``pushcdn_tpu/testing/clientpack.py`` and extended: every
 payload opens with the benchmark's own header (``loadgen/plan.py``), so a
@@ -9,7 +10,9 @@ per (publisher, stream), checks that a direct was meant for it and
 compares a sample of payloads byte for byte. Publishers run one of three
 loops, all parameters of the traffic file:
 
-- ``open``      independent Poisson arrivals at a fixed rate, timed from due;
+- ``open``      a Poisson process's gaps at a fixed rate, timed from due:
+                the same number of frames, gaps and sizes for every seed,
+                in another order (``plan.arrivals``, ``plan.mix_block``);
 - ``windowed``  back to back, at most ``window`` frames ahead of the last
                 probe the broker echoed (every ``probe_every``-th frame is
                 a small direct to the publisher itself);
@@ -99,17 +102,18 @@ class Publisher:
 
 class Pack:
     def __init__(self, args, traffic: dict):
+        from pushcdn_tpu.bin.common import scheme_by_name, transport_by_name
         from pushcdn_tpu.client import Client, ClientConfig
-        from pushcdn_tpu.proto.crypto.signature import DEFAULT_SCHEME
         from pushcdn_tpu.proto.message import Broadcast, Direct
-        from pushcdn_tpu.proto.transport import Tcp
         self._broadcast, self._direct = Broadcast, Direct
-        self._client = lambda **kw: Client(ClientConfig(protocol=Tcp, **kw))
+        protocol = transport_by_name(args.transport)
+        self.scheme = scheme_by_name(args.scheme)
+        self._client = lambda **kw: Client(
+            ClientConfig(protocol=protocol, scheme=self.scheme, **kw))
         self.args = args
         self.layout = plan.Layout(args.users, args.groups, args.sub_procs,
                                   args.pub_procs, traffic["flows"])
         self.pool = plan.make_pool(args.seed)
-        self.scheme = DEFAULT_SCHEME
         self.table = plan.subscriptions(traffic["subscriptions"], args.users)
         self.mine = self.layout.users_of_proc(args.proc)
         self.users: List[User] = []                 # connected so far
@@ -242,15 +246,18 @@ class Pack:
                         rate_per_s: float) -> None:
         w = self.window
         share = rate_per_s / p.flow["publishers"]
-        gaps = plan.arrival_gaps(self.args.seed, p.pub, share)
-        due = t0_ns + next(gaps)
-        while due < w.end_ns:
-            await self._sleep_until(due)
-            late = time.monotonic_ns() - due
-            if due >= w.start_ns:
-                w.late.add(late if late > 0 else 1)
-            await self._send(p, due)
-            due += next(gaps)
+        # a fixed number of frames in the warm-up and in the window, the
+        # same for every seed (``plan.arrivals``)
+        for part, lo_ns, hi_ns in (("warm", t0_ns, w.start_ns),
+                                   ("window", w.start_ns, w.end_ns)):
+            for offset in plan.arrivals(self.args.seed, p.pub, share,
+                                        hi_ns - lo_ns, part):
+                due = lo_ns + offset
+                await self._sleep_until(due)
+                late = time.monotonic_ns() - due
+                if due >= w.start_ns:
+                    w.late.add(late if late > 0 else 1)
+                await self._send(p, due)
 
     async def _run_windowed(self, p: Publisher, t0_ns: int) -> None:
         w = self.window
@@ -416,6 +423,10 @@ def main() -> int:
     ap.add_argument("--groups", type=int, required=True)
     ap.add_argument("--sub-procs", type=int, required=True)
     ap.add_argument("--pub-procs", type=int, required=True)
+    ap.add_argument("--transport", default="tcp",
+                    help="the users' transport, by the program's name")
+    ap.add_argument("--scheme", default="ed25519",
+                    help="the users' signature scheme, by the program's name")
     args = ap.parse_args()
     with open(args.traffic) as f:
         traffic = json.load(f)

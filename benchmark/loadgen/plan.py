@@ -21,6 +21,8 @@ recompute, so a sampled payload is compared byte for byte.
 from __future__ import annotations
 
 import bisect
+import itertools
+import math
 import random
 import struct
 from typing import Iterator, List, NamedTuple, Sequence, Set
@@ -138,11 +140,39 @@ def subscriptions(rules: Sequence[dict], users: int) -> List[Set[int]]:
     return table
 
 
+def zipf_edges(n: int, s: float) -> List[float]:
+    """Cumulative weights of a Zipf draw over topics ``0..n-1``: topic
+    ``k`` has weight ``(k + 1) ** -s``."""
+    return list(itertools.accumulate((k + 1) ** -s for k in range(n)))
+
+
+def mix_block(shares: Sequence[float]) -> List[int]:
+    """The mix entries, by index, of one block of an open loop's frames:
+    the smallest block of at most 100 frames that holds every entry at
+    its share exactly (0.9 and 0.1: nine and one), else 100 frames shared
+    out by largest remainder."""
+    parts = [share / sum(shares) for share in shares]
+    size = next((n for n in range(1, 100) if all(
+        abs(part * n - round(part * n)) < 1e-9 for part in parts)), 100)
+    exact = [part * size for part in parts]
+    counts = [int(x + 1e-9) for x in exact]
+    for i in sorted(range(len(exact)),
+                    key=lambda i: counts[i] - exact[i])[:size - sum(counts)]:
+        counts[i] += 1
+    return [i for i, count in enumerate(counts) for _ in range(count)]
+
+
 def frame_plan(seed: int, layout: Layout, flow: dict,
                publisher: int) -> Iterator[Frame]:
     """Publisher ``publisher``'s frames in the order it sends them. In a
     windowed loop every ``probe_every``-th frame is a probe to itself and
-    draws nothing, so the other frames do not depend on the spacing."""
+    draws nothing, so the other frames do not depend on the spacing. In an
+    open loop, which sends a number of frames fixed by its rate, the mix
+    entries come block by block (``mix_block``), each block in an order
+    drawn from the seed, so that every seed offers the same sizes and
+    fan-outs in another order; in the other loops each frame draws its
+    entry. A broadcast's topic is ``fixed`` (no draw), ``uniform`` over
+    ``n`` or ``zipf`` over ``n`` with exponent ``s`` (one draw each)."""
     rng = random.Random(f"{seed}:{publisher}:frames")
     me = layout.pub_users[publisher]
     loop = flow["loop"]
@@ -152,18 +182,37 @@ def frame_plan(seed: int, layout: Layout, flow: dict,
     for entry in mix:
         acc += entry["share"]
         edges.append(acc)
+    zipf = {i: zipf_edges(entry["topic"]["zipf"], entry["topic"].get("s", 1.0))
+            for i, entry in enumerate(mix)
+            if "zipf" in entry.get("topic", ())}
+    block, left = None, []
+    if loop["kind"] == "open":
+        block = mix_block([entry["share"] for entry in mix])
+        order = random.Random(f"{seed}:{publisher}:mix")
     k = 0
     while True:
         k += 1
         if every and k % every == 0:
             yield Frame(PROBE, me, loop["probe_bytes"])
             continue
-        entry = mix[min(bisect.bisect_right(edges, rng.random() * acc),
-                        len(mix) - 1)]
+        if block is None:
+            i = min(bisect.bisect_right(edges, rng.random() * acc),
+                    len(mix) - 1)
+        else:
+            if not left:
+                left = block[:]
+                order.shuffle(left)
+            i = left.pop()
+        entry = mix[i]
         if entry["kind"] == "broadcast":
             topic = entry["topic"]
-            target = topic["fixed"] if "fixed" in topic \
-                else rng.randrange(topic["uniform"])
+            if "fixed" in topic:
+                target = topic["fixed"]
+            elif i in zipf:
+                target = min(bisect.bisect_right(
+                    zipf[i], rng.random() * zipf[i][-1]), len(zipf[i]) - 1)
+            else:
+                target = rng.randrange(topic["uniform"])
             yield Frame(BROADCAST, target, entry["bytes"])
         else:
             to = entry["to"]
@@ -177,8 +226,17 @@ def frame_plan(seed: int, layout: Layout, flow: dict,
             yield Frame(DIRECT, target, entry["bytes"])
 
 
-def arrival_gaps(seed: int, publisher: int, rate_per_s: float) -> Iterator[int]:
-    """Nanoseconds between one publisher's open-loop arrivals (Poisson)."""
-    rng = random.Random(f"{seed}:{publisher}:arrivals")
-    while True:
-        yield int(rng.expovariate(rate_per_s) * 1e9)
+def arrivals(seed: int, publisher: int, rate_per_s: float, span_ns: int,
+             part: str) -> List[int]:
+    """When one publisher's open-loop frames are due inside a span (``part``
+    names it: the warm-up's, the window's), in nanoseconds from its start,
+    ascending: ``round(rate × span)`` of them whatever the seed, so that
+    every seed offers the same work. The gaps are those of a Poisson
+    process laid out evenly over their distribution (the n + 1
+    mid-quantiles of the exponential), scaled to fill the span, in an
+    order drawn from the seed; the last gap runs to the span's end."""
+    n = round(rate_per_s * span_ns / 1e9)
+    gaps = [-math.log(1 - (i + 0.5) / (n + 1)) for i in range(n + 1)]
+    scale = span_ns / sum(gaps)  # before the shuffle: the same for every seed
+    random.Random(f"{seed}:{publisher}:arrivals:{part}").shuffle(gaps)
+    return [int(t * scale) for t in itertools.accumulate(gaps[:n])]

@@ -7,7 +7,7 @@ per-layer metric sits in a file of its own, found by its name:
     benchmark/configs/<config>.json          (named by configs[].file)
     benchmark/traffic/<traffic>.json
     benchmark/launchers/<launcher>.py        (named by the configuration)
-    benchmark/layer_metrics/<metric>.py
+    benchmark/layer_metrics/<metric>.py      (``<metric>.<tag>`` shares it)
 
 No cell's name appears in code.
 """
@@ -28,11 +28,24 @@ LAYER = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 LAYERS = (
     "load_generator", "process_start", "compile_cache", "marshal_auth",
     "transport_ingress", "scalar_ingress", "stage_pack", "routing_step",
-    "kernels", "mesh_tick", "egress", "client_decode", "host_path", "device",
-    "end_to_end",
+    "kernels", "mesh_tick", "broker_links", "egress", "client_decode",
+    "host_path", "device", "end_to_end",
 )
 SOURCES = ("device_trace", "program_span", "program_counter", "host_clock")
 LOOPS = ("open", "windowed", "echo")
+# What the users connect over and sign with: a configuration's optional
+# ``user_transport`` and ``signature_scheme`` keys, the first of each
+# the default and the last what upstream's production definition uses. The names are the program's (``pushcdn_tpu/bin/common.py``:
+# ``TRANSPORTS``, ``SCHEMES``; a test holds these to be among them), the
+# choice is what upstream's definitions use (``cdn-proto/src/def.rs``).
+USER_TRANSPORTS = ("tcp", "tcp+tls")
+SIGNATURE_SCHEMES = ("ed25519", "bls-bn254")
+# The flag of ``bin/broker`` and ``bin/marshal`` that has to agree with
+# each key, and what the binaries take when the flag is not given.
+CLIENT_KEYS = {
+    "user_transport": (USER_TRANSPORTS, "--user-transport", "tcp+tls"),
+    "signature_scheme": (SIGNATURE_SCHEMES, "--scheme", "ed25519"),
+}
 
 
 def load(root: str = ROOT) -> dict:
@@ -53,8 +66,32 @@ def launcher_path(launcher: str) -> str:
     return os.path.join("benchmark", "launchers", f"{launcher}.py")
 
 
+def base_name(metric: str) -> str:
+    """``step_wall_ms.global1k`` is ``step_wall_ms``: a per-layer metric
+    entered apart (``<metric>.<tag>``), the same number from the same
+    reader, for the cells in which it moves another end-to-end metric
+    than the one its reader names (a cell that does not report that
+    one). An end-to-end metric is never entered apart."""
+    return metric.split(".", 1)[0]
+
+
 def layer_metric_path(metric: str) -> str:
-    return os.path.join("benchmark", "layer_metrics", f"{metric}.py")
+    return os.path.join("benchmark", "layer_metrics",
+                        f"{base_name(metric)}.py")
+
+
+def client_settings(config: dict) -> dict:
+    """The users' transport and signature scheme by name, as the
+    configuration states them or by default."""
+    return {key: config.get(key, names[0])
+            for key, (names, _flag, _unset) in CLIENT_KEYS.items()}
+
+
+def four_chip_cells_allowed(cells: int) -> int:
+    """A cell takes four chips only where what it measures exists only
+    across chips, and costs four times the chip time in every later
+    check: at most half the cells, rounded down, and one always."""
+    return max(1, cells // 2)
 
 
 def applies(metric: dict, workload: str) -> bool:
@@ -133,6 +170,7 @@ def lint(root: str = ROOT) -> List[str]:
                        f"{launcher_path(cfg['launcher'])}")
         if not any(w["config"] == entry["name"] for w in manifest["workloads"]):
             bad.append(f"config {entry['name']}: no cell uses it")
+        bad += [f"config {entry['name']}: {p}" for p in _lint_clients(cfg)]
 
     pairs = set()
     for w in manifest["workloads"]:
@@ -152,8 +190,9 @@ def lint(root: str = ROOT) -> List[str]:
         bad += [f"traffic {w['traffic']}: {p}" for p in
                 _lint_traffic(read_json(root, traffic_path(w["traffic"])))]
     four = sum(1 for w in manifest["workloads"] if w["chips"] == 4)
-    if four > max(1, len(manifest["workloads"]) // 2):
-        bad.append(f"{four} cells ask for 4 chips")
+    if four > four_chip_cells_allowed(len(manifest["workloads"])):
+        bad.append(f"{four} cells of {len(manifest['workloads'])} ask for "
+                   "4 chips")
 
     cells = [w["name"] for w in manifest["workloads"]]
     e2e = {m["name"]: m for m in manifest["end_to_end"]}
@@ -164,12 +203,16 @@ def lint(root: str = ROOT) -> List[str]:
             bad.append(f"metric {m['name']}: source {m['source']!r}")
         if not 0 < m["bound"] <= 0.25:
             bad.append(f"metric {m['name']}: bound {m['bound']}")
+        if m["name"] != base_name(m["name"]):
+            bad.append(f"metric {m['name']}: an end-to-end metric is not "
+                       "entered apart")
     for cell in cells:
         mine = [m for m in manifest["end_to_end"] if applies(m, cell)]
         if len(mine) < 2 or not any(m["name"] == "setup_s" for m in mine):
             bad.append(f"workload {cell}: needs setup_s and one more metric")
         if not any(applies(m, cell) for m in manifest["per_layer"]):
             bad.append(f"workload {cell}: no per-layer metric")
+    per_layer = {m["name"]: m for m in manifest["per_layer"]}
     for m in manifest["per_layer"]:
         name = m["name"]
         if m["source"] not in SOURCES:
@@ -193,12 +236,42 @@ def lint(root: str = ROOT) -> List[str]:
             bad.append(f"metric {name}: LAYER {getattr(module, 'LAYER', None)!r} "
                        "is not in the layer table")
         for attr, key in (("LAYER", "layer"), ("UNIT", "unit"),
-                          ("BETTER", "better"), ("SOURCE", "source"),
-                          ("MOVES", "moves")):
+                          ("BETTER", "better"), ("SOURCE", "source")):
             if getattr(module, attr, None) != m[key]:
                 bad.append(f"metric {name}: {attr} differs from BENCHMARK.json")
+        base = per_layer.get(base_name(name), m)
+        if base is m:
+            if getattr(module, "MOVES", None) != m["moves"]:
+                bad.append(f"metric {name}: MOVES differs from BENCHMARK.json")
+        elif m["moves"] == base["moves"] or any(
+                applies(m, c) and applies(base, c) for c in cells):
+            # entered apart: only to move another metric in other cells
+            bad.append(f"metric {name}: is {base['name']} entered apart, yet "
+                       "moves what it moves or shares a cell with it")
         if not callable(getattr(module, "read", None)):
             bad.append(f"metric {name}: no read(run)")
+    return bad
+
+
+def _lint_clients(cfg: dict) -> List[str]:
+    """The users and the deployment must agree on transport and scheme,
+    and a key at upstream's value is not a reduction."""
+    bad: List[str] = []
+    for key, value in client_settings(cfg).items():
+        names, flag, unset = CLIENT_KEYS[key]
+        if value not in names:
+            bad.append(f"{key} {value!r} is not one of {names}")
+            continue
+        if value == names[-1] and key in cfg.get("reduced", {}):
+            bad.append(f"{key} {value!r} is upstream's, yet listed "
+                       "under reduced")
+        for flags in ("broker_flags", "marshal_flags"):
+            argv = cfg.get(flags)
+            if argv is None:
+                continue  # a launcher that starts no such binary
+            said = argv[argv.index(flag) + 1] if flag in argv[:-1] else unset
+            if said != value:
+                bad.append(f"{key} is {value!r}, {flags} say {said!r}")
     return bad
 
 
@@ -218,6 +291,11 @@ def _lint_traffic(traffic: dict) -> List[str]:
                 bad.append(f"flow {flow.get('name')}: {entry['bytes']} bytes")
             if entry["kind"] not in ("broadcast", "direct"):
                 bad.append(f"flow {flow.get('name')}: kind {entry['kind']!r}")
+            topic = entry.get("topic", {})
+            if "zipf" in topic and not (
+                    isinstance(topic["zipf"], int) and topic["zipf"] >= 1
+                    and topic.get("s", 1.0) >= 0):
+                bad.append(f"flow {flow.get('name')}: topic {topic}")
     return bad
 
 

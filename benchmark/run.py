@@ -178,6 +178,7 @@ class Run:
     async def start(self) -> None:
         from benchmark import manifest
         cfg = self.cell.config
+        clients = manifest.client_settings(cfg)
         t_spawn = time.monotonic_ns()
         self.launcher = await Child.spawn(
             "launcher",
@@ -193,7 +194,9 @@ class Run:
                  "--seed", str(self.args.seed), "--proc", str(proc),
                  "--users", str(self.users), "--groups", str(self.groups),
                  "--sub-procs", str(self.sub_procs),
-                 "--pub-procs", str(self.pub_procs)],
+                 "--pub-procs", str(self.pub_procs),
+                 "--transport", clients["user_transport"],
+                 "--scheme", clients["signature_scheme"]],
                 os.path.join(self.workdir, f"pack{proc}.log")))
         # the first run in a checkout compiles: the contract gives it 1200 s
         self.ready = await self.launcher.expect("ready", 900)
@@ -221,7 +224,12 @@ class Run:
         say(f"{self.users} users connected in {self.setup['connect_s']:.2f}s")
 
     async def counters(self) -> dict:
-        return await self.launcher.ask("counters", "counters")
+        """Everything the launcher says: the program's own counters under
+        its names (``control.scalars``) and the launcher's beside them.
+        The launcher asks a busy broker for ``TOPOLOGY_WAIT_S`` at most."""
+        from benchmark.launchers import control
+        return await self.launcher.ask(
+            "counters", "counters", control.TOPOLOGY_WAIT_S + 15)
 
     # ---- one window -------------------------------------------------------
 
@@ -400,33 +408,29 @@ def end_to_end(w, setup_s: float) -> Dict[str, float]:
     }
 
 
-def verdict(w, run: Run, launcher_rc: Optional[int],
-            cpu_dry_run: bool) -> List[str]:
-    """Why the run is not correct; empty when it is."""
-    why = list(w.problems[:5])
-    if len(w.problems) > 5:
-        why.append(f"... and {len(w.problems) - 5} more streams differ")
-    why += [f"{k}: {v}" for k, v in w.client_faults.items() if v]
-    if w.publish_errors:
-        why.append(f"{w.publish_errors} publish errors")
-    if w.failed:
-        why.append(f"{w.failed} of {w.attempted} deliveries due in the "
-                   "window never arrived")
+def compared(w, run: Run, launcher_rc: Optional[int],
+             cpu_dry_run: bool) -> Dict[str, list]:
+    """Every number ``correct`` rests on beside its limit, as ``[number,
+    limit]``. Each comparison is exact: the run is correct when every
+    number equals its limit. The plain reference (``reference.py``, all
+    users, every frame) gives the first; the clients, the launcher and
+    the program's own counters the rest."""
     c = w.counters
-    if c["final"]["disabled"]:
-        why.append("the device plane disabled itself")
-    if c["end"]["programs"] != c["warm"]["programs"]:
-        why.append(f"{c['end']['programs'] - c['warm']['programs']} programs "
-                   "were compiled or loaded inside the window")
-    if c["before"]["users"] != run.users or c["before"]["unmirrored"]:
-        why.append(f"{c['before']['users']} users connected "
-                   f"({c['before']['unmirrored']} unmirrored), not {run.users}")
-    if launcher_rc != 0:
-        why.append(f"the launcher exited {launcher_rc}")
     platform = run.ready["device"]["platform"]
-    if platform == "cpu" and not cpu_dry_run:
-        why.append("the run found no accelerator")
-    return why
+    return {
+        "streams_differing": [len(w.problems), 0],
+        "deliveries_missing": [w.failed, 0],
+        "publish_errors": [w.publish_errors, 0],
+        **{k: [v, 0] for k, v in w.client_faults.items()},
+        "plane_disabled": [int(bool(c["final"]["disabled"])), 0],
+        "programs_in_window":
+            [c["end"]["programs"] - c["warm"]["programs"], 0],
+        "users_connected": [c["before"]["users"], run.users],
+        "users_unmirrored": [c["before"]["unmirrored"], 0],
+        "launcher_exit_code": [launcher_rc, 0],
+        "accelerator_missing":
+            [int(platform == "cpu" and not cpu_dry_run), 0],
+    }
 
 
 async def run_cell(cell, args, workdir: str, cpu_dry_run: bool) -> int:
@@ -456,19 +460,22 @@ async def run_cell(cell, args, workdir: str, cpu_dry_run: bool) -> int:
         "steps", "frames_staged", "messages_routed", "programs",
         "cache_hits", "cache_misses", "compile_s")}
         for k, v in w.counters.items()}))
+    say("counters at the end, every key: " + json.dumps(w.counters["final"]))
     quarters = [s / n / 1e6 if n else None
                 for s, n in zip(w.quarter_sum, w.quarter_n)]
     say(f"mean latency by quarter of the window (ms): {quarters}")
-    why = verdict(w, run, launcher_rc, cpu_dry_run)
-    for line in why:
-        say(f"NOT CORRECT: {line}")
+    checks = compared(w, run, launcher_rc, cpu_dry_run)
+    for problem in w.problems[:5]:  # which streams: ``checks`` only counts
+        say(f"differs from the reference: {problem}")
 
     numbers = end_to_end(w, setup_s)
     say("end to end" + (" (traced run: not reported as metrics)"
                         if trace else "") + f": {numbers}")
     device = dict(run.ready["device"])
     device["memory_peak_bytes"] = w.counters["final"]["memory_peak_bytes"]
-    line = {"correct": not why, "attempted": w.attempted, "failed": w.failed}
+    line = {"correct": all(number == limit
+                           for number, limit in checks.values()),
+            "attempted": w.attempted, "failed": w.failed}
     if trace:
         info = SimpleNamespace(
             window=w, setup=run.setup, device=device, config=cell.config,
@@ -491,13 +498,23 @@ async def run_cell(cell, args, workdir: str, cpu_dry_run: bool) -> int:
                 f"start_trace took {w.traced['start_call_s']:.3f}s, "
                 f"stop_trace {w.traced['stop_call_s']:.3f}s")
     else:
-        metrics = {m["name"]: {"value": numbers[m["name"]], "unit": m["unit"]}
-                   for m in cell.end_to_end
-                   if numbers.get(m["name"]) is not None}
+        metrics = {}
+        for m in cell.end_to_end:
+            value = numbers.get(m["name"])
+            if value is not None:
+                metrics[m["name"]] = {"value": value, "unit": m["unit"]}
     line["metrics"] = metrics
     line["device"] = device
+    # the verdict's one rendering is ``checks``, last in the line; the
+    # contract has the same numbers as standard error's last lines, where
+    # the driver's record of a run that is not correct keeps them
+    line["checks"] = checks
     if "jax" in sys.modules:
         raise Failure("the benchmark's parent imported jax")
+    sys.stdout.flush()
+    for name, (number, limit) in checks.items():
+        print(f"check {name}: {number} (limit {limit})", file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(line), flush=True)
     return 0
 

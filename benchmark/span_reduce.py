@@ -7,8 +7,11 @@ time of scalar ingress and of egress.
 The spans are ``jax.profiler.TraceAnnotation`` events the program emits
 (``pushcdn_tpu/parallel/spans.py`` names them): on ``/host:CPU``, one
 line per thread, the name bare and the keyword arguments as the event's
-stats. Lines of different threads may share a name, so lines are told
-apart by their position. A program without spans (an older commit)
+stats. A span is told from the runtime's own host events by its name
+alone, dotted and lower-case (``SPAN_NAME``: ``ingress.scan``,
+``plane.take``, and whatever a later PR adds under such a name); no list
+here has to know it. Lines of different threads may share a name, so
+lines are told apart by their position. A program without spans (an older commit)
 gives ``None`` here, and every reader then leaves its metric out.
 
 Run as a child of the benchmark's parent (which never imports jax), like
@@ -33,12 +36,20 @@ it). A step counts when the trace holds both its ``plane.take`` and its
 
 What lies between two worker spans of a step (Python between the phases)
 is in ``wall`` and in none of its parts.
+
+Beside the join, for every span name: ``spans[name]`` (count, total and
+median length) and ``stats[name][stat]``, the sum of each numeric stat
+over that span's events in the trace (``step`` left out: it is an
+index). A reader of a new span or stat asks
+``spans_of(run)["stats"].get(name, {}).get(stat)`` and returns ``None``
+where a commit's span has no such stat.
 """
 
 from __future__ import annotations
 
 import json
 import os
+import re
 import statistics
 import subprocess
 import sys
@@ -53,6 +64,7 @@ from benchmark.trace_reduce import covered, overlap, union  # noqa: E402
 WORKER = ("plane.h2d", "plane.dispatch", "plane.d2h", "plane.encode")
 LOOP = ("ingress.scan", "ingress.stage", "plane.take", "plane.egress")
 PARTS = ("wall", "handoff", "h2d", "dispatch", "d2h", "encode", "ring_wait")
+SPAN_NAME = re.compile(r"^[a-z][a-z0-9_]*(\.[a-z][a-z0-9_]*)+$")
 
 
 class Span(NamedTuple):
@@ -72,7 +84,7 @@ def load(path: str) -> List[Span]:
             continue
         for thread, line in enumerate(plane.lines):
             for e in line.events:
-                if e.name in WORKER or e.name in LOOP:
+                if SPAN_NAME.match(e.name):
                     spans.append(Span(e.name, thread, e.start_ns,
                                       e.start_ns + e.duration_ns,
                                       dict(e.stats)))
@@ -115,12 +127,21 @@ def reduce(spans: List[Span]) -> Optional[dict]:
     spans."""
     if not spans:
         return None
-    out: dict = {"spans": {}}
-    for name in LOOP + WORKER:
-        durs = [(s.end - s.start) / 1e6 for s in spans if s.name == name]
-        if durs:
-            out["spans"][name] = {"count": len(durs), "total_ms": sum(durs),
-                                  "median_ms": statistics.median(durs)}
+    out: dict = {"spans": {}, "stats": {}}
+    known = LOOP + WORKER
+    for name in known + tuple(sorted({s.name for s in spans} - set(known))):
+        mine = [s for s in spans if s.name == name]
+        if not mine:
+            continue
+        durs = [(s.end - s.start) / 1e6 for s in mine]
+        out["spans"][name] = {"count": len(durs), "total_ms": sum(durs),
+                              "median_ms": statistics.median(durs)}
+        sums = out["stats"][name] = {}
+        for s in mine:
+            for stat, value in s.stats.items():
+                if stat != "step" and isinstance(value, (int, float)) \
+                        and not isinstance(value, bool):
+                    sums[stat] = sums.get(stat, 0) + value
     rows = steps_of(spans)
     out["steps"] = len(rows)
     out["step_ms"] = {part: statistics.median(r[part] for r in rows)
@@ -176,6 +197,13 @@ def step_median_ms(run, part: str) -> Optional[float]:
     """Median over the traced steps of one of ``PARTS``."""
     spans = spans_of(run)
     return spans["step_ms"].get(part) if spans else None
+
+
+def stat_sum(run, span: str, stat: str) -> Optional[float]:
+    """Sum of one stat over one span's events in the traced window; None
+    where the trace has no such span or the span no such stat."""
+    spans = spans_of(run)
+    return spans.get("stats", {}).get(span, {}).get(stat) if spans else None
 
 
 def us_per(run, side: str, unit: str) -> Optional[float]:

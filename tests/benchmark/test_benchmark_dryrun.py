@@ -32,7 +32,14 @@ def test_dry_run_echo_sparse_on_explicit_cpu():
         env=_env(JAX_PLATFORMS="cpu"))
     assert proc.returncode == 0, proc.stdout[-3000:] + proc.stderr[-3000:]
     line = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert set(line) == {"correct", "attempted", "failed", "metrics", "device"}
+    assert list(line) == ["correct", "attempted", "failed", "metrics",
+                          "device", "checks"]
+    # every number ``correct`` rests on, beside its limit: all met
+    assert len(line["checks"]) >= 10
+    assert all(number == limit for number, limit in line["checks"].values())
+    assert line["checks"]["users_connected"] == [16, 16]
+    assert proc.stderr.strip().splitlines()[-1] == \
+        "check accelerator_missing: 0 (limit 0)"
     assert line["correct"] is True, proc.stdout[-3000:]
     assert line["failed"] == 0 and line["attempted"] > 100
     assert line["device"]["platform"] == "cpu"

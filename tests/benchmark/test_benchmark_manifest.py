@@ -3,10 +3,12 @@ meet the rules a later PR's added files are held to — and a cell, a
 configuration, a traffic mix and a layer metric can each be added with
 new files and new entries, editing nothing that is there."""
 
+import importlib.util
 import json
 import os
 import shutil
 import sys
+from types import SimpleNamespace
 
 import pytest
 
@@ -34,9 +36,33 @@ def test_contract_shape():
         assert 0.01 <= metric["bound"] <= 0.25, metric
     setup = next(x for x in m["end_to_end"] if x["name"] == "setup_s")
     assert setup["bound"] == 0.25
-    # at most one cell takes four chips, and only where the thing it
-    # measures exists only across chips
-    assert sum(w["chips"] == 4 for w in m["workloads"]) <= 1
+    # a cell takes four chips only where the thing it measures exists
+    # only across chips; how many may is the lint's rule, not one of the
+    # test's own (test_lint_catches holds it to three of five)
+    assert sum(w["chips"] == 4 for w in m["workloads"]) <= \
+        manifest.four_chip_cells_allowed(len(m["workloads"]))
+
+
+def test_the_four_chip_rule_is_half_the_cells_rounded_down_and_one_always():
+    assert [manifest.four_chip_cells_allowed(n) for n in (1, 2, 3, 5, 6, 24)] \
+        == [1, 1, 1, 2, 3, 12]
+
+
+def test_the_client_names_are_the_programs():
+    """The lint imports no program code; its two tuples are held here to
+    be names ``bin/common.py`` resolves."""
+    from pushcdn_tpu.bin import common
+    assert set(manifest.USER_TRANSPORTS) <= set(common.TRANSPORTS)
+    assert set(manifest.SIGNATURE_SCHEMES) <= set(common.SCHEMES)
+    base = manifest.read_json(REPO, "benchmark/configs/broker1-1k.json")
+    assert manifest.client_settings(base) == {
+        "user_transport": "tcp", "signature_scheme": "ed25519"}
+    # what the binaries take when a flag is not given, as the lint assumes
+    from pushcdn_tpu.bin import broker, marshal
+    for binary in (broker, marshal):
+        args = binary.build_parser().parse_args(["--discovery-endpoint", "x"])
+        for key, (_names, flag, unset) in manifest.CLIENT_KEYS.items():
+            assert getattr(args, flag[2:].replace("-", "_")) == unset, key
 
 
 @pytest.mark.parametrize("cell", [
@@ -50,7 +76,44 @@ def test_every_cell_resolves(cell):
     for m in c.per_layer:
         module = manifest.layer_metric(REPO, m["name"])
         assert module.LAYER in manifest.LAYERS and callable(module.read)
-        assert any(e["name"] == module.MOVES for e in c.end_to_end), m["name"]
+        assert any(e["name"] == m["moves"] for e in c.end_to_end), m["name"]
+        if m["name"] == manifest.base_name(m["name"]):
+            assert module.MOVES == m["moves"], m["name"]
+
+
+def test_a_per_layer_metric_entered_apart_is_its_base_moving_another_metric():
+    """``<metric>.<tag>``: the same number from the same reader, in cells
+    that do not report the end-to-end metric its reader names (PR 32:
+    ``global-steady``'s p50 scatters too widely for any bound, so it is
+    recorded per layer there, and the step's breakdown moves what that
+    cell still judges)."""
+    assert manifest.base_name("step_wall_ms.global1k") == "step_wall_ms"
+    assert manifest.base_name("setup_s") == "setup_s"
+    assert manifest.layer_metric_path("step_wall_ms.global1k") == \
+        manifest.layer_metric_path("step_wall_ms")
+    m = manifest.load(REPO)
+    assert all(x["name"] == manifest.base_name(x["name"])
+               for x in m["end_to_end"])
+    apart = [x for x in m["per_layer"]
+             if x["name"] != manifest.base_name(x["name"])]
+    assert apart
+    for x in apart:
+        base = next(y for y in m["per_layer"]
+                    if y["name"] == manifest.base_name(x["name"]))
+        assert not set(x["workloads"]) & set(base["workloads"]), x["name"]
+        for key in ("unit", "better", "source", "layer"):
+            assert x[key] == base[key], (x["name"], key)
+        assert x["moves"] != base["moves"], x["name"]
+    # no cell lost a reading: both steady cells carry the step's breakdown
+    # under one name or the other, and a median, judged or recorded
+    for cell, median in (
+            ("broker1-1k.global-steady", "steady_delivery_p50_ms"),
+            ("broker1-5k.global5k-steady", "delivery_p50_ms")):
+        c = manifest.Cell(m, cell, REPO)
+        assert {manifest.base_name(x["name"]) for x in c.per_layer} >= {
+            "step_wall_ms", "step_handoff_ms", "ring_wait_ms", "steps_per_s",
+            "step_device_us", "delivery_kernel_roofline", "gen_late_p99_ms"}
+        assert median in {x["name"] for x in c.per_layer + c.end_to_end}
 
 
 @pytest.fixture
@@ -62,43 +125,95 @@ def scratch(tmp_path):
     return str(root)
 
 
+METRIC_FILE = '''"""A dummy."""
+
+{imports}LAYER = "{layer}"
+UNIT = "x"
+BETTER = "lower"
+SOURCE = "{source}"
+MOVES = "delivery_p50_ms"
+
+
+def read(run):
+    return {expr}
+'''
+# name -> (layer, source, what read() returns, imports): one on an old
+# layer that reads nothing, one on the broker_links layer, one that reads
+# a stat of a span and one a counter that no file here names
+DUMMY_METRICS = {
+    "dummy_metric": ("egress", "program_counter", "None", ""),
+    "dummy_links_metric": ("broker_links", "host_clock", "None", ""),
+    "dummy_span_metric": (
+        "broker_links", "program_span",
+        'span_reduce.stat_sum(run, "links.forward", "peers")',
+        "from benchmark import span_reduce\n\n"),
+    "dummy_counter_metric": (
+        "broker_links", "program_counter",
+        'run.window.counters["end"].get("frames_forwarded")', ""),
+}
+DUMMY_CELLS = ("dummy-config.dummy-mix", "dummy-mesh.dummy-mix",
+               "dummy-prod.dummy-mix")
+
+
 def _add_dummies(root):
-    """What a later PR would add: files, and entries at the ends of lists."""
+    """What the next PRs would add: files, and entries at the ends of
+    lists. A plain cell; a second four-chip cell on ``mesh_inprocess``; a
+    deployment whose users come over TCP+TLS with BLS-BN254 keys, as
+    upstream's do; and the metrics of ``DUMMY_METRICS``."""
     def write(path, obj):
         with open(os.path.join(root, path), "w") as f:
             f.write(obj if isinstance(obj, str) else json.dumps(obj))
 
     base = manifest.read_json(root, "benchmark/configs/broker1-1k.json")
-    write("benchmark/configs/dummy-config.json",
-          {**base, "name": "dummy-config", "source": "https://example.org/x"})
+    mesh = manifest.read_json(root, "benchmark/configs/mesh4-1k.json")
+    prod = {**base, "user_transport": "tcp+tls",
+            "signature_scheme": "bls-bn254",
+            "broker_flags": ["--device-plane", "--user-transport", "tcp+tls",
+                             "--scheme", "bls-bn254"],
+            "marshal_flags": ["--user-transport", "tcp+tls",
+                              "--scheme", "bls-bn254"],
+            "reduced": {"brokers": base["reduced"]["brokers"]}}
+    m = manifest.load(root)
+    for name, cfg in (("dummy-config", base), ("dummy-mesh", mesh),
+                      ("dummy-prod", prod)):
+        source = f"https://example.org/{name}"
+        write(f"benchmark/configs/{name}.json",
+              {**cfg, "name": name, "source": source})
+        m["configs"].append({
+            "name": name, "source": source,
+            "file": f"benchmark/configs/{name}.json",
+            "reduced": sorted(cfg["reduced"]), "why": "a dummy"})
+        m["workloads"].append({
+            "name": f"{name}.dummy-mix", "config": name,
+            "traffic": "dummy-mix", "chips": cfg["chips"],
+            "why": "a dummy cell"})
     write("benchmark/traffic/dummy-mix.json", {
         "name": "dummy-mix", "who": "nobody", "why": "a dummy",
-        "subscriptions": [{"users": "all", "topic": {"fixed": 3}}],
+        "subscriptions": [{"users": "all", "topic": {"mod": 8}}],
         "flows": [{"name": "f", "publishers": 2,
                    "loop": {"kind": "open", "arrivals": "poisson",
                             "rate_per_s": 10.0},
-                   "mix": [{"share": 1.0, "kind": "broadcast", "bytes": 100,
-                            "topic": {"fixed": 3}}]}]})
-    write("benchmark/layer_metrics/dummy_metric.py",
-          '"""A dummy."""\n\nLAYER = "egress"\nUNIT = "x"\nBETTER = "lower"\n'
-          'SOURCE = "program_counter"\nMOVES = "delivery_p50_ms"\n\n\n'
-          'def read(run):\n    return None\n')
-    m = manifest.load(root)
-    m["configs"].append({
-        "name": "dummy-config", "source": "https://example.org/x",
-        "file": "benchmark/configs/dummy-config.json",
-        "reduced": sorted(base["reduced"]), "why": "a dummy"})
-    m["workloads"].append({
-        "name": "dummy-config.dummy-mix", "config": "dummy-config",
-        "traffic": "dummy-mix", "chips": 1, "why": "a dummy cell"})
+                   "mix": [{"share": 0.5, "kind": "broadcast", "bytes": 100,
+                            "topic": {"fixed": 3}},
+                           {"share": 0.5, "kind": "broadcast", "bytes": 100,
+                            "topic": {"zipf": 8, "s": 1.0}}]}]})
     for metric in m["end_to_end"]:
-        if metric["name"] in ("delivery_p50_ms", "delivery_p99_ms"):
-            metric["workloads"].append("dummy-config.dummy-mix")
-    m["per_layer"].append({
-        "name": "dummy_metric", "unit": "x", "better": "lower",
-        "source": "program_counter", "layer": "egress",
-        "moves": "delivery_p50_ms",
-        "workloads": ["dummy-config.dummy-mix"]})
+        if metric["name"] == "delivery_p50_ms":
+            # the last cell's median is not judged: its per-layer metrics
+            # are entered apart there and move what it does report: an
+            # entry each, no file, no code
+            metric["workloads"] += DUMMY_CELLS[:-1]
+    for name, (layer, source, expr, imports) in DUMMY_METRICS.items():
+        write(f"benchmark/layer_metrics/{name}.py", METRIC_FILE.format(
+            layer=layer, source=source, expr=expr, imports=imports))
+        entry = {
+            "name": name, "unit": "x", "better": "lower", "source": source,
+            "layer": layer, "moves": "delivery_p50_ms",
+            "workloads": list(DUMMY_CELLS[:-1])}
+        m["per_layer"] += [entry, {
+            **entry, "name": name + ".dummy",
+            "moves": "broker_cpu_us_per_delivery",
+            "workloads": [DUMMY_CELLS[-1]]}]
     write("BENCHMARK.json", m)
 
 
@@ -112,29 +227,117 @@ def test_a_cell_config_mix_and_metric_are_added_by_files_alone(scratch):
                     before[path] = f.read()
     _add_dummies(scratch)
     assert manifest.lint(scratch) == []
-    cell = manifest.Cell(manifest.load(scratch), "dummy-config.dummy-mix",
-                         scratch)
-    assert [m["name"] for m in cell.per_layer if m["name"] == "dummy_metric"]
+    m = manifest.load(scratch)
+    assert [w["chips"] for w in m["workloads"]].count(4) == 2
+    for name in DUMMY_CELLS:
+        cell = manifest.Cell(m, name, scratch)
+        assert set(DUMMY_METRICS) <= {
+            manifest.base_name(x["name"]) for x in cell.per_layer}
+    assert {name + ".dummy" for name in DUMMY_METRICS} <= {
+        x["name"] for x in cell.per_layer}
+    assert "delivery_p50_ms" not in {x["name"] for x in cell.end_to_end}
+    assert manifest.client_settings(cell.config) == {
+        "user_transport": "tcp+tls", "signature_scheme": "bls-bn254"}
+    # the new span metric and the new counter metric read what the
+    # program says through the two pass-throughs, and nothing where an
+    # older commit says nothing
+    from benchmark import span_reduce
+    from benchmark.span_reduce import Span
+    spans = span_reduce.reduce([
+        Span("links.forward", 0, 0.0, 1e6, {"peers": 3, "step": 1}),
+        Span("links.forward", 0, 2e6, 3e6, {"peers": 2, "step": 2})])
+    new = SimpleNamespace(window=SimpleNamespace(
+        spans=spans, counters={"end": {"frames_forwarded": 7}}))
+    old = SimpleNamespace(window=SimpleNamespace(
+        spans=span_reduce.reduce([Span("plane.take", 0, 0.0, 1e6, {})]),
+        counters={"end": {}}))
+    for name, want in (("dummy_span_metric", 5), ("dummy_counter_metric", 7),
+                       ("dummy_links_metric", None)):
+        reader = manifest.layer_metric(scratch, name)
+        assert reader.read(new) == want and reader.read(old) is None, name
+    # the plan pin of test_benchmark_arith.py holds on the copy too: a new
+    # traffic file and new cells on it are none of its business
+    spec = importlib.util.spec_from_file_location(
+        "benchmark_arith_tests", os.path.join(
+            os.path.dirname(os.path.abspath(__file__)),
+            "test_benchmark_arith.py"))
+    arith = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(arith)
+    assert "dummy-mix" not in arith.PLAN_PIN
+    for traffic in arith.PLAN_PIN:
+        arith.assert_plan_pinned(scratch, traffic)
     for path, content in before.items():  # nothing that was there changed
         with open(path, "rb") as f:
             assert f.read() == content, path
 
 
+def _edit(root, path, change):
+    """Change one of the scratch copy's JSON files in place."""
+    obj = manifest.read_json(root, path)
+    change(obj)
+    with open(os.path.join(root, path), "w") as f:
+        json.dump(obj, f)
+
+
+def _three_of_five_on_four_chips(m, _root):
+    for w in m["workloads"][:2]:
+        w["chips"] = 4
+
+
+BROKER1 = "benchmark/configs/broker1-1k.json"
+
+
 @pytest.mark.parametrize("breakage,needle", [
-    (lambda m: m["workloads"][0].update(traffic="nope"), "no benchmark/traffic/nope.json"),
-    (lambda m: m["workloads"][0].update(chips=4), "chips differ"),
-    (lambda m: m["per_layer"][0].update(moves="nope"), "no end-to-end metric"),
-    (lambda m: m["per_layer"][0].update(layer="made_up"), "LAYER differs"),
-    (lambda m: m["per_layer"][0].update(layer="made up"), "layer 'made up' is not plain"),
-    (lambda m: m["per_layer"].append(dict(m["per_layer"][0], name="ghost")),
+    (lambda m, _: m["workloads"][0].update(traffic="nope"), "no benchmark/traffic/nope.json"),
+    (lambda m, _: m["workloads"][0].update(chips=4), "chips differ"),
+    (lambda m, _: m["per_layer"][0].update(moves="nope"), "no end-to-end metric"),
+    (lambda m, _: m["per_layer"][0].update(layer="made_up"), "LAYER differs"),
+    (lambda m, _: m["per_layer"][0].update(layer="made up"), "layer 'made up' is not plain"),
+    (lambda m, _: m["per_layer"].append(dict(m["per_layer"][0], name="ghost")),
      "no benchmark/layer_metrics/ghost.py"),
-    (lambda m: m["workloads"][0].update(name="bad name!"), "is not plain"),
-    (lambda m: m["end_to_end"][0].update(bound=0.5), "bound 0.5"),
-    (lambda m: m["configs"][0].update(reduced=[]), "reduced differs"),
+    (lambda m, _: m["workloads"][0].update(name="bad name!"), "is not plain"),
+    (lambda m, _: m["end_to_end"][0].update(bound=0.5), "bound 0.5"),
+    (lambda m, _: m["configs"][0].update(reduced=[]), "reduced differs"),
+    # half the cells, rounded down: two of five, so not three (three of
+    # six would pass, as it does at the driver)
+    (_three_of_five_on_four_chips, "3 cells of 5 ask for 4 chips"),
+    (lambda _, root: _edit(root, BROKER1, lambda c: c.update(
+        user_transport="quic")), "user_transport 'quic' is not one of"),
+    # the clients would sign with BLS, the broker and the marshal verify
+    # Ed25519 (no --scheme among the flags)
+    (lambda _, root: _edit(root, BROKER1, lambda c: c.update(
+        signature_scheme="bls-bn254")),
+     "signature_scheme is 'bls-bn254', broker_flags say 'ed25519'"),
+    (lambda _, root: _edit(root, BROKER1, lambda c: c.update(
+        user_transport="tcp+tls",
+        broker_flags=["--device-plane", "--user-transport", "tcp+tls"],
+        marshal_flags=["--user-transport", "tcp+tls"])),
+     "user_transport 'tcp+tls' is upstream's, yet listed under reduced"),
+    # only a per-layer metric is entered apart, and only to move another
+    # metric in cells its base does not list
+    (lambda m, _: m["end_to_end"].append(dict(
+        m["end_to_end"][0], name=m["end_to_end"][0]["name"] + ".twice")),
+     "an end-to-end metric is not entered apart"),
+    (lambda m, _: m["per_layer"].append(dict(
+        m["per_layer"][0], name=m["per_layer"][0]["name"] + ".apart",
+        moves="setup_s")),
+     "metric gen_late_p99_ms.apart: is gen_late_p99_ms entered apart, yet"),
+    (lambda m, _: m["per_layer"].append(dict(
+        m["per_layer"][0], name=m["per_layer"][0]["name"] + ".apart",
+        workloads=["broker1-1k.echo-sparse"])),
+     "metric gen_late_p99_ms.apart: is gen_late_p99_ms entered apart, yet"),
+    (lambda _, root: _edit(
+        root, "benchmark/traffic/fanout4-sat.json",
+        lambda t: t["flows"][0]["mix"][0].update(topic={"zipf": 0})),
+     "topic {'zipf': 0}"),
+    (lambda _, root: _edit(
+        root, "benchmark/traffic/fanout4-sat.json",
+        lambda t: t["flows"][0]["mix"][0].update(
+            topic={"zipf": 250, "s": -1.0})), "topic {'zipf': 250, 's': -1.0}"),
 ])
 def test_lint_catches(scratch, breakage, needle):
     m = manifest.load(scratch)
-    breakage(m)
+    breakage(m, scratch)
     with open(os.path.join(scratch, "BENCHMARK.json"), "w") as f:
         json.dump(m, f)
     assert any(needle in p for p in manifest.lint(scratch)), \
