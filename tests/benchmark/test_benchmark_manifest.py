@@ -1,12 +1,16 @@
 """Manifest lint (ISSUE 22): ``BENCHMARK.json`` and every file it names
 meet the rules a later PR's added files are held to — and a cell, a
 configuration, a traffic mix and a layer metric can each be added with
-new files and new entries, editing nothing that is there."""
+new files and new entries, editing nothing that is there. The tests
+themselves hold on a manifest that has grown (ISSUE 33): no case counts the
+committed cells, and the ones that start no deployment are run on a tree
+with one more cell."""
 
 import importlib.util
 import json
 import os
 import shutil
+import subprocess
 import sys
 from types import SimpleNamespace
 
@@ -38,7 +42,8 @@ def test_contract_shape():
     assert setup["bound"] == 0.25
     # a cell takes four chips only where the thing it measures exists
     # only across chips; how many may is the lint's rule, not one of the
-    # test's own (test_lint_catches holds it to three of five)
+    # test's own (test_lint_catches holds it to one more than the rule
+    # allows of the cells there are: three of five today)
     assert sum(w["chips"] == 4 for w in m["workloads"]) <= \
         manifest.four_chip_cells_allowed(len(m["workloads"]))
 
@@ -225,10 +230,11 @@ def test_a_cell_config_mix_and_metric_are_added_by_files_alone(scratch):
                 path = os.path.join(dirpath, name)
                 with open(path, "rb") as f:
                     before[path] = f.read()
+    four_before = _four_chip_cells(manifest.load(scratch))
     _add_dummies(scratch)
     assert manifest.lint(scratch) == []
     m = manifest.load(scratch)
-    assert [w["chips"] for w in m["workloads"]].count(4) == 2
+    assert _four_chip_cells(m) == four_before + 1  # ``dummy-mesh``'s
     for name in DUMMY_CELLS:
         cell = manifest.Cell(m, name, scratch)
         assert set(DUMMY_METRICS) <= {
@@ -279,9 +285,59 @@ def _edit(root, path, change):
         json.dump(obj, f)
 
 
-def _three_of_five_on_four_chips(m, _root):
-    for w in m["workloads"][:2]:
+def _save(root, m):
+    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
+        json.dump(m, f)
+
+
+def _assert_lint_says(root, breakage, needle):
+    """Break ``root``'s manifest and find ``needle`` (text, or what to
+    look for given the manifest before the breakage) in what the lint says."""
+    m = manifest.load(root)
+    if callable(needle):
+        needle = needle(m)
+    breakage(m, root)
+    _save(root, m)
+    problems = manifest.lint(root)
+    assert any(needle in p for p in problems), problems
+
+
+def _four_chip_cells(m):
+    return sum(w["chips"] == 4 for w in m["workloads"])
+
+
+def _one_four_chip_cell_too_many(m, _root):
+    """As many one-chip cells moved to four chips as it takes to stand one
+    over what the lint's own rule allows of the cells there are."""
+    more = manifest.four_chip_cells_allowed(len(m["workloads"])) + 1 \
+        - _four_chip_cells(m)
+    one_chip = [w for w in m["workloads"] if w["chips"] == 1]
+    assert 0 < more <= len(one_chip)
+    for w in one_chip[:more]:
         w["chips"] = 4
+
+
+def _one_too_many_said(m):
+    """The lint's message for it: three of five today, four of six with
+    the next cell, five of the grown copy's eight."""
+    cells = len(m["workloads"])
+    return (f"{manifest.four_chip_cells_allowed(cells) + 1} cells of "
+            f"{cells} ask for 4 chips")
+
+
+def _a_cell_the_first_leaves_out(m):
+    """A cell that reports what the first per-layer metric moves and is
+    not among that metric's own (``echo-sparse`` today)."""
+    first = m["per_layer"][0]
+    moved = next(e for e in m["end_to_end"] if e["name"] == first["moves"])
+    return next(w["name"] for w in m["workloads"]
+                if manifest.applies(moved, w["name"])
+                and not manifest.applies(first, w["name"]))
+
+
+def _first_entered_apart_said(m):
+    name = m["per_layer"][0]["name"]
+    return f"metric {name}.apart: is {name} entered apart, yet"
 
 
 BROKER1 = "benchmark/configs/broker1-1k.json"
@@ -289,7 +345,8 @@ BROKER1 = "benchmark/configs/broker1-1k.json"
 
 @pytest.mark.parametrize("breakage,needle", [
     (lambda m, _: m["workloads"][0].update(traffic="nope"), "no benchmark/traffic/nope.json"),
-    (lambda m, _: m["workloads"][0].update(chips=4), "chips differ"),
+    (lambda m, _: next(w for w in m["workloads"] if w["chips"] == 1).update(
+        chips=4), "chips differ"),
     (lambda m, _: m["per_layer"][0].update(moves="nope"), "no end-to-end metric"),
     (lambda m, _: m["per_layer"][0].update(layer="made_up"), "LAYER differs"),
     (lambda m, _: m["per_layer"][0].update(layer="made up"), "layer 'made up' is not plain"),
@@ -298,9 +355,9 @@ BROKER1 = "benchmark/configs/broker1-1k.json"
     (lambda m, _: m["workloads"][0].update(name="bad name!"), "is not plain"),
     (lambda m, _: m["end_to_end"][0].update(bound=0.5), "bound 0.5"),
     (lambda m, _: m["configs"][0].update(reduced=[]), "reduced differs"),
-    # half the cells, rounded down: two of five, so not three (three of
-    # six would pass, as it does at the driver)
-    (_three_of_five_on_four_chips, "3 cells of 5 ask for 4 chips"),
+    # half the cells, rounded down, and one more: three of five (three of
+    # six would pass, as it does at the driver), whatever cells there are
+    (_one_four_chip_cell_too_many, _one_too_many_said),
     (lambda _, root: _edit(root, BROKER1, lambda c: c.update(
         user_transport="quic")), "user_transport 'quic' is not one of"),
     # the clients would sign with BLS, the broker and the marshal verify
@@ -320,12 +377,11 @@ BROKER1 = "benchmark/configs/broker1-1k.json"
      "an end-to-end metric is not entered apart"),
     (lambda m, _: m["per_layer"].append(dict(
         m["per_layer"][0], name=m["per_layer"][0]["name"] + ".apart",
-        moves="setup_s")),
-     "metric gen_late_p99_ms.apart: is gen_late_p99_ms entered apart, yet"),
+        moves="setup_s")), _first_entered_apart_said),
     (lambda m, _: m["per_layer"].append(dict(
         m["per_layer"][0], name=m["per_layer"][0]["name"] + ".apart",
-        workloads=["broker1-1k.echo-sparse"])),
-     "metric gen_late_p99_ms.apart: is gen_late_p99_ms entered apart, yet"),
+        workloads=[_a_cell_the_first_leaves_out(m)])),
+     _first_entered_apart_said),
     (lambda _, root: _edit(
         root, "benchmark/traffic/fanout4-sat.json",
         lambda t: t["flows"][0]["mix"][0].update(topic={"zipf": 0})),
@@ -335,10 +391,98 @@ BROKER1 = "benchmark/configs/broker1-1k.json"
         lambda t: t["flows"][0]["mix"][0].update(
             topic={"zipf": 250, "s": -1.0})), "topic {'zipf': 250, 's': -1.0}"),
 ])
-def test_lint_catches(scratch, breakage, needle):
+@pytest.mark.parametrize("grown", [False, True], ids=["committed", "grown"])
+def test_lint_catches(scratch, breakage, needle, grown):
+    """Each breakage on the committed manifest, and again on the copy
+    ``_add_dummies`` has grown (three more cells, one of them on four
+    chips, three configurations, a mix, eight per-layer entries): a case
+    that counts the cells there are, or finds its entry by a place that an
+    appended entry moves, fails here in the PR that writes it and not in
+    the PR that adds a cell."""
+    if grown:
+        _add_dummies(scratch)
+    _assert_lint_says(scratch, breakage, needle)
+
+
+def _append_a_cell(root, chips):
+    """One more cell as the queue's next PRs will bring it, but for the
+    files: a committed configuration on ``chips`` chips under a committed
+    mix it does not run yet, at the end of ``workloads``, its name added
+    to every ``workloads`` list that holds a committed cell of that
+    configuration. Returns its name."""
+    m = manifest.load(root)
+    like = next(w for w in m["workloads"] if w["chips"] == chips)
+    pairs = {(w["config"], w["traffic"]) for w in m["workloads"]}
+    traffic = next(w["traffic"] for w in m["workloads"]
+                   if (like["config"], w["traffic"]) not in pairs)
+    name = f"{like['config']}.{traffic}"
+    m["workloads"].append({
+        "name": name, "config": like["config"], "traffic": traffic,
+        "chips": chips, "why": "one more cell"})
+    for metric in m["end_to_end"] + m["per_layer"]:
+        if like["name"] in metric.get("workloads", ()):
+            metric["workloads"].append(name)
+    _save(root, m)
+    return name
+
+
+# One more cell on the committed manifest, and the tests of this directory
+# that start no deployment run on that tree in a child: what a
+# ``model_config`` PR's tier-1 run will be. The child holds the lint, the
+# whole of ``test_lint_catches`` (its four-chip case with it) and every
+# per-cell assertion of the three files to the grown manifest; a test that
+# pins the committed set of cells fails here, in the PR that writes it.
+GROWN_TREE_TESTS = ("test_benchmark_manifest.py", "test_span_reduce.py",
+                    "test_benchmark_arith.py")
+# Left out of the child by node id, not by a pattern of names: the one test
+# of those files that starts a deployment of a committed cell (tens of
+# seconds, and nothing of it reads the set of cells), and this test itself.
+# A later test of those files that starts one runs in the child, inside its
+# time limit, until its own PR lists it here.
+NOT_IN_THE_CHILD = (
+    "test_span_reduce.py::"
+    "test_traced_dry_run_reports_the_host_side_of_the_step",
+    "test_benchmark_manifest.py::"
+    "test_one_more_cell_leaves_the_tests_that_start_nothing_whole",
+)
+
+
+@pytest.mark.parametrize("chips", [1, 4], ids=["one_chip", "four_chips"])
+def test_one_more_cell_leaves_the_tests_that_start_nothing_whole(
+        scratch, chips):
+    before = manifest.load(scratch)
+    name = _append_a_cell(scratch, chips)
+    # what the child cannot say of the cell it does not know by name
     m = manifest.load(scratch)
-    breakage(m, scratch)
-    with open(os.path.join(scratch, "BENCHMARK.json"), "w") as f:
-        json.dump(m, f)
-    assert any(needle in p for p in manifest.lint(scratch)), \
-        manifest.lint(scratch)
+    assert len(m["workloads"]) == len(before["workloads"]) + 1
+    assert _four_chip_cells(m) == _four_chip_cells(before) + (chips == 4)
+    cell = manifest.Cell(m, name, scratch)
+    assert len(cell.end_to_end) >= 2 and cell.per_layer
+
+    here = os.path.dirname(os.path.abspath(__file__))
+    there = os.path.join(scratch, "tests", "benchmark")
+    os.makedirs(there)
+    for name in GROWN_TREE_TESTS:
+        shutil.copy(os.path.join(here, name), there)
+    for node in NOT_IN_THE_CHILD:  # pytest says nothing of an id it has not
+        path, func = node.split("::")
+        with open(os.path.join(there, path)) as f:
+            assert f"\ndef {func}(" in f.read(), node
+    # the recorded trace two of them read, and the program beside them as
+    # it is: one test holds the lint's two tuples to be names
+    # ``bin/common.py`` resolves
+    shutil.copytree(os.path.join(REPO, "benchmark", "fixtures"),
+                    os.path.join(scratch, "benchmark", "fixtures"))
+    os.symlink(os.path.join(REPO, "pushcdn_tpu"),
+               os.path.join(scratch, "pushcdn_tpu"))
+    env = {k: v for k, v in os.environ.items() if not k.startswith("PYTEST_")}
+    proc = subprocess.run(
+        [sys.executable, "-m", "pytest", "-q", "-p", "no:cacheprovider",
+         "--rootdir", scratch,
+         *(f"--deselect=tests/benchmark/{node}" for node in NOT_IN_THE_CHILD),
+         *(os.path.join(there, name) for name in GROWN_TREE_TESTS)],
+        capture_output=True, text=True, timeout=120, cwd=scratch,
+        env={**env, "JAX_PLATFORMS": "cpu"})
+    assert proc.returncode == 0, proc.stdout[-6000:] + proc.stderr[-2000:]
+    assert " passed" in proc.stdout and "failed" not in proc.stdout
+    assert " deselected" in proc.stdout
