@@ -186,6 +186,7 @@ class MeshShardPlane:
             "messages_routed": g.messages_routed,
             "egress_inline": g.egress_inline,
             "egress_queued": g.egress_queued,
+            "egress_batched": g.egress_batched,
         }
 
     @property
@@ -211,6 +212,39 @@ class MeshShardPlane:
     @property
     def messages_routed(self) -> int:
         return self.group.messages_routed
+
+
+class _Members:
+    """What ``tasks/senders`` asks of a broker, a user's link and its
+    removal after a failed send, answered by the member that holds the
+    user: an egress job spans the shards, a user's connection lives on
+    one. A user whose shard has stopped, or who left mid-step, has no
+    link, and its stream is dropped as a lone broker drops it."""
+
+    def __init__(self, group: "MeshBrokerGroup"):
+        self._group = group
+        self.connections = self
+
+    def _member(self, public_key: bytes) -> Optional["Broker"]:
+        group = self._group
+        slot = group.slots.slot_of(public_key)
+        owner = ABSENT if slot is None else int(group._owner[slot])
+        return None if owner == ABSENT else group.brokers[owner]
+
+    def get_user_connection(self, public_key: bytes):
+        broker = self._member(public_key)
+        return None if broker is None \
+            else broker.connections.get_user_connection(public_key)
+
+    def remove_user(self, public_key: bytes, reason: str = "") -> None:
+        broker = self._member(public_key)
+        if broker is not None:
+            broker.connections.remove_user(public_key, reason=reason)
+
+    def update_metrics(self) -> None:
+        for broker in self._group.brokers:
+            if broker is not None:
+                broker.update_metrics()
 
 
 class MeshBrokerGroup:
@@ -282,6 +316,8 @@ class MeshBrokerGroup:
         # senders.egress_streams tallies all three)
         self.egress_inline = 0
         self.egress_queued = 0
+        self.egress_batched = 0  # of the inline ones: by the native batch
+        self._members = _Members(self)
         # collectives traced by the most recently COMPILED step
         # specialization (router.trace_collectives delta around the call):
         # the counted one-collective-per-tick invariant, asserted by the
@@ -580,6 +616,21 @@ class MeshBrokerGroup:
                 + sum(b.total_used
                       for bkts in self.lane_buckets for b in bkts))
 
+    def _back_pressured(self) -> bool:
+        """Whether a stager of the base lane waits on this tick: a live
+        shard's base ring has no free slot, or one of its base direct
+        buckets stands at its capacity (``DirectBuckets.push`` refuses).
+        The tick is lockstep, one period for all shards, so the
+        observation is the group's: while any shard's publishers wait on
+        the tick, the length of every shard's sends is their rate. The
+        wide lanes do not count, as ``DevicePlane`` counts its base ring
+        only."""
+        return any(
+            live and (not ring.free_slots
+                      or buckets.max_used >= buckets.capacity)
+            for live, ring, buckets in zip(
+                self._liveness, self.lane_rings[0], self.lane_buckets[0]))
+
     async def _pump(self) -> None:
         c = self.config
         loop = asyncio.get_running_loop()
@@ -617,6 +668,7 @@ class MeshBrokerGroup:
             waited = (0.0 if self._staged_since is None
                       else time.monotonic() - self._staged_since)
             self._staged_since = None
+            back_pressured = self._back_pressured()
             with spans.span("plane.take", step=step, frames=staged,
                             ring_wait_us=int(waited * 1e6)):
                 # one-tick snapshot: all lanes' rings + buckets + mirrors
@@ -647,21 +699,21 @@ class MeshBrokerGroup:
                     liveness, rev, step)
                 gate.stepped(loop.time())
                 with spans.span("plane.egress", step=step) as sp:
-                    routed, inline, queued = (
+                    routed, inline, queued, batched = (
                         self.messages_routed, self.egress_inline,
-                        self.egress_queued)
-                    for shard, streams, d2, lengths, frames in egress_jobs:
-                        broker = self.brokers[shard]
-                        if broker is None:
-                            continue
+                        self.egress_queued, self.egress_batched)
+                    for streams, d2, lengths, frames in egress_jobs:
                         if streams is not None:
-                            egress_streams(self, broker, streams)
+                            egress_streams(self, self._members, streams,
+                                           back_pressured)
                         else:
-                            self._egress_py(broker, d2, lengths, frames)
+                            self._egress_py(self._members, d2, lengths,
+                                            frames)
                     sp.set_metadata(
                         deliveries=self.messages_routed - routed,
                         inline=self.egress_inline - inline,
-                        queued=self.egress_queued - queued)
+                        queued=self.egress_queued - queued,
+                        batched=self.egress_batched - batched)
             except asyncio.CancelledError:
                 raise
             except Exception:
@@ -696,7 +748,7 @@ class MeshBrokerGroup:
         The device user table is re-uploaded only when ``state_rev`` moved
         (steady state pays zero H2D for state), and egress payloads come
         from the HOST snapshots when ``gather_frame_bytes`` is off — the
-        step returns per-shard egress jobs, each either a native
+        step returns one egress job a busy lane, each either a native
         :class:`native.EgressStreams` (encoded right here, off the event
         loop) or the Python-fallback (deliver, lengths, frames) triple.
 
@@ -838,38 +890,43 @@ class MeshBrokerGroup:
             return self._encode_jobs(jobs)
 
     def _encode_jobs(self, jobs) -> list:
-        """Per-shard egress jobs from one step's read-back decisions
-        (worker thread; the step's ``plane.encode`` span)."""
+        """One egress job a busy lane from one step's read-back decisions
+        (worker thread; the step's ``plane.encode`` span). A shard
+        delivers to the users it owns and a user has one owner, so the
+        shards' matrices of a lane hold no user twice: they encode as ONE
+        matrix, one stream a user whichever member holds it
+        (:class:`_Members` finds the link), and a back-pressured tick's
+        sends leave in one native batch a lane, not one a shard."""
         from pushcdn_tpu import native as native_mod
         B = self.num_shards
         out = []
         for deliver, lengths, blocks, direct_lane in jobs:
-            for shard in range(B):
-                if self.brokers[shard] is None:
-                    continue
-                d2 = deliver[shard]
-                if not d2.any():
-                    continue
+            if direct_lane is None:
+                # every shard decided over the same gathered frames
+                d2 = deliver.any(axis=0)
+            else:
+                # the all_to_all gave each shard frames of its own: its
+                # columns stand beside the other shards'
+                d2 = deliver.transpose(1, 0, 2).reshape(deliver.shape[1], -1)
                 if direct_lane == "per-shard":
-                    s_lengths = lengths[shard]
-                    s_blocks = [blocks[shard]]
-                elif direct_lane is not None:
-                    s_lengths = np.concatenate(
-                        [direct_lane[src].length[shard] for src in range(B)])
-                    s_blocks = [direct_lane[src].bytes_[shard]
-                                for src in range(B)]
+                    lengths, blocks = lengths.reshape(-1), list(blocks)
                 else:
-                    s_lengths, s_blocks = lengths, blocks
-                streams = native_mod.egress_encode(d2, s_lengths, s_blocks)
-                if streams is not None:
-                    out.append((shard, streams, None, None, None))
-                else:  # no native library: per-frame Python fallback
-                    out.append((shard, None, d2, s_lengths,
-                                np.concatenate(s_blocks)))
+                    lengths = np.concatenate(
+                        [direct_lane[src].length[shard]
+                         for shard in range(B) for src in range(B)])
+                    blocks = [direct_lane[src].bytes_[shard]
+                              for shard in range(B) for src in range(B)]
+            if not d2.any():
+                continue
+            streams = native_mod.egress_encode(d2, lengths, blocks)
+            if streams is not None:
+                out.append((streams, None, None, None))
+            else:  # no native library: per-frame Python fallback
+                out.append((None, d2, lengths, np.concatenate(blocks)))
         return out
 
     def _egress_py(self, broker, deliver2, lengths, frames) -> None:
-        """Per-frame fallback egress for one shard (native lib absent)."""
+        """Per-frame fallback egress of one job (native lib absent)."""
         users, frame_idx = np.nonzero(deliver2)
         cache: Dict[int, Bytes] = {}
 

@@ -1,5 +1,7 @@
 """MeshCluster — N broker shards on the device mesh + a marshal, users
-over the Memory transport. The shared harness for mesh-group tests AND
+over the Memory transport (or ``user_protocol``'s: real TCP listeners on
+free local ports, what ``benchmark/launchers/mesh_inprocess.py`` wires).
+The shared harness for mesh-group tests AND
 the device-mesh configs bench (the same test/bench split the reference
 serves with its non-cfg(test) harness, cdn-broker/src/tests/mod.rs:7-9).
 
@@ -16,6 +18,7 @@ import itertools
 import os
 import tempfile
 
+from pushcdn_tpu.bin.common import free_ports
 from pushcdn_tpu.broker.broker import Broker, BrokerConfig
 from pushcdn_tpu.broker.mesh_group import MeshBrokerGroup, MeshGroupConfig
 from pushcdn_tpu.broker.tasks.heartbeat import heartbeat_once
@@ -37,7 +40,8 @@ class MeshCluster:
                  ring_slots: int = 32, frame_bytes: int = 1024,
                  num_user_slots: int = 64, batch_window_s: float = 0.002,
                  devices=None, prefix: str = "mg",
-                 gather_frame_bytes: bool = False):
+                 gather_frame_bytes: bool = False,
+                 direct_bucket_slots: int = 64, user_protocol=Memory):
         self.uid = next(_UID)
         self.num_shards = num_shards
         self.extra_lanes = extra_lanes
@@ -46,24 +50,34 @@ class MeshCluster:
         self.num_user_slots = num_user_slots
         self.batch_window_s = batch_window_s
         self.gather_frame_bytes = gather_frame_bytes
+        self.direct_bucket_slots = direct_bucket_slots
+        self.user_protocol = user_protocol
         self.devices = devices
         self.prefix = f"{prefix}{self.uid}"
+        # where users connect: the brokers' public endpoints, the marshal's
+        if user_protocol is Memory:
+            self._public = [f"{self.prefix}-b{i}-pub"
+                            for i in range(num_shards)]
+            self._marshal_endpoint = f"{self.prefix}-marshal"
+        else:
+            *self._public, self._marshal_endpoint = (
+                f"127.0.0.1:{port}" for port in free_ports(num_shards + 1))
         self.db = os.path.join(tempfile.mkdtemp(prefix="pushcdn-mesh-"),
                                "d.sqlite")
-        self.run_def = testing_run_def()
+        self.run_def = testing_run_def(user_protocol=user_protocol)
         self.keypair = DEFAULT_SCHEME.generate_keypair(seed=40_000 + self.uid)
         self.brokers: list[Broker] = []
         self.group: MeshBrokerGroup = None
         self.marshal: Marshal = None
 
     def _ident(self, i: int) -> BrokerIdentifier:
-        return BrokerIdentifier(f"{self.prefix}-b{i}-pub",
-                                f"{self.prefix}-b{i}-priv")
+        return BrokerIdentifier(self._public[i], f"{self.prefix}-b{i}-priv")
 
     async def start(self, form_host_mesh: bool = False) -> "MeshCluster":
         mesh = make_broker_mesh(self.num_shards, devices=self.devices)
         self.group = MeshBrokerGroup(mesh, MeshGroupConfig(
             num_user_slots=self.num_user_slots, ring_slots=self.ring_slots,
+            direct_bucket_slots=self.direct_bucket_slots,
             frame_bytes=self.frame_bytes, extra_lanes=self.extra_lanes,
             batch_window_s=self.batch_window_s,
             gather_frame_bytes=self.gather_frame_bytes))
@@ -94,7 +108,7 @@ class MeshCluster:
             await asyncio.sleep(0.2)
         self.marshal = await Marshal.new(MarshalConfig(
             run_def=self.run_def, discovery_endpoint=self.db,
-            bind_endpoint=f"{self.prefix}-marshal"))
+            bind_endpoint=self._marshal_endpoint))
         await self.marshal.start()
         return self
 
@@ -105,9 +119,9 @@ class MeshCluster:
             await h.perform_heartbeat(0 if i == shard else 100, 60.0)
             await h.close()
         c = Client(ClientConfig(
-            marshal_endpoint=f"{self.prefix}-marshal",
+            marshal_endpoint=self._marshal_endpoint,
             keypair=DEFAULT_SCHEME.generate_keypair(seed=seed),
-            protocol=Memory, subscribed_topics=set(topics)))
+            protocol=self.user_protocol, subscribed_topics=set(topics)))
         await c.ensure_initialized()
         await wait_until(
             lambda: self.brokers[shard].connections.has_user(c.public_key))

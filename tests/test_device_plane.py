@@ -559,13 +559,15 @@ _SMALL_PLANE = dict(num_user_slots=32, ring_slots=64, frame_bytes=1024,
                     batch_window_s=0.002)
 
 
-def _wire(*payloads: bytes, topic: int = 0) -> bytes:
-    """Broadcasts on ``topic`` as a publisher's kernel holds them: each
-    frame behind the transport's u32 length."""
+def _wire(*payloads: bytes, topic: int = 0, to: bytes = None) -> bytes:
+    """Broadcasts on ``topic``, or directs to the user ``to``, as a
+    publisher's kernel holds them: each frame behind the transport's u32
+    length."""
     import struct
 
-    from pushcdn_tpu.proto.message import Broadcast, serialize
-    frames = [serialize(Broadcast(topics=[topic], message=p))
+    from pushcdn_tpu.proto.message import Broadcast, Direct, serialize
+    frames = [serialize(Broadcast(topics=[topic], message=p) if to is None
+                        else Direct(recipient=to, message=p))
               for p in payloads]
     return b"".join(struct.pack(">I", len(f)) + f for f in frames)
 

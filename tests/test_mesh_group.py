@@ -3,13 +3,24 @@ step (all_gather over the virtual CPU mesh) with NO host broker links —
 the north-star path (BASELINE.json config 4 shape) in miniature."""
 
 import asyncio
+import contextlib
+import os
 
 import numpy as np
+import pytest
 
 from pushcdn_tpu.broker.mesh_group import MeshBrokerGroup, MeshGroupConfig
 from pushcdn_tpu.parallel.mesh import make_broker_mesh
 from pushcdn_tpu.proto.message import Broadcast, Direct
+from pushcdn_tpu.proto.transport import Memory, Tcp
 from pushcdn_tpu.testing.mesh_cluster import MeshCluster
+from tests.test_device_plane import (
+    _broker_fd,
+    _receive_all,
+    _record_batches,
+    _socket_of,
+    _wire,
+)
 from tests.test_integration import wait_until
 
 
@@ -436,3 +447,235 @@ async def test_mesh_tick_is_one_collective():
         b.close()
     finally:
         await cluster.stop()
+
+
+# ---------------------------------------------------------------------------
+# the group's native batch (ISSUE 34): the sends of a tick whose take found a
+# live shard's base ring or a base direct bucket full leave in one
+# ``native.send_batch`` call a lane, for the links that are idle plain sockets;
+# every other tick, and every other link, goes one by one (``DevicePlane``'s
+# twin: tests/test_device_plane.py, "the native batch").
+# ---------------------------------------------------------------------------
+
+_RING, _BUCKET, _WIDE = 16, 8, 2
+
+
+@contextlib.asynccontextmanager
+async def _served_group(per_shard: int = 2, user_protocol=Tcp,
+                        ring_slots: int = _RING):
+    """A four-shard group with small lanes and ``per_shard`` users a shard
+    on topic 0, over real TCP user links (what
+    ``benchmark/launchers/mesh_inprocess.py`` wires) or ``user_protocol``'s:
+    ``(cluster, clients)``, shard ``s``'s users from
+    ``clients[s * per_shard]`` on."""
+    cluster = await MeshCluster(
+        num_shards=4, ring_slots=ring_slots, direct_bucket_slots=_BUCKET,
+        extra_lanes=((4096, _WIDE, _WIDE),),
+        user_protocol=user_protocol).start()
+    clients = []
+    try:
+        for shard in range(4):
+            for i in range(per_shard):
+                clients.append(await cluster.place_client(
+                    seed=3400 + 10 * shard + i, shard=shard, topics=[0]))
+        yield cluster, clients
+    finally:
+        for c in clients:
+            c.close()
+        await cluster.stop()
+
+
+@pytest.mark.parametrize("lane, frames, pressured", [
+    ("ring", _RING, True), ("ring", _RING - 1, False),
+    ("bucket", _BUCKET, True), ("bucket", _BUCKET - 1, False),
+    ("wide_ring", _WIDE, False)],
+    ids=["ring_full", "ring_one_short", "bucket_full", "bucket_one_short",
+         "wide_ring_full"])
+async def test_only_a_back_pressured_tick_is_sent_by_the_native_batch(
+        lane, frames, pressured, monkeypatch):
+    """One shard's base ring full at the take batches every idle link of
+    every shard in one call (the tick is lockstep: the observation is the
+    group's, and a lane's egress is one job over all shards); so
+    does one full base direct bucket alone; one frame short of either, or
+    a full wide lane, batches nothing. ``egress_batched`` is what the
+    recorded calls sent, and what ``describe()`` says."""
+    payloads = [(b"frame %d" % i).ljust(2000 if lane == "wide_ring" else 8)
+                for i in range(frames)]
+    calls = _record_batches(monkeypatch)
+    async with _served_group() as (cluster, clients):
+        group = cluster.group
+        publisher, recipient = clients[0], clients[2]   # shards 0 and 1
+        # one write, one read, one receive batch: the take finds what it
+        # staged
+        if lane == "bucket":
+            readers = [2]
+            wire = _wire(*payloads, to=recipient.public_key)
+        else:
+            readers = list(range(8))
+            wire = _wire(*payloads)
+        os.write(_socket_of(publisher), wire)
+        got = await _receive_all([clients[u] for u in readers], frames)
+        assert got == [payloads] * len(readers)
+        assert (group.steps, group.egress_inline, group.egress_queued) == \
+            (1, len(readers), 0)
+        if pressured:
+            # one call for the lane's job, whichever shard holds the user
+            (fds, nbytes, sent), = calls
+            assert sorted(fds) == sorted(
+                _broker_fd(cluster.brokers[u // 2], clients[u])
+                for u in readers)
+            assert sent == nbytes
+            assert group.egress_batched == len(readers)
+        else:
+            assert group.egress_batched == 0 and not calls
+        assert [b.device_plane.describe()["egress_batched"]
+                for b in cluster.brokers] == [group.egress_batched] * 4
+
+
+@pytest.mark.parametrize("first_send", ["whole", "short"])
+async def test_a_users_two_streams_of_one_tick_keep_their_order(
+        first_send, monkeypatch):
+    """A tick hands a user its broadcasts and its directs as two streams
+    (two jobs, two calls): the link is checked again for the second after
+    the first has settled. A whole first send leaves the link idle and the
+    second is batched too; so does a short one (a reader that stopped
+    reading) whose remainder the transport could write at once; where the
+    transport holds some of it the second queues behind, and the link is
+    not batched again while it does. Either way the user gets every
+    frame, each stream in its publisher's order."""
+    import socket
+
+    lane = 48   # 48 KB a user a tick: over what a stalled link's buffers take
+    rounds = 1 if first_send == "whole" else 4
+    broadcasts = [[(b"b%d.%d|" % (r, i)).ljust(1000, b".")
+                   for i in range(lane)] for r in range(rounds)]
+    directs = [[b"d%d.%d" % (r, i) for i in range(4)] for r in range(rounds)]
+    calls = _record_batches(monkeypatch)
+    async with _served_group(per_shard=1, ring_slots=lane) as (cluster,
+                                                               clients):
+        group = cluster.group
+        publisher, user = clients[0], clients[1]    # shards 0 and 1
+        others = [c for c in clients if c is not user]
+        link = cluster.brokers[1].connections.get_user_connection(
+            user.public_key)
+        fd = _broker_fd(cluster.brokers[1], user)
+        transport = link._stream.writer.transport
+        stream = user._connection._stream
+        if first_send == "short":
+            stream.writer.get_extra_info("socket").setsockopt(
+                socket.SOL_SOCKET, socket.SO_RCVBUF, 4096)
+            link._stream.writer.get_extra_info("socket").setsockopt(
+                socket.SOL_SOCKET, socket.SO_SNDBUF, 4096)
+            stream.reader._transport.pause_reading()
+        short = unbatched = 0
+        for r in range(rounds):
+            held, seen = transport.get_write_buffer_size(), len(calls)
+            queued = group.egress_queued
+            # the publisher's order on the wire, a direct after every
+            # twelfth broadcast; one write, one receive batch, a full ring
+            os.write(_socket_of(publisher), b"".join(
+                _wire(b) + (_wire(directs[r][i // 12], to=user.public_key)
+                            if i % 12 == 11 else b"")
+                for i, b in enumerate(broadcasts[r])))
+            assert await _receive_all(others, lane) == [broadcasts[r]] * 3
+            await wait_until(lambda: group.egress_inline
+                             + group.egress_queued == 5 * (r + 1))
+            assert group.steps == r + 1
+            mine = [(nbytes[at], sent[at])
+                    for fds, nbytes, sent in calls[seen:] if fd in fds
+                    for at in [fds.index(fd)]]
+            if held:    # bytes on the fd would pass the transport's
+                assert not mine
+                unbatched += 1
+                continue
+            (b_bytes, b_sent), *second = mine
+            assert b_sent <= b_bytes > 40_000
+            short += b_sent < b_bytes
+            if second:  # the transport took what was left of the first
+                (d_bytes, d_sent), = second
+                assert d_sent <= d_bytes < 1_000
+            else:       # it holds some of it: the directs queue behind
+                assert b_sent < b_bytes and link.idle_fd(1) is None
+                assert group.egress_queued == queued + 1
+        if first_send == "whole":
+            assert not short
+            assert (group.egress_batched, group.egress_queued) == (5, 0)
+        else:
+            assert short and unbatched
+            stream.reader._transport.resume_reading()
+        assert group.egress_batched == sum(len(c[0]) for c in calls)
+        got, = await _receive_all([user], rounds * (lane + 4))
+        assert [m for m in got if m.startswith(b"b")] == \
+            [b for frames in broadcasts for b in frames]
+        assert [m for m in got if m.startswith(b"d")] == \
+            [d for frames in directs for d in frames]
+        assert sum(b.connections.num_users for b in cluster.brokers) == 4
+        assert not group.disabled
+
+
+async def test_a_send_that_fails_in_the_groups_batch_removes_that_user_only(
+        monkeypatch):
+    """A job spans the shards, a user's link lives on one member: the
+    failed send's user is removed there, and there only."""
+    import socket
+
+    gone = []   # the victim's peer is gone by the time of the send()
+
+    def shut_down(fds):
+        if gone:
+            sock = socket.socket(fileno=os.dup(gone.pop()))
+            sock.shutdown(socket.SHUT_RDWR)
+            sock.close()
+    calls = _record_batches(monkeypatch, shut_down)
+    first = [b"first %d" % i for i in range(_RING)]
+    second = [b"second %d" % i for i in range(_RING)]
+    async with _served_group() as (cluster, clients):
+        group = cluster.group
+        victim = clients[5]     # shard 2
+        others = [c for c in clients if c is not victim]
+        gone.append(_broker_fd(cluster.brokers[2], victim))
+        os.write(_socket_of(clients[0]), _wire(*first))
+        assert await _receive_all(others, _RING) == [first] * 7
+        assert not gone
+        assert all(b.connections.get_user_connection(victim.public_key)
+                   is None for b in cluster.brokers)
+        assert [b.connections.num_users for b in cluster.brokers] == \
+            [2, 2, 1, 2]
+        assert (group.egress_batched, group.egress_inline,
+                group.messages_routed) == (7, 7, 7 * _RING)
+        # the next tick: the seven that are left, batched again
+        os.write(_socket_of(clients[0]), _wire(*second))
+        assert await _receive_all(others, _RING) == [second] * 7
+        assert [len(fds) for fds, _, _ in calls] == [8, 7]
+        assert group.egress_batched == 14 and not group.disabled
+
+
+async def test_memory_users_of_a_back_pressured_tick_are_never_batched(
+        monkeypatch):
+    """The Memory transport has no socket (``idle_fd`` is ``None``): a
+    tick that is back-pressured like any other goes one by one, every
+    stream through its writer, and is delivered."""
+    payloads = [b"frame %d" % i for i in range(_RING)]
+    calls = _record_batches(monkeypatch)
+    async with _served_group(user_protocol=Memory) as (cluster, clients):
+        group = cluster.group
+        conns = [cluster.brokers[u // 2].connections.get_user_connection(
+            c.public_key) for u, c in enumerate(clients)]
+        assert [c.idle_fd(1) for c in conns] == [None] * 8
+        observed = []
+        real = group._back_pressured
+
+        def back_pressured():
+            observed.append(real())
+            return observed[-1]
+        group._back_pressured = back_pressured
+        # no socket to write into: one pipelined burst fills the ring
+        await asyncio.gather(*(
+            clients[0].send_broadcast_message([0], p) for p in payloads))
+        got = await _receive_all(clients, _RING)
+        assert got == [payloads] * 8
+        assert observed == [True] and group.steps == 1
+        assert group.egress_batched == 0 and not calls
+        assert (group.egress_inline, group.egress_queued) == (0, 8)
+        assert cluster.brokers[3].device_plane.describe()[
+            "egress_batched"] == 0

@@ -106,9 +106,9 @@ async def test_traced_steps_yield_flat_joined_conserving_spans(
         await _burst(client, 16, b"untraced")
         staged0, routed0, steps0 = (plane.frames_staged,
                                     plane.messages_routed, plane.steps)
-        handoffs0 = (plane.egress_inline, plane.egress_queued)
+        handoffs0 = (plane.egress_inline, plane.egress_queued,
+                     plane.egress_batched)
         drained0 = getattr(plane, "frames_drained", 0)  # no group has it
-        batched0 = getattr(plane, "egress_batched", 0)  # nor this
         options = jax.profiler.ProfileOptions()
         options.python_tracer_level = 0
         options.host_tracer_level = 2
@@ -123,8 +123,8 @@ async def test_traced_steps_yield_flat_joined_conserving_spans(
         steps = plane.steps - steps0
         inline, queued = (plane.egress_inline - handoffs0[0],
                           plane.egress_queued - handoffs0[1])
+        batched = plane.egress_batched - handoffs0[2]
         drained = getattr(plane, "frames_drained", 0) - drained0
-        batched = getattr(plane, "egress_batched", 0) - batched0
     finally:
         client.close()
         await cluster.stop()
@@ -193,9 +193,8 @@ def _batched_conserves(events, batched: int) -> None:
     """``plane.egress``'s ``batched`` (hand-offs one native call sent)
     sums to the plane's ``egress_batched`` and is part of ``inline``."""
     egresses = [e[3] for e in events if e[0] == "plane.egress"]
-    assert all(0 <= g.get("batched", 0) <= g["inline"] for g in egresses), \
-        egresses
-    assert sum(g.get("batched", 0) for g in egresses) == batched
+    assert all(0 <= g["batched"] <= g["inline"] for g in egresses), egresses
+    assert sum(g["batched"] for g in egresses) == batched
 
 
 def _drained_conserves(events, drained: int) -> None:
@@ -258,10 +257,13 @@ async def test_traced_takes_report_what_the_drain_staged(
         (1, 0), (1, 1), (1, 0), (2, 2), (1, 0), (3, 3)]
 
 
-async def test_traced_egress_reports_what_the_native_batch_sent(tmp_path):
+@pytest.mark.parametrize("deploy", ["device_plane", "mesh_group"])
+async def test_traced_egress_reports_what_the_native_batch_sent(
+        deploy, tmp_path):
     """Over real TCP links a step whose take found the base lane full
-    sends its streams in one native batch, and ``plane.egress`` says how
-    many: all of that step's hand-offs, none of a step with room left."""
+    sends its streams in one native batch (the group's tick: one a
+    shard), and ``plane.egress`` says how many: all of that step's
+    hand-offs, none of a step with room left."""
     import jax
 
     from pushcdn_tpu.broker.device_plane import DevicePlaneConfig
@@ -273,14 +275,23 @@ async def test_traced_egress_reports_what_the_native_batch_sent(tmp_path):
         _socket_of,
         _wire,
     )
+    from tests.test_mesh_group import _RING, _served_group
     spans.bind()
-    lane = _SMALL_PLANE["ring_slots"]
+    if deploy == "device_plane":
+        lane, users = _SMALL_PLANE["ring_slots"], 2
+        served = _served_over_tcp(
+            3140, DevicePlaneConfig(bypass_max_items=0, **_SMALL_PLANE),
+            [{0}] * users)
+    else:
+        lane, users = _RING, 4      # one a shard
+        served = _served_group(per_shard=1)
     rounds = [[b"round %d %d" % (r, i) for i in range(n)]
               for r, n in enumerate((lane, 3, lane))]
-    async with _served_over_tcp(
-            3140, DevicePlaneConfig(bypass_max_items=0, **_SMALL_PLANE),
-            [{0}] * 2) as (broker, clients):
-        plane = broker.device_plane
+    async with served as (serving, clients):
+        if deploy == "device_plane":
+            plane = facade = serving.device_plane
+        else:
+            plane, facade = serving.group, serving.brokers[0].device_plane
         options = jax.profiler.ProfileOptions()
         options.python_tracer_level = 0
         options.host_tracer_level = 2
@@ -289,15 +300,16 @@ async def test_traced_egress_reports_what_the_native_batch_sent(tmp_path):
             for frames in rounds:
                 os.write(_socket_of(clients[0]), _wire(*frames))
                 got = await _receive_all(clients, len(frames))
-                assert got == [frames] * 2
+                assert got == [frames] * users
         finally:
             jax.profiler.stop_trace()
-        batched, described = plane.egress_batched, plane.describe()
-    assert batched == described["egress_batched"] == 4
+        batched, described = plane.egress_batched, facade.describe()
+    assert batched == described["egress_batched"] == 2 * users
     threads, _ = _program_spans(str(tmp_path))
     events = [e for evs in threads.values() for e in evs]
     _batched_conserves(events, batched)
     egresses = sorted((e for e in events if e[0] == "plane.egress"),
                       key=lambda e: e[1])
     assert [(g[3]["inline"], g[3]["queued"], g[3]["batched"])
-            for g in egresses] == [(2, 0, 2), (2, 0, 0), (2, 0, 2)]
+            for g in egresses] == [
+                (users, 0, users), (users, 0, 0), (users, 0, users)]
