@@ -253,6 +253,12 @@ class DevicePlane:
         # of those, staged while the pump drained the sockets into the
         # rings between an egress it wrote itself and its next take
         self.frames_drained = 0
+        # the broker↔broker leg, counted by the receive loops: of
+        # ``frames_staged``, those a peer's link brought
+        # (``broker_receive_loop``), and the (frame, peer) sends the user
+        # loops appended for the peers (``links.forward``'s ``forwards``)
+        self.link_frames_staged = 0
+        self.link_frames_forwarded = 0
         # monotonic time at which the rings last went from empty to
         # non-empty (None while empty): the age of the oldest staged
         # frame at the next take is ``plane.take``'s ``ring_wait_us``
@@ -559,6 +565,8 @@ class DevicePlane:
             "steps": self.steps,
             "frames_staged": self.frames_staged,
             "frames_drained": self.frames_drained,
+            "link_frames_staged": self.link_frames_staged,
+            "link_frames_forwarded": self.link_frames_forwarded,
             "messages_routed": self.messages_routed,
             "egress_inline": self.egress_inline,
             "egress_queued": self.egress_queued,
@@ -568,6 +576,9 @@ class DevicePlane:
             "user_slots": self.user_slots,
             "user_high_water": self.slots.high_water,
             "table_grows": self.table_grows,
+            # read when asked: whoever starts several chip-owning brokers
+            # cannot look into their devices from outside
+            "device_memory_peak_bytes": runtime.memory_peak_bytes(),
         }
 
     def _pack_walks(self, batches):

@@ -139,6 +139,9 @@ class MeshShardPlane:
     def __init__(self, group: "MeshBrokerGroup", shard: int):
         self.group = group
         self.shard = shard
+        # what handlers.py counts on any plane whose broker has a peer
+        # link (a group's only when it fails open to host links)
+        self.link_frames_forwarded = 0
 
     # Connections observer protocol --------------------------------------
     def on_user_added(self, public_key: bytes, topics) -> None:

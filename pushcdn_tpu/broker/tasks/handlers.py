@@ -92,7 +92,7 @@ class EgressBatch:
     dropped one (unknown recipient, no interest)."""
 
     __slots__ = ("broker", "users", "brokers", "shards", "appended",
-                 "_traces")
+                 "forwarded", "_traces")
 
     def __init__(self, broker: "Broker"):
         self.broker = broker
@@ -102,6 +102,9 @@ class EgressBatch:
         # flushed as ONE handoff-ring record per shard (ISSUE 6)
         self.shards: dict = {}
         self.appended = 0
+        # of ``appended``, the (frame, peer broker) pairs over this
+        # process's own links: ``links.forward``'s ``forwards``
+        self.forwarded = 0
         self._traces: Optional[list] = None
 
     def note_trace(self, tr) -> None:
@@ -125,6 +128,7 @@ class EgressBatch:
             lst = self.brokers[identifier] = []
         lst.append(raw.clone())
         self.appended += 1
+        self.forwarded += 1
         # per-link conservation table (ISSUE 20): counted at the routing
         # decision, where the per-frame class is exact on both ends
         ledger_mod.note_link_sent(identifier, cls)
@@ -537,6 +541,38 @@ async def _stage_with_backpressure(device, message, raw: Bytes):
         await asyncio.sleep(0.002)
 
 
+def _route_after_stage(broker: "Broker", device, stage_items: list,
+                       results: list, egress: EgressBatch,
+                       interest_cache: dict) -> None:
+    """The user loop's pass over a staged batch, in the order the frames
+    came; synchronous. A staged broadcast still owes the peer brokers
+    their copy (the device covers the local users, and a mesh group its
+    members); whatever the device did not take is host-routed whole."""
+    for (message, raw, pruned), res in zip(stage_items, results):
+        staged = res == StageResult.STAGED
+        if staged:
+            _emit_staged_trace(message)
+        if isinstance(message, Direct):
+            if not staged:
+                a0 = egress.appended
+                route_direct(broker, message.recipient, raw,
+                             to_user_only=False, egress=egress)
+                _emit_scalar_trace(message, egress, a0)
+        else:
+            # host side: remaining fan-out — all of it when not staged;
+            # only out-of-group/interest forwarding when the device
+            # covers users (+ group peers over ICI)
+            a0 = egress.appended
+            route_broadcast(
+                broker, pruned, raw, to_users_only=False, egress=egress,
+                users_via_device=staged,
+                exclude_brokers=(frozenset(device.covered_broker_idents())
+                                 if staged else frozenset()),
+                interest_cache=interest_cache, raw_topics=message.topics)
+            if not staged:
+                _emit_scalar_trace(message, egress, a0)
+
+
 # ---------------------------------------------------------------------------
 # user receive loop
 # ---------------------------------------------------------------------------
@@ -692,38 +728,31 @@ async def user_receive_loop(broker: "Broker", public_key: bytes,
                             [(m, r) for m, r, _ in stage_items])
                         sp.set_metadata(
                             staged=results.count(StageResult.STAGED))
-                    for (message, raw, pruned), res in zip(stage_items,
-                                                           results):
-                        if res == StageResult.FULL:
-                            res = await _stage_with_backpressure(
-                                device, message, raw)
-                        staged = res == StageResult.STAGED
-                        if staged:
-                            _emit_staged_trace(message)
-                        if isinstance(message, Direct):
-                            if not staged:
-                                a0 = egress.appended
-                                route_direct(broker, message.recipient, raw,
-                                             to_user_only=False,
-                                             egress=egress)
-                                _emit_scalar_trace(message, egress, a0)
-                        else:
-                            # host side: remaining fan-out — all of it when
-                            # not staged; only out-of-group/interest
-                            # forwarding when the device covers users
-                            # (+ group peers over ICI)
-                            a0 = egress.appended
-                            route_broadcast(
-                                broker, pruned, raw, to_users_only=False,
-                                egress=egress, users_via_device=staged,
-                                exclude_brokers=(
-                                    frozenset(
-                                        device.covered_broker_idents())
-                                    if staged else frozenset()),
-                                interest_cache=interest_cache,
-                                raw_topics=message.topics)
-                            if not staged:
-                                _emit_scalar_trace(message, egress, a0)
+                    if StageResult.FULL in results:
+                        # a full ring blocks THIS reader until the pump
+                        # has made room, frame by frame in the order they
+                        # came; nothing below awaits, so the pass that
+                        # routes the batch is one flat span
+                        for i, (message, raw, _) in enumerate(stage_items):
+                            if results[i] == StageResult.FULL:
+                                results[i] = await _stage_with_backpressure(
+                                    device, message, raw)
+                    # the broker↔broker leg of the batch, and the host
+                    # route of what the device did not take. Spanned only
+                    # where a peer link exists: a lone broker pays nothing
+                    if broker.connections.num_brokers:
+                        with span("links.forward",
+                                  frames=len(stage_items)) as sp:
+                            forwards = egress.forwarded
+                            _route_after_stage(broker, device, stage_items,
+                                               results, egress,
+                                               interest_cache)
+                            forwards = egress.forwarded - forwards
+                            device.link_frames_forwarded += forwards
+                            sp.set_metadata(forwards=forwards)
+                    else:
+                        _route_after_stage(broker, device, stage_items,
+                                           results, egress, interest_cache)
             finally:
                 try:
                     await egress.flush()
@@ -781,96 +810,104 @@ async def broker_receive_loop(broker: "Broker", identifier: str,
             single_shard = (device is not None
                             and not device.covers_brokers)
             try:
-                for raw in raws:
-                    try:
-                        message = deserialize(raw.data)
-                    except Error:
-                        logger.warning(
-                            "broker %s sent malformed frame; dropping link",
-                            identifier)
-                        connection.flightrec.record("malformed-frame",
-                                                    abnormal=True)
-                        ledger_mod.record_fate("dropped", "malformed",
-                                               flowclass.CLASS_NONE)
-                        alive = False
-                        break
-                    ledger_mod.note_ingress(_ingress_class(message),
-                                            peer=identifier)
-                    result = hook(identifier, message)
-                    if result == HookResult.SKIP:
-                        continue
-                    if result == HookResult.DISCONNECT:
-                        alive = False
-                        break
-
-                    if isinstance(message, Direct):
-                        # deliver to our own user only — never re-forward
-                        # (broker/handler.rs:148-153); the single-shard
-                        # device path's delivery-iff-owner rule keeps that
-                        # invariant
-                        if single_shard:
-                            stage_items.append((message, raw, None))
+                with span("links.scan", frames=len(raws)):
+                    for raw in raws:
+                        try:
+                            message = deserialize(raw.data)
+                        except Error:
+                            logger.warning(
+                                "broker %s sent malformed frame; dropping link",
+                                identifier)
+                            connection.flightrec.record("malformed-frame",
+                                                        abnormal=True)
+                            ledger_mod.record_fate("dropped", "malformed",
+                                                   flowclass.CLASS_NONE)
+                            alive = False
+                            break
+                        ledger_mod.note_ingress(_ingress_class(message),
+                                                peer=identifier)
+                        result = hook(identifier, message)
+                        if result == HookResult.SKIP:
                             continue
-                        a0 = egress.appended
-                        route_direct(broker, message.recipient, raw,
-                                     to_user_only=True, egress=egress)
-                        _emit_scalar_trace(message, egress, a0)
-                    elif isinstance(message, Broadcast):
-                        # users only — prevents broadcast loops
-                        # (broker/handler.rs:156-161)
-                        pruned, _bad = topics.prune(message.topics)
-                        if pruned:
-                            # mesh-forwarded durable broadcasts are retained
-                            # here too, so a user rejoining at THIS broker
-                            # replays mesh-wide history (seqs broker-local)
-                            durable = broker.durable
-                            if durable is not None and not durable.on_publish(
-                                    pruned, message, raw,
-                                    to_users_only=True):
-                                continue
+                        if result == HookResult.DISCONNECT:
+                            alive = False
+                            break
+
+                        if isinstance(message, Direct):
+                            # deliver to our own user only — never re-forward
+                            # (broker/handler.rs:148-153); the single-shard
+                            # device path's delivery-iff-owner rule keeps that
+                            # invariant
                             if single_shard:
-                                stage_items.append((message, raw, pruned))
+                                stage_items.append((message, raw, None))
                                 continue
                             a0 = egress.appended
-                            route_broadcast(broker, pruned, raw,
-                                            to_users_only=True,
-                                            egress=egress,
-                                            interest_cache=interest_cache,
-                                            raw_topics=message.topics)
+                            route_direct(broker, message.recipient, raw,
+                                         to_user_only=True, egress=egress)
                             _emit_scalar_trace(message, egress, a0)
-                    elif isinstance(message, UserSync):
-                        broker.connections.apply_user_sync(message.payload)
-                        broker.update_metrics()
-                    elif isinstance(message, TopicSync):
-                        broker.connections.apply_topic_sync(identifier,
-                                                            message.payload)
-                    elif isinstance(message, LedgerSync):
-                        # peer's conservation balance sheet (ISSUE 20) —
-                        # unparseable sheets are ignored, not link-fatal
-                        # (monotone snapshots, last writer wins)
-                        import json
-                        try:
-                            sheet = json.loads(bytes(message.payload))
-                        except (ValueError, UnicodeDecodeError):
-                            sheet = None
-                        if sheet is not None:
-                            ledger_mod.LEDGER.note_peer_sheet(identifier,
-                                                              sheet)
-                    else:
-                        logger.warning(
-                            "broker %s sent unexpected %s; dropping link",
-                            identifier, type(message).__name__)
-                        alive = False
-                        break
+                        elif isinstance(message, Broadcast):
+                            # users only — prevents broadcast loops
+                            # (broker/handler.rs:156-161)
+                            pruned, _bad = topics.prune(message.topics)
+                            if pruned:
+                                # mesh-forwarded durable broadcasts are retained
+                                # here too, so a user rejoining at THIS broker
+                                # replays mesh-wide history (seqs broker-local)
+                                durable = broker.durable
+                                if durable is not None and not durable.on_publish(
+                                        pruned, message, raw,
+                                        to_users_only=True):
+                                    continue
+                                if single_shard:
+                                    stage_items.append((message, raw, pruned))
+                                    continue
+                                a0 = egress.appended
+                                route_broadcast(broker, pruned, raw,
+                                                to_users_only=True,
+                                                egress=egress,
+                                                interest_cache=interest_cache,
+                                                raw_topics=message.topics)
+                                _emit_scalar_trace(message, egress, a0)
+                        elif isinstance(message, UserSync):
+                            broker.connections.apply_user_sync(message.payload)
+                            broker.update_metrics()
+                        elif isinstance(message, TopicSync):
+                            broker.connections.apply_topic_sync(identifier,
+                                                                message.payload)
+                        elif isinstance(message, LedgerSync):
+                            # peer's conservation balance sheet (ISSUE 20) —
+                            # unparseable sheets are ignored, not link-fatal
+                            # (monotone snapshots, last writer wins)
+                            import json
+                            try:
+                                sheet = json.loads(bytes(message.payload))
+                            except (ValueError, UnicodeDecodeError):
+                                sheet = None
+                            if sheet is not None:
+                                ledger_mod.LEDGER.note_peer_sheet(identifier,
+                                                                  sheet)
+                        else:
+                            logger.warning(
+                                "broker %s sent unexpected %s; dropping link",
+                                identifier, type(message).__name__)
+                            alive = False
+                            break
 
                 if stage_items:
-                    results = device.stage_batch(
-                        [(m, r) for m, r, _ in stage_items])
+                    with span("links.stage",
+                              frames=len(stage_items)) as sp:
+                        results = device.stage_batch(
+                            [(m, r) for m, r, _ in stage_items])
+                        staged = results.count(StageResult.STAGED)
+                        device.link_frames_staged += staged
+                        sp.set_metadata(staged=staged)
                     for (message, raw, pruned), res in zip(stage_items,
                                                            results):
                         if res == StageResult.FULL:
                             res = await _stage_with_backpressure(
                                 device, message, raw)
+                            if res == StageResult.STAGED:
+                                device.link_frames_staged += 1
                         if res == StageResult.STAGED:
                             _emit_staged_trace(message)
                             continue

@@ -102,6 +102,14 @@ def device() -> Device:
     return Device(devices[0].platform, devices[0].device_kind, len(devices))
 
 
+def memory_peak_bytes() -> int:
+    """Peak bytes in use on this process's fullest device (0 where the
+    backend does not say, as the CPU)."""
+    import jax
+    return max((int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+                for d in jax.local_devices()), default=0)
+
+
 def init(who: str) -> Runtime:
     """Place the compile cache, start compile accounting, bind the host
     spans to the profiler, initialise the backend and refuse a CPU nobody

@@ -28,6 +28,16 @@ The spans (the names are what ``benchmark/span_reduce.py`` reads):
                                   batch; ``frames``
 ``ingress.stage``     event loop  its one ``stage_batch`` call; ``frames``,
                                   ``staged``
+``links.scan``        event loop  ``broker_receive_loop``'s scan of one receive
+                                  batch from a peer broker; ``frames``
+``links.stage``       event loop  its one ``stage_batch`` call; ``frames``,
+                                  ``staged``
+``links.forward``     event loop  ``user_receive_loop``'s pass over a staged
+                                  batch (the peers' copies of its broadcasts,
+                                  the host route of what the device left),
+                                  only on a broker with a peer link;
+                                  ``frames``, ``forwards`` (the (frame, peer)
+                                  sends it appended)
 ``plane.take``        event loop  the pump's snapshot of rings and mirrors;
                                   ``step``, ``frames``, ``ring_wait_us``,
                                   ``users`` (the step's user dimension;

@@ -15,6 +15,9 @@ MB = 1 << 20
 
 @pytest.fixture
 def pool():
+    # leases an earlier test of this worker left in garbage come back to
+    # the pool now, not at a ``gc.collect()`` inside the test
+    gc.collect()
     saved, need = list(native._EGRESS_POOL), native._EGRESS_NEED_HW
     del native._EGRESS_POOL[:]
     native._EGRESS_NEED_HW = 64 * MB  # nothing below is "far above need"
