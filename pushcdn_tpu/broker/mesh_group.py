@@ -67,8 +67,7 @@ from pushcdn_tpu.parallel.frames import (
 )
 from pushcdn_tpu.parallel.router import (
     BROKER_AXIS,
-    DirectIngress,
-    IngressBatch,
+    LaneWords,
     RouterState,
     make_mesh_lane_step,
 )
@@ -107,11 +106,13 @@ class MeshGroupConfig:
     # (cached) jit specialization whose collectives move ~1/16th the bytes,
     # cutting sparse-traffic step latency several-fold.
     latency_slots: int = 8
-    # Single-host groups skip the frame-byte collectives entirely: all
+    # Single-host groups keep frame bytes off the device altogether: all
     # shards' staged frames live in this process, so only the delivery
-    # DECISION rides the mesh; egress reads payloads from the host ring
-    # snapshots (router.routing_step_lanes gather_bytes docs). Multi-host
-    # deployments set this True.
+    # DECISION rides the mesh and with this off no frame byte reaches the
+    # device (a lane's bytes leaf is a zero-width stub; a tick uploads its
+    # lanes' metadata, one buffer); egress reads payloads from the host
+    # ring snapshots (router.routing_step_lanes gather_bytes docs).
+    # Multi-host deployments set this True.
     gather_frame_bytes: bool = False
     # One sharding-aware collective per tick: every gathered leaf (CRDT
     # state, lane metadata, direct buckets — frame bytes too when
@@ -190,6 +191,7 @@ class MeshShardPlane:
             "egress_inline": g.egress_inline,
             "egress_queued": g.egress_queued,
             "egress_batched": g.egress_batched,
+            "h2d_puts": g.h2d_puts, "h2d_bytes": g.h2d_bytes,
         }
 
     @property
@@ -297,10 +299,20 @@ class MeshBrokerGroup:
         self._state_rev = 0
         self._state_cache = RevCache()  # (RouterState, liveness) on device
         self._tmask_cache = TopicMaskCache(c.topic_words)
-        # cached device-side EMPTY lane batches: an idle lane re-uses its
-        # device arrays, paying zero stack/H2D per step (keying the jit
-        # cache on lane SUBSETS instead would recompile per traffic mix)
-        self._idle_dev_lanes: Dict = {}
+        # a tick's lanes, busy or idle, cross in one buffer: per lane
+        # geometry (full shapes, latency-sliced shapes) the layout and the
+        # host buffer the pump packs (keying the jit cache on lane SUBSETS
+        # instead would recompile per traffic mix)
+        self._lane_words: Dict = {}
+        # device-side constants of the lanes' bytes leaves, by shape: the
+        # zero-width stub where nothing gathers bytes, else an idle
+        # shard's zero block a device
+        self._byte_consts: Dict = {}
+        # ``jax.device_put`` calls ``_run_step`` made and the host bytes
+        # it handed them (``plane.h2d``'s ``puts`` / ``bytes`` sum to
+        # these; warm-up included)
+        self.h2d_puts = 0
+        self.h2d_bytes = 0
         self.disabled = False
         # set when traffic falls outside what the mesh step can carry —
         # heartbeats then form host links even in mesh-only deployments
@@ -367,7 +379,7 @@ class MeshBrokerGroup:
         u0 = effective_users(0, self.config.num_user_slots)
         # compile the ONLY two specializations the pump needs at first
         # population (u_eff = first user bucket): all lanes at full
-        # shapes (idle lanes ride cached device-side empties, so
+        # shapes (idle lanes ride the tick's one buffer as zeros, so
         # traffic mix never changes the jit key), and the latency-
         # sliced base lanes (sparse traffic); wider user buckets
         # compile on first growth past the mark. A failure here
@@ -743,25 +755,34 @@ class MeshBrokerGroup:
     def _run_step(self, batches, directs, owner, versions, masks,
                   liveness=None, state_rev=None, step: Optional[int] = None):
         """Blocking multi-shard device step (worker thread). ``batches`` and
-        ``directs`` are [lane][shard] host snapshots; busy lanes ride ONE
-        jitted shard_map program with one shared CRDT merge. Lanes idle on
-        EVERY shard ride cached device-side empty batches (zero stack/H2D
-        per step) so the jit key never depends on the traffic mix.
+        ``directs`` are [lane][shard] host snapshots; all lanes ride ONE
+        jitted shard_map program with one shared CRDT merge.
 
-        The device user table is re-uploaded only when ``state_rev`` moved
-        (steady state pays zero H2D for state), and egress payloads come
-        from the HOST snapshots when ``gather_frame_bytes`` is off — the
-        step returns one egress job a busy lane, each either a native
-        :class:`native.EgressStreams` (encoded right here, off the event
-        loop) or the Python-fallback (deliver, lengths, frames) triple.
+        A tick uploads what its step reads, once: every lane's metadata
+        (busy or idle: an idle lane is a few KiB of zeros, so the jit key
+        never depends on the traffic mix) is packed into one ``[B, W]``
+        u32 buffer (:class:`router.LaneWords`) and crosses in one
+        ``device_put``. No frame byte crosses when ``gather_frame_bytes``
+        is off: a lane's bytes leaf is a cached zero-width stub, and
+        egress payloads come from the HOST snapshots. The device user
+        table is re-uploaded only when ``state_rev`` moved (steady state
+        pays zero H2D for state). The step returns one egress job a busy
+        lane, each either a native :class:`native.EgressStreams` (encoded
+        right here, off the event loop) or the Python-fallback (deliver,
+        lengths, frames) triple.
 
         ``step`` is the tick number the profiler spans carry; without one
         (the compile-only warm-up) the step emits no spans."""
         import jax
         span = spans.none if step is None else spans.span
         B = self.num_shards
-        put = lambda a: jax.device_put(a, self._sharding)
         live = (np.ones(B, bool) if liveness is None else liveness)
+        puts, nbytes = self.h2d_puts, self.h2d_bytes
+
+        def put(a, where=self._sharding):
+            self.h2d_puts += 1
+            self.h2d_bytes += a.nbytes
+            return jax.device_put(a, where)
 
         def build_state():
             # every shard's state row is the (shared) global view; on real
@@ -777,74 +798,62 @@ class MeshBrokerGroup:
                 topic_masks=put(masks_b)),
                 put(np.broadcast_to(live, (B, B))))
 
-        def put_rows(key, rows, busy_rows):
-            """Assemble the [B, ...] byte tensor per device: busy shards
-            H2D their own block; idle shards reuse a cached device-side
-            zero block (their ``valid`` masks are False, so stale content
-            can never deliver). Stack+upload cost is ∝ TRAFFIC, not lane
-            geometry — with one busy shard this moves 1/B of the bytes a
-            full-stack would."""
-            devices = self.mesh.devices.reshape(-1)
-            shards = []
-            zero_key = ("z", key, rows[0].shape)
-            zeros = self._idle_dev_lanes.get(zero_key)
-            if zeros is None:
-                zeros = [
-                    jax.device_put(np.zeros((1,) + rows[0].shape, np.uint8),
-                                   d) for d in devices]
-                self._idle_dev_lanes[zero_key] = zeros
-            for i, row in enumerate(rows):
-                if busy_rows[i]:
-                    shards.append(jax.device_put(row[None], devices[i]))
-                else:
-                    shards.append(zeros[i])
-            return jax.make_array_from_single_device_arrays(
-                (len(rows),) + rows[0].shape, self._sharding, shards)
+        def const(shape, build):
+            got = self._byte_consts.get(shape)
+            if got is None:
+                got = self._byte_consts[shape] = build()
+            return got
 
-        def lane_to_dev(key, lane, busy):
-            """H2D one lane; an idle lane reuses its cached device-side
-            empty batch (zero stack/copy), keyed by (kind, index, shape)."""
-            if not busy:
-                cached = self._idle_dev_lanes.get(key)
-                if cached is not None:
-                    return cached
-            if key[0] == "b":
-                dev = IngressBatch(
-                    put_rows(key, [b.bytes_ for b in lane],
-                             [bool(b.valid.any()) for b in lane]),
-                    put(np.stack([b.kind for b in lane])),
-                    put(np.stack([b.length for b in lane])),
-                    put(np.stack([b.topic_mask for b in lane])),
-                    put(np.stack([b.dest for b in lane])),
-                    put(np.stack([b.valid for b in lane])))
-            else:
-                dev = DirectIngress(
-                    put_rows(key, [d.bytes_ for d in lane],
-                             [bool(d.valid.any()) for d in lane]),
-                    put(np.stack([d.length for d in lane])),
-                    put(np.stack([d.dest for d in lane])),
-                    put(np.stack([d.valid for d in lane])))
-            if not busy:
-                self._idle_dev_lanes[key] = dev
-            return dev
+        def put_rows(lane):
+            """``gather_frame_bytes`` only: assemble the [B, ...] byte
+            tensor per device. Busy shards H2D their own block; idle
+            shards reuse a cached device-side zero block (their ``valid``
+            masks are False, so stale content can never deliver): with
+            one busy shard this moves 1/B of the bytes a full stack
+            would."""
+            devices = self.mesh.devices.reshape(-1)
+            block = (1,) + lane[0].bytes_.shape
+            zeros = const(block, lambda: [put(np.zeros(block, np.uint8), d)
+                                          for d in devices])
+            return jax.make_array_from_single_device_arrays(
+                (B,) + block[1:], self._sharding,
+                [put(snap.bytes_[None], devices[i]) if snap.valid.any()
+                 else zeros[i] for i, snap in enumerate(lane)])
+
+        def bytes_leaf(lane):
+            """A lane's ``frame_bytes`` leaf: the bytes where the step
+            gathers them, else a zero-width stub of the lane's geometry
+            (nothing reads it; what ``DevicePlane`` does on one chip)."""
+            if self.config.gather_frame_bytes:
+                return put_rows(lane)
+            stub = (B,) + lane[0].bytes_.shape[:-1] + (0,)
+            return const(stub, lambda: put(np.zeros(stub, np.uint8)))
 
         busy_b = [any(b.valid.any() for b in lane) for lane in batches]
         busy_d = [any(d.valid.any() for d in lane) for lane in directs]
-        with span("plane.h2d", step=step):
+        geometry = (tuple(lane[0].valid.shape[0] for lane in batches),
+                    tuple(lane[0].valid.shape for lane in directs))
+        with span("plane.h2d", step=step) as sp:
             state, live_dev = self._state_cache.get(state_rev, build_state)
-            lane_batches = tuple(
-                lane_to_dev(("b", li, lane[0].valid.shape[0]), lane,
-                            busy_b[li])
-                for li, lane in enumerate(batches))
-            lane_directs = tuple(
-                lane_to_dev(("d", li, lane[0].valid.shape[1]), lane,
-                            busy_d[li])
-                for li, lane in enumerate(directs))
+            packed = self._lane_words.get(geometry)
+            if packed is None:
+                layout = LaneWords(*geometry, self._masks.shape[1:])
+                packed = self._lane_words[geometry] = (
+                    layout, np.zeros((B, layout.width), np.uint32))
+            layout, buf = packed
+            # the step that read this buffer last has finished (its
+            # decisions were read back), so its words can be overwritten
+            layout.pack(buf, batches, directs)
+            words = put(buf)
+            lane_bytes = tuple(bytes_leaf(lane) for lane in batches)
+            direct_bytes = tuple(bytes_leaf(lane) for lane in directs)
+            sp.set_metadata(puts=self.h2d_puts - puts,
+                            bytes=self.h2d_bytes - nbytes)
         from pushcdn_tpu.parallel import router as router_mod
         before = router_mod.trace_collectives()
         with span("plane.dispatch", step=step):
-            result = self.step_fn(state, lane_batches, lane_directs,
-                                  live_dev)
+            result = self.step_fn(state, lane_bytes, direct_bytes,
+                                  live_dev, words)
         traced = router_mod.trace_collectives() - before
         if traced:  # this call compiled a fresh specialization
             self.collectives_last_trace = traced

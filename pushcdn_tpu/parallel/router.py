@@ -635,6 +635,77 @@ def routing_step_ragged_single(state: RouterState, batch: IngressBatch,
                                interpret=interpret)
 
 
+class LaneWords:
+    """Where every lane's step inputs of one tick sit in ONE u32 row a
+    shard, so that a tick's host->device traffic is one transfer: a
+    broadcast lane's ``kind``, ``length``, ``topic_mask``, ``dest`` and
+    ``valid``, then a direct lane's ``length``, ``dest`` and ``valid``,
+    each in :class:`_WordPacker`'s word form (an i32 bitcast, a bool one
+    word) and in the order the fused tick packs them for its collective.
+    The host fills a ``[B, width]`` buffer (:meth:`pack`, slice
+    assignment into rows) and the step slices a shard's row back into
+    lanes at the same static offsets (:meth:`unpack`). The geometry is
+    the lanes' own: ``ring_slots`` one S a broadcast lane,
+    ``bucket_shapes`` one (B, C) a direct lane, ``mask_words`` the
+    trailing shape of a topic mask (``()`` or ``(W,)``)."""
+
+    _BATCH = (("kind", "i32"), ("length", "i32"), ("topic_mask", "u32"),
+              ("dest", "i32"), ("valid", "bool"))
+    _DIRECT = (("length", "i32"), ("dest", "i32"), ("valid", "bool"))
+
+    def __init__(self, ring_slots, bucket_shapes, mask_words=()):
+        from math import prod
+        self.width = 0
+
+        def place(fields, shape):
+            out = []
+            for name, form in fields:
+                full = tuple(shape) + (tuple(mask_words)
+                                       if name == "topic_mask" else ())
+                out.append((name, form, self.width, prod(full), full))
+                self.width += prod(full)
+            return out
+
+        # [lane][field] -> (name, word form, offset, words, leaf shape)
+        self.lanes = [place(self._BATCH, (s,)) for s in ring_slots]
+        self.direct_lanes = [place(self._DIRECT, bc) for bc in bucket_shapes]
+
+    def pack(self, buf, batches, directs) -> None:
+        """Write [lane][shard] host snapshots (``frames.FrameBatch``,
+        ``frames.DirectBatch``) into ``buf``, ``uint32[B, width]``: every
+        word of it, so a buffer can be reused from tick to tick."""
+        for fields, lane in zip(self.lanes + self.direct_lanes,
+                                list(batches) + list(directs)):
+            for name, form, off, n, _shape in fields:
+                for row, snap in zip(buf, lane):
+                    words = row[off:off + n]
+                    if form == "i32":
+                        words = words.view("int32")
+                    words[:] = getattr(snap, name).reshape(-1)
+
+    def unpack(self, words: jax.Array, lane_bytes: tuple,
+               direct_bytes: tuple):
+        """One shard's row back into ``(batches, directs)``, each lane
+        with the ``frame_bytes`` leaf it is given."""
+        if words.shape != (self.width,):
+            raise ValueError(f"a shard's row of {self.width} words, got "
+                             f"{words.shape}")
+
+        def leaves(fields):
+            for _name, form, off, n, shape in fields:
+                leaf = words[off:off + n].reshape(shape)
+                if form == "bool":
+                    leaf = leaf != 0
+                elif form == "i32":
+                    leaf = jax.lax.bitcast_convert_type(leaf, jnp.int32)
+                yield leaf
+
+        return (tuple(IngressBatch(b, *leaves(f))
+                      for b, f in zip(lane_bytes, self.lanes)),
+                tuple(DirectIngress(d, *leaves(f))
+                      for d, f in zip(direct_bytes, self.direct_lanes)))
+
+
 def make_mesh_lane_step(mesh: Mesh, gather_bytes: bool = True,
                         fused: bool = False):
     """Build the multi-chip lane step: every leaf of (state, batches,
@@ -645,13 +716,28 @@ def make_mesh_lane_step(mesh: Mesh, gather_bytes: bool = True,
     variant whose lanes skip the frame-byte collectives (see
     :func:`routing_step_lanes`). ``fused=True`` builds the
     one-collective-per-tick variant: the whole exchange rides a single
-    packed all_gather (see :func:`_routing_step_lanes_fused`)."""
+    packed all_gather (see :func:`_routing_step_lanes_fused`).
+
+    The step has two entries. Per array: ``batches`` and ``directs`` are
+    tuples of stacked :class:`IngressBatch` / :class:`DirectIngress`.
+    Packed, with ``words``: every lane's metadata arrives as one
+    ``uint32[B, W]`` buffer in :class:`LaneWords`' layout, and ``batches``
+    and ``directs`` hold each lane's stacked ``frame_bytes`` leaf alone
+    (``[B, S, F]``, ``[B, B, C, F]``; F = 0 where nothing gathers
+    bytes), which also gives the lanes' geometry; the row is sliced back
+    into lanes before the same routing math."""
 
     def per_shard(state: RouterState, batches: tuple, directs: tuple,
-                  liveness: jax.Array):
+                  liveness: jax.Array, words: Optional[jax.Array]):
         state = jax.tree.map(lambda x: x[0], state)
         batches = jax.tree.map(lambda x: x[0], batches)
         directs = jax.tree.map(lambda x: x[0], directs)
+        if words is not None:
+            batches, directs = LaneWords(
+                [b.shape[0] for b in batches],
+                [d.shape[:2] for d in directs],
+                state.topic_masks.shape[1:]).unpack(
+                    words[0], batches, directs)
         my = jax.lax.axis_index(BROKER_AXIS).astype(jnp.int32)
         result = routing_step_lanes(state, batches, my,
                                     axis_name=BROKER_AXIS, directs=directs,
@@ -661,17 +747,15 @@ def make_mesh_lane_step(mesh: Mesh, gather_bytes: bool = True,
         return jax.tree.map(lambda x: x[None], result)
 
     sharded = jax.shard_map(
-        per_shard, mesh=mesh,
-        in_specs=(P(BROKER_AXIS), P(BROKER_AXIS), P(BROKER_AXIS),
-                  P(BROKER_AXIS)),
+        per_shard, mesh=mesh, in_specs=(P(BROKER_AXIS),) * 5,
         out_specs=P(BROKER_AXIS), check_vma=False)
 
     @jax.jit
-    def step(state, batches, directs, liveness=None):
+    def step(state, batches, directs, liveness=None, words=None):
         if liveness is None:
             B = mesh.devices.size
             liveness = jnp.ones((B, B), dtype=bool)
-        return sharded(state, batches, directs, liveness)
+        return sharded(state, batches, directs, liveness, words)
 
     return step
 

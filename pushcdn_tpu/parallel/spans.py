@@ -42,7 +42,10 @@ The spans (the names are what ``benchmark/span_reduce.py`` reads):
                                   ``step``, ``frames``, ``ring_wait_us``,
                                   ``users`` (the step's user dimension;
                                   single-shard plane)
-``plane.h2d``         worker      state and lane batches to the device; ``step``
+``plane.h2d``         worker      state and lane batches to the device; ``step``;
+                                  the mesh group's also ``puts`` and ``bytes``
+                                  (its ``device_put`` calls and the host bytes
+                                  handed to them: one put a settled tick)
 ``plane.dispatch``    worker      the jitted step's call; ``step``
 ``plane.d2h``         worker      each read-back of a decision; ``step``
 ``plane.encode``      worker      decisions to egress streams; ``step``

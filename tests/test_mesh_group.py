@@ -450,6 +450,111 @@ async def test_mesh_tick_is_one_collective():
 
 
 # ---------------------------------------------------------------------------
+# a tick's upload (ISSUE 36): every lane's metadata in one buffer, one
+# ``device_put``; frame bytes only where ``gather_frame_bytes`` has the step
+# gather them.
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("gather", [False, True],
+                         ids=["host_bytes", "gathered_bytes"])
+async def test_a_tick_uploads_what_its_step_reads_in_one_transfer(
+        gather, monkeypatch):
+    """At ``MeshGroupConfig``'s default shapes a steady tick makes ONE
+    ``device_put``, under 128 KiB, and hands the device nothing of a
+    lane's slots x width; a tick after a membership change adds the
+    state's. With ``gather_frame_bytes`` a busy shard's blocks cross too,
+    and every client receives the same frames either way."""
+    import jax
+    d = MeshGroupConfig()
+    handed = []  # (shape, nbytes) of every array given to device_put
+    real_put = jax.device_put
+
+    def recording_put(x, *args, **kwargs):
+        handed.append((np.shape(x), np.asarray(x).nbytes))
+        return real_put(x, *args, **kwargs)
+
+    monkeypatch.setattr(jax, "device_put", recording_put)
+    cluster = await MeshCluster(
+        num_shards=4, num_user_slots=d.num_user_slots,
+        ring_slots=d.ring_slots, frame_bytes=d.frame_bytes,
+        extra_lanes=d.extra_lanes,
+        direct_bucket_slots=d.direct_bucket_slots,
+        gather_frame_bytes=gather).start()
+    clients = []
+    try:
+        group = cluster.group
+        for shard in range(4):
+            clients.append(await cluster.place_client(
+                seed=3600 + shard, shard=shard, topics=[0]))
+        expected = [[] for _ in clients]
+
+        async def round_(tag: bytes):
+            """Base and wide broadcasts from shard 0, a direct from every
+            shard to the next; every client reads what it is owed."""
+            for i in range(12):
+                payload = tag + b" small %d" % i
+                await clients[0].send_broadcast_message([0], payload)
+                for got in expected:
+                    got.append(payload)
+            wide = tag + b" wide " + b"w" * 9000
+            await clients[0].send_broadcast_message([0], wide)
+            for got in expected:
+                got.append(wide)
+            for shard, c in enumerate(clients):
+                nxt = (shard + 1) % 4
+                payload = tag + b" direct from %d" % shard
+                await c.send_direct_message(clients[nxt].public_key, payload)
+                expected[nxt].append(payload)
+
+        received = [[] for _ in clients]
+
+        async def read_all():
+            async with asyncio.timeout(30):
+                for c, got, want in zip(clients, received, expected):
+                    while len(got) < len(want):
+                        got.extend(bytes(m.message)
+                                   for m in await c.receive_messages())
+
+        # the first ticks carry the four claims: the state crosses again
+        puts, steps = group.h2d_puts, group.steps
+        await round_(b"first")
+        await read_all()
+        assert group.h2d_puts - puts > group.steps - steps
+        # steady: the membership is what the last tick saw
+        del handed[:]
+        puts, nbytes, steps = group.h2d_puts, group.h2d_bytes, group.steps
+        await round_(b"steady")
+        await read_all()
+        ticks = group.steps - steps
+        assert ticks >= 1
+        words = [n for shape, n in handed if len(shape) == 2]
+        assert len(words) == ticks and len(set(words)) == 1
+        assert words[0] < 131072
+        assert (group.h2d_puts - puts, group.h2d_bytes - nbytes) == (
+            len(handed), sum(n for _shape, n in handed))
+        if gather:
+            # a busy shard's block a busy lane, whole
+            blocks = [shape for shape, _n in handed if len(shape) > 2]
+            assert blocks and {s[-1] for s in blocks} <= {
+                d.frame_bytes, d.extra_lanes[0][0]}
+            assert (1, d.ring_slots, d.frame_bytes) in blocks
+        else:
+            assert len(handed) == ticks
+            assert all(n < d.ring_slots * d.frame_bytes
+                       for _shape, n in handed)
+        assert [b.device_plane.describe()["h2d_puts"]
+                for b in cluster.brokers] == [group.h2d_puts] * 4
+        assert [sorted(got) for got in received] == \
+            [sorted(want) for want in expected]
+        assert not group.disabled and not group.overflow_seen
+    finally:
+        for c in clients:
+            c.close()
+        await cluster.stop()
+
+
+# ---------------------------------------------------------------------------
 # the group's native batch (ISSUE 34): the sends of a tick whose take found a
 # live shard's base ring or a base direct bucket full leave in one
 # ``native.send_batch`` call a lane, for the links that are idle plain sockets;

@@ -109,6 +109,7 @@ async def test_traced_steps_yield_flat_joined_conserving_spans(
         handoffs0 = (plane.egress_inline, plane.egress_queued,
                      plane.egress_batched)
         drained0 = getattr(plane, "frames_drained", 0)  # no group has it
+        described0 = cluster.brokers[0].device_plane.describe()
         options = jax.profiler.ProfileOptions()
         options.python_tracer_level = 0
         options.host_tracer_level = 2
@@ -125,6 +126,7 @@ async def test_traced_steps_yield_flat_joined_conserving_spans(
                           plane.egress_queued - handoffs0[1])
         batched = plane.egress_batched - handoffs0[2]
         drained = getattr(plane, "frames_drained", 0) - drained0
+        described = cluster.brokers[0].device_plane.describe()
     finally:
         client.close()
         await cluster.stop()
@@ -187,6 +189,25 @@ async def test_traced_steps_yield_flat_joined_conserving_spans(
     assert all(0 <= w < trace_ns / 1e3 for w in waits), waits
     _drained_conserves(events, drained)
     _batched_conserves(events, batched)
+    _uploads_conserve(events, described0, described)
+
+
+def _uploads_conserve(events, before: dict, after: dict) -> None:
+    """The mesh group's ``plane.h2d`` says what it uploaded: ``puts``
+    (``device_put`` calls) and ``bytes`` (host bytes handed to them) sum
+    to what ``describe()``'s ``h2d_puts`` / ``h2d_bytes`` moved by, one
+    put a tick with the membership settled. The single-shard plane's span
+    has neither stat, nor its ``describe()`` the keys."""
+    h2d = [e[3] for e in events if e[0] == "plane.h2d"]
+    if "h2d_puts" not in after:
+        assert not any("puts" in st or "bytes" in st for st in h2d)
+        return
+    assert sum(st["puts"] for st in h2d) == \
+        after["h2d_puts"] - before["h2d_puts"]
+    assert sum(st["bytes"] for st in h2d) == \
+        after["h2d_bytes"] - before["h2d_bytes"]
+    assert [st["puts"] for st in h2d] == [1] * len(h2d)
+    assert len({st["bytes"] for st in h2d}) <= 2  # full and sliced shapes
 
 
 def _batched_conserves(events, batched: int) -> None:

@@ -1,9 +1,12 @@
 """Device-router tests: single-chip semantics, 8-shard mesh routing,
 eviction propagation, and Pallas-kernel equivalence."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from pushcdn_tpu.ops.delivery_kernel import (
     delivery_matrix_pallas,
@@ -14,7 +17,9 @@ from pushcdn_tpu.parallel.frames import FrameRing
 from pushcdn_tpu.parallel.mesh import make_broker_mesh
 from pushcdn_tpu.parallel.router import (
     BROKER_AXIS,
+    DirectIngress,
     IngressBatch,
+    LaneWords,
     RouterState,
     empty_router_state,
     make_mesh_lane_step,
@@ -586,3 +591,173 @@ def test_fused_tick_liveness_and_eviction_equivalence():
     assert (merged[:, 2] == ABSENT).all()
     assert (merged[:, 5] == ABSENT).all()
     assert np.asarray(out_f.evictions)[0, 0]
+
+
+# ---------------------------------------------------------------------------
+# the packed entry of the mesh lane step (ISSUE 36): every lane's metadata
+# in one u32 buffer (``LaneWords``), a zero-width stub for each lane's
+# bytes where nothing gathers them — bit for bit the per-array entry, at
+# the shapes and traffic mixes ``MeshBrokerGroup`` runs it with.
+# ---------------------------------------------------------------------------
+
+_PB = 4                                   # shards
+_PLAT = 4                                 # the latency slice
+_PBASE, _PWIDE = (64, 16, 8), (256, 4, 2)  # (frame bytes, ring, bucket)
+
+
+@functools.lru_cache(maxsize=None)
+def _lane_steps(gather_bytes: bool):
+    mesh = make_broker_mesh(_PB, devices=jax.devices()[:_PB])
+    return mesh, make_mesh_lane_step(mesh, gather_bytes=gather_bytes,
+                                     fused=True)
+
+
+def _packed_case(shape, mix, topic_words, dead, seed):
+    """Host snapshots ([lane][shard]) and stacked state of one tick: the
+    lanes of ``shape`` ("full": base + wide; "sliced": the base lane's
+    first ``_PLAT`` slots), traffic on the lanes ``mix`` names, every
+    shard publishing, a re-claim that evicts, and ``dead`` masked out."""
+    from pushcdn_tpu.parallel.frames import (
+        DirectBuckets, mask_mirror_shape, slice_batch, slice_direct_batch)
+    rng = np.random.default_rng(seed)
+    W = topic_words
+    lanes = [_PBASE, _PWIDE] if shape == "full" else [_PBASE]
+    busy = {"base": [0], "wide": [1], "both": [0, 1], "none": []}[mix]
+    batches, directs = [], []
+    for li, (fb, ring_slots, bucket) in enumerate(lanes):
+        room = _PLAT if shape == "sliced" else ring_slots
+        room_d = _PLAT if shape == "sliced" else bucket
+        lane_b, lane_d = [], []
+        for shard in range(_PB):
+            ring = FrameRing(slots=ring_slots, frame_bytes=fb, topic_words=W)
+            bkts = DirectBuckets(_PB, capacity=bucket, frame_bytes=fb)
+            if li in busy:
+                for j in range(int(rng.integers(1, room + 1))):
+                    # topics on both sides of the first mask word
+                    mask = (1 << int(rng.integers(0, 32 * W))) | 1
+                    assert ring.push_broadcast(b"b%d.%d.%d" % (li, shard, j),
+                                               mask)
+                for j in range(int(rng.integers(1, room_d + 1))):
+                    to = int(rng.integers(0, _PB))
+                    assert bkts.push(to, b"d%d.%d.%d" % (li, shard, j),
+                                     dest_slot=int(rng.integers(0, U)))
+            b, d = ring.take_batch(), bkts.take_batch()
+            if shape == "sliced":
+                b, d = slice_batch(b, _PLAT), slice_direct_batch(d, _PLAT)
+            lane_b.append(b)
+            lane_d.append(d)
+        batches.append(lane_b)
+        directs.append(lane_d)
+    owners = np.full((_PB, U), ABSENT, np.int32)
+    versions = np.zeros((_PB, U), np.uint32)
+    masks = np.zeros(
+        (_PB,) + np.zeros(mask_mirror_shape(U, W)).shape, np.uint32)
+    for slot in range(U):
+        owners[:, slot] = slot % _PB
+        versions[:, slot] = 1
+        masks[:, slot] = rng.integers(0, 2**32, masks.shape[2:],
+                                      dtype=np.uint32) | 1
+    owners[1, 0], versions[1, 0] = 1, 5   # shard 1 takes user 0 from shard 0
+    state = RouterState(
+        CrdtState(jnp.asarray(owners), jnp.asarray(versions),
+                  jnp.asarray(owners)), jnp.asarray(masks))
+    live = np.ones((_PB, _PB), bool)
+    if dead is not None:
+        live[:, dead] = False
+    return batches, directs, state, jnp.asarray(live)
+
+
+def _stacked(lane, cls, fields):
+    return cls(*[jnp.asarray(np.stack([getattr(s, f) for s in lane]))
+                 for f in fields])
+
+
+def _packed_args(batches, directs, state, gather_bytes):
+    """What the packed entry takes for these snapshots: each lane's bytes
+    leaf (zero-width where nothing gathers bytes) and the one buffer,
+    packed over a used one."""
+    layout = LaneWords([lane[0].valid.shape[0] for lane in batches],
+                       [lane[0].valid.shape for lane in directs],
+                       state.topic_masks.shape[2:])
+    buf = np.full((_PB, layout.width), 0xDEADBEEF, np.uint32)
+    layout.pack(buf, batches, directs)
+
+    def bytes_leaf(lane):
+        rows = np.stack([s.bytes_ for s in lane])
+        return jnp.asarray(rows if gather_bytes else rows[..., :0])
+
+    return (tuple(bytes_leaf(lane) for lane in batches),
+            tuple(bytes_leaf(lane) for lane in directs), jnp.asarray(buf))
+
+
+@pytest.mark.parametrize("shape, mix, topic_words, dead, gather_bytes", [
+    ("full", "base", 8, None, False),
+    ("full", "wide", 8, None, False),
+    ("full", "both", 8, 2, False),
+    ("full", "none", 8, None, False),
+    ("sliced", "base", 8, None, False),
+    ("sliced", "none", 8, 1, False),
+    ("full", "both", 1, None, False),
+    ("sliced", "base", 1, 3, False),
+    ("full", "both", 8, 2, True),
+    ("sliced", "base", 1, None, True),
+], ids=lambda v: str(v))
+def test_packed_lane_step_matches_per_array(shape, mix, topic_words, dead,
+                                            gather_bytes):
+    batches, directs, state, live = _packed_case(
+        shape, mix, topic_words, dead, seed=36)
+    _mesh, step = _lane_steps(gather_bytes)
+    want = step(
+        state,
+        tuple(_stacked(lane, IngressBatch, ("bytes_", "kind", "length",
+                                            "topic_mask", "dest", "valid"))
+              for lane in batches),
+        tuple(_stacked(lane, DirectIngress, ("bytes_", "length", "dest",
+                                             "valid")) for lane in directs),
+        live)
+
+    lane_bytes, direct_bytes, words = _packed_args(
+        batches, directs, state, gather_bytes)
+    got = step(state, lane_bytes, direct_bytes, live, words)
+
+    def same(a, b):
+        if a is None or b is None:
+            assert a is None and b is None
+        else:
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    assert len(got.lanes) == len(batches) == len(want.lanes)
+    assert len(got.direct_lanes) == len(directs) == len(want.direct_lanes)
+    for g, w in zip(got.lanes + got.direct_lanes,
+                    want.lanes + want.direct_lanes):
+        same(g.deliver, w.deliver)
+        same(g.gathered_length, w.gathered_length)
+        same(g.gathered_bytes, w.gathered_bytes)
+        assert (g.gathered_bytes is not None) == gather_bytes
+    for leaf in ("owners", "versions", "identities"):
+        same(getattr(got.state.crdt, leaf), getattr(want.state.crdt, leaf))
+    same(got.state.topic_masks, want.state.topic_masks)
+    same(got.evictions, want.evictions)
+    # the case says something: the re-claim evicts, busy lanes deliver
+    assert np.asarray(got.evictions)[0, 0]
+    delivered = sum(int(np.asarray(l.deliver).sum())
+                    for l in got.lanes + got.direct_lanes)
+    assert (delivered > 0) == (mix != "none")
+
+
+def test_packed_lane_step_is_one_collective():
+    """The packed form of the fused tick is still ONE collective: counted
+    at trace time and in the lowered text."""
+    from pushcdn_tpu.parallel import router as router_mod
+    from pushcdn_tpu.parallel.router import count_collectives
+    batches, directs, state, live = _packed_case("full", "both", 8, None,
+                                                 seed=37)
+    mesh = make_broker_mesh(_PB, devices=jax.devices()[:_PB])
+    step = make_mesh_lane_step(mesh, gather_bytes=False, fused=True)
+    lane_bytes, direct_bytes, words = _packed_args(
+        batches, directs, state, gather_bytes=False)
+    args = (state, lane_bytes, direct_bytes, live, words)
+    before = router_mod.trace_collectives()
+    step(*args)
+    assert router_mod.trace_collectives() - before == 1
+    assert count_collectives(step.lower(*args).as_text()) == 1
