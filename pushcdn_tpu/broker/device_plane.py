@@ -67,6 +67,7 @@ import numpy as np
 
 from pushcdn_tpu.broker.pump_common import (
     CoalesceGate,
+    PumpAccount,
     RevCache,
     TopicMaskCache,
     effective_users,
@@ -253,6 +254,13 @@ class DevicePlane:
         # of those, staged while the pump drained the sockets into the
         # rings between an egress it wrote itself and its next take
         self.frames_drained = 0
+        # the full ring: every ``FULL`` handed back to a stager (its
+        # retries included), and the frames a ``stage_batch`` held back
+        # (each then retries alone: ``_stage_with_backpressure``)
+        self.stage_full_results = 0
+        self.stage_full_frames = 0
+        # where the pump's wall time goes (made anew when the pump starts)
+        self._account = PumpAccount()
         # the broker↔broker leg, counted by the receive loops: of
         # ``frames_staged``, those a peer's link brought
         # (``broker_receive_loop``), and the (frame, peer) sends the user
@@ -449,6 +457,7 @@ class DevicePlane:
                 self._staged_since = time.monotonic()
             self._kick.set()
             return StageResult.STAGED
+        self.stage_full_results += 1
         return StageResult.FULL
 
     def stage_batch(self, items) -> List[StageResult]:
@@ -503,6 +512,10 @@ class DevicePlane:
             staged += n
             for idx, *_ in group[n:]:  # raced-full leftovers
                 results[idx] = StageResult.FULL
+        full = results.count(StageResult.FULL)
+        if full:
+            self.stage_full_results += full
+            self.stage_full_frames += full
         if staged:
             self.frames_staged += staged
             if self._staged_since is None:
@@ -554,6 +567,7 @@ class DevicePlane:
         """The plane's device and state as one JSON-able dict — logged
         once at start, served under ``/debug/topology``."""
         from pushcdn_tpu.parallel import runtime
+        from pushcdn_tpu.proto.metrics import loop_account
         dev = runtime.device()
         return {
             "platform": dev.platform, "device_kind": dev.kind,
@@ -565,6 +579,8 @@ class DevicePlane:
             "steps": self.steps,
             "frames_staged": self.frames_staged,
             "frames_drained": self.frames_drained,
+            "stage_full_results": self.stage_full_results,
+            "stage_full_frames": self.stage_full_frames,
             "link_frames_staged": self.link_frames_staged,
             "link_frames_forwarded": self.link_frames_forwarded,
             "messages_routed": self.messages_routed,
@@ -576,6 +592,8 @@ class DevicePlane:
             "user_slots": self.user_slots,
             "user_high_water": self.slots.high_water,
             "table_grows": self.table_grows,
+            **self._account.counters(),
+            **loop_account(),
             # read when asked: whoever starts several chip-owning brokers
             # cannot look into their devices from outside
             "device_memory_peak_bytes": runtime.memory_peak_bytes(),
@@ -669,10 +687,17 @@ class DevicePlane:
         c = self.config
         loop = asyncio.get_running_loop()
         gate = CoalesceGate(c.batch_window_s, c.coalesce_min_frames)
+        # one sequential task: its states partition its wall time
+        account = self._account = PumpAccount()
         while True:
-            drained = await self._drain() if self._between_steps else 0
+            drained = 0
+            if self._between_steps:
+                account.enter("drain")
+                drained = await self._drain()
+            account.enter("parked")
             await self._kick.wait()
             self._kick.clear()
+            account.enter("gate")
             await self._load_programs()
             # From a parked pump: let the stagers of this pass land (a
             # burst's receive loops are already runnable). The pump's
@@ -702,9 +727,12 @@ class DevicePlane:
             # the base lane full at the take: its stagers wait on the
             # step, so this step's length is the publishers' rate
             back_pressured = not self.rings[0].free_slots
+            account.enter("take")
+            parked_us, gate_us, drain_us = account.since_take()
             with spans.span("plane.take", step=step, frames=staged,
                             ring_wait_us=int(waited * 1e6), users=u_eff,
-                            drained=drained):
+                            drained=drained, parked_us=parked_us,
+                            gate_us=gate_us, drain_us=drain_us):
                 # snapshot mirrors + all lane rings in ONE event-loop tick
                 batches_np = [r.take_batch() for r in self.rings]
                 if small:
@@ -721,14 +749,16 @@ class DevicePlane:
                 walks = self._pack_walks(batches_np) \
                     if self.delivery_impl == "ragged" else None
                 quarantined, self._quarantine = self._quarantine, []
+            account.enter("worker")
             try:
                 self._step_inflight = True
                 try:
                     jobs = await asyncio.to_thread(
-                        self._run_step, batches_np, owned, masks, rev,
-                        walks)
+                        account.run, self._run_step, batches_np, owned,
+                        masks, rev, walks)
                 finally:
                     self._step_inflight = False
+                account.enter("egress")
                 gate.stepped(loop.time())
                 with spans.span("plane.egress", step=step) as sp:
                     routed, inline, queued, batched = (

@@ -44,6 +44,7 @@ import numpy as np
 
 from pushcdn_tpu.broker.pump_common import (
     CoalesceGate,
+    PumpAccount,
     RevCache,
     TopicMaskCache,
     effective_users,
@@ -178,6 +179,7 @@ class MeshShardPlane:
         """This shard's view of the group for ``/debug/topology`` (the
         single-shard plane's twin)."""
         from pushcdn_tpu.parallel import runtime
+        from pushcdn_tpu.proto.metrics import loop_account
         dev = runtime.device()
         g = self.group
         return {
@@ -192,6 +194,10 @@ class MeshShardPlane:
             "egress_queued": g.egress_queued,
             "egress_batched": g.egress_batched,
             "h2d_puts": g.h2d_puts, "h2d_bytes": g.h2d_bytes,
+            "stage_full_results": g.stage_full_results,
+            "stage_full_frames": g.stage_full_frames,
+            **g._account.counters(),
+            **loop_account(),
         }
 
     @property
@@ -323,6 +329,12 @@ class MeshBrokerGroup:
         self._state_dirty = False  # forces a step with no staged traffic
         self.steps = 0
         self.frames_staged = 0  # frames accepted into a ring or bucket
+        # the full ring (DevicePlane's twins): every ``FULL`` handed back,
+        # and the frames a ``stage_batch`` held back
+        self.stage_full_results = 0
+        self.stage_full_frames = 0
+        # where the pump's wall time goes (made anew when the pump starts)
+        self._account = PumpAccount()
         # monotonic time at which rings and buckets last went from empty
         # to non-empty (None while empty): ``plane.take``'s ring_wait_us
         self._staged_since: Optional[float] = None
@@ -551,6 +563,7 @@ class MeshBrokerGroup:
                 self._staged_since = time.monotonic()
             self._kick.set()
             return StageResult.STAGED
+        self.stage_full_results += 1
         return StageResult.FULL
 
     def stage_batch(self, shard: int, items):
@@ -616,6 +629,10 @@ class MeshBrokerGroup:
             staged += n
             for idx, *_ in group[n:]:
                 results[idx] = StageResult.FULL
+        full = results.count(StageResult.FULL)
+        if full:
+            self.stage_full_results += full
+            self.stage_full_frames += full
         if staged:
             self.frames_staged += staged
             if self._staged_since is None:
@@ -650,9 +667,13 @@ class MeshBrokerGroup:
         c = self.config
         loop = asyncio.get_running_loop()
         gate = CoalesceGate(c.batch_window_s, c.coalesce_min_frames)
+        # one sequential task: its states partition its wall time
+        account = self._account = PumpAccount()
         while True:
+            account.enter("parked")
             await self._kick.wait()
             self._kick.clear()
+            account.enter("gate")
             # one yield so every stager woken in this tick lands first
             await asyncio.sleep(0)
             staged = self._staged_total()
@@ -684,8 +705,12 @@ class MeshBrokerGroup:
                       else time.monotonic() - self._staged_since)
             self._staged_since = None
             back_pressured = self._back_pressured()
+            account.enter("take")
+            parked_us, gate_us, drain_us = account.since_take()
             with spans.span("plane.take", step=step, frames=staged,
-                            ring_wait_us=int(waited * 1e6)):
+                            ring_wait_us=int(waited * 1e6),
+                            parked_us=parked_us, gate_us=gate_us,
+                            drain_us=drain_us):
                 # one-tick snapshot: all lanes' rings + buckets + mirrors
                 batches = [[r.take_batch() for r in rings]
                            for rings in self.lane_rings]
@@ -708,10 +733,12 @@ class MeshBrokerGroup:
                 liveness = self._liveness.copy()
                 rev = self._state_rev
                 quarantined, self._quarantine = self._quarantine, []
+            account.enter("worker")
             try:
                 egress_jobs = await asyncio.to_thread(
-                    self._run_step, batches, directs, owner, versions, masks,
-                    liveness, rev, step)
+                    account.run, self._run_step, batches, directs, owner,
+                    versions, masks, liveness, rev, step)
+                account.enter("egress")
                 gate.stepped(loop.time())
                 with spans.span("plane.egress", step=step) as sp:
                     routed, inline, queued, batched = (

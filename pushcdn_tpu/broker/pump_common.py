@@ -9,12 +9,15 @@ one home keeps them in lockstep):
   two so delivery matrices, their D2H, and the egress scans pay for the
   actual population, while the jit key only moves when it doubles),
 - the revision-keyed device-state cache (steady state pays zero H2D for
-  the user table).
+  the user table),
+- the pump's state account (where the one sequential pump task spends its
+  wall time, as cumulative microseconds in ``describe()``).
 """
 
 from __future__ import annotations
 
-from typing import Any, Callable, Optional
+import time
+from typing import Any, Callable, Dict, Optional, Tuple
 
 from pushcdn_tpu.parallel.frames import mask_of_topics
 
@@ -57,6 +60,78 @@ class CoalesceGate:
 
     def stepped(self, now: float) -> None:
         self.last_step_t = now
+
+
+class PumpAccount:
+    """Where the pump's wall time goes, as cumulative microseconds.
+
+    The pump is one sequential task, so its states partition its wall
+    time: ``enter(state)`` adds the time since the last call to the state
+    that ends there, and the six counters sum to the pump's lifetime
+    within the state that is still open. Plain ints that only grow, on
+    ``time.monotonic_ns()`` (the clock of the benchmark's marks): the
+    difference between two ``describe()`` calls is the interval's, the
+    sum over brokers the deployment's. ``DevicePlane`` alone has a drain;
+    the group's ``drain`` stays 0.
+
+    ====================  ================================================
+    ``pump_parked_us``    awaiting ``_kick`` with nothing staged
+    ``pump_gate_us``      ``_load_programs``, the ``sleep(0)`` that lets
+                          the pass's stagers land, the coalescing
+                          ``sleep(wait)``
+    ``pump_drain_us``     ``DevicePlane._drain``
+    ``pump_take_us``      the ``plane.take`` section
+    ``pump_worker_us``    ``await asyncio.to_thread(...)`` of the step:
+                          the worker's wall and both thread hops
+    ``pump_egress_us``    the ``plane.egress`` section
+    ``worker_busy_us``    the step's wall measured on the worker thread
+                          (:meth:`run`), so ``pump_worker_us`` less this
+                          is the two hops
+    ====================  ================================================
+    """
+
+    STATES = ("parked", "gate", "drain", "take", "worker", "egress")
+    __slots__ = ("us", "worker_busy_us", "_state", "_since", "_taken")
+
+    def __init__(self):
+        self.us: Dict[str, int] = dict.fromkeys(self.STATES, 0)
+        self.worker_busy_us = 0
+        self._state = "parked"
+        self._since = time.monotonic_ns()
+        self._taken = (0, 0, 0)
+
+    def enter(self, state: str) -> None:
+        """The open state ends now and ``state`` begins. Whole
+        microseconds are credited and the remainder carried, so nothing
+        is lost to rounding however short the states."""
+        us = (time.monotonic_ns() - self._since) // 1000
+        self.us[self._state] += us
+        self._since += us * 1000
+        self._state = state
+
+    def since_take(self) -> Tuple[int, int, int]:
+        """``(parked_us, gate_us, drain_us)`` since the last call: what
+        lay between the egress before and the take that asks, the stats
+        that close a traced period (take + worker + egress + these three
+        = take to take)."""
+        now = (self.us["parked"], self.us["gate"], self.us["drain"])
+        last, self._taken = self._taken, now
+        return tuple(a - b for a, b in zip(now, last))
+
+    def run(self, step: Callable[..., Any], *args) -> Any:
+        """``step(*args)`` on the calling thread (the worker), its wall
+        added to ``worker_busy_us``."""
+        t0 = time.monotonic_ns()
+        try:
+            return step(*args)
+        finally:
+            self.worker_busy_us += (time.monotonic_ns() - t0) // 1000
+
+    def counters(self) -> Dict[str, int]:
+        """The seven counters under their names in ``describe()``."""
+        out = {f"pump_{state}_us": us for state, us in self.us.items()}
+        out["worker_busy_us"] = self.worker_busy_us
+        return out
 
 
 class RevCache:

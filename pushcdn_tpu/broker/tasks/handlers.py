@@ -533,7 +533,11 @@ async def _stage_with_backpressure(device, message, raw: Bytes):
     receive loop and retry — the same "block the reader, not the router"
     semantics the byte-pool gives the host path. The wait is unbounded on
     purpose (so is the pool's): if the pump dies it flips ``disabled`` and
-    try_stage starts returning INELIGIBLE, which exits the loop."""
+    try_stage starts returning INELIGIBLE, which exits the loop. It is
+    handed the frames a ``stage_batch`` held back, which the plane counted
+    there once each (``stage_full_frames``); every ``FULL`` of the retry
+    counts under ``stage_full_results``. No span: the retry is per frame
+    and holds ``await``s."""
     while True:
         result = device.try_stage(message, raw)
         if result != StageResult.FULL:

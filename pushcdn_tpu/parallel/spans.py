@@ -41,7 +41,12 @@ The spans (the names are what ``benchmark/span_reduce.py`` reads):
 ``plane.take``        event loop  the pump's snapshot of rings and mirrors;
                                   ``step``, ``frames``, ``ring_wait_us``,
                                   ``users`` (the step's user dimension;
-                                  single-shard plane)
+                                  single-shard plane), ``parked_us``,
+                                  ``gate_us``, ``drain_us`` (the pump's time
+                                  in those three states since the take
+                                  before: with them a traced period closes,
+                                  take + worker + egress + these three =
+                                  take to take)
 ``plane.h2d``         worker      state and lane batches to the device; ``step``;
                                   the mesh group's also ``puts`` and ``bytes``
                                   (its ``device_put`` calls and the host bytes
@@ -60,6 +65,54 @@ The spans (the names are what ``benchmark/span_reduce.py`` reads):
 ``step`` is the plane's own step number; the two thread hops of a step
 are the gaps between ``plane.take`` and the first ``plane.h2d``, and
 between the last worker span and ``plane.egress``.
+
+**Counters.** A trace covers seconds; the window is read off cumulative
+counters in the planes' ``describe()`` (``/debug/topology``'s
+``device_plane``), each a sum of plain ints that only grows (events,
+microseconds on ``time.monotonic_ns()``), never a gauge or a mean: the
+difference between two readings is the interval's, the sum over brokers
+the deployment's. Beside the older ones (``steps``, ``frames_staged``,
+``messages_routed``, ``egress_*``, ``frames_drained``, ``link_frames_*``,
+``h2d_*``), with where each is incremented and the per-layer metric of
+``benchmark/layer_metrics/`` that reads it:
+
+==========================  ==============================================
+``pump_parked_us`` ...      ``pump_common.PumpAccount.enter``, called by
+``pump_egress_us`` (six)    both pumps at every change of state (parked,
+                            gate, drain, take, worker, egress): they
+                            partition the pump task's wall time;
+                            ``pump_parked_share``
+``worker_busy_us``          ``PumpAccount.run``, on the worker thread,
+                            around the step: ``pump_worker_us`` less this
+                            is the two hops; ``step_hop_ms``,
+                            ``sat_step_hop_ms``
+``stage_full_results``      ``try_stage`` / ``stage_batch`` of both
+                            planes, every ``FULL`` handed back (a retry's
+                            too); no reader
+``stage_full_frames``       ``stage_batch``, the frames it held back (each
+                            then retries alone on a 2 ms poll);
+                            ``ring_full_share``
+``writer_dequeues``,        read off ``cdn_writer_queue_delay_seconds``
+``writer_wait_us``,         (``Connection._account_entry`` observes every
+``writer_wait_over_500ms``  dequeue's wait) when asked; ``writer_wait_ms``
+``writer_writes``,          ``AsyncioStream.write`` / ``writev`` (the
+``writer_write_us``,        transport's synchronous ``write``, never the
+``writer_write_bytes``      drain, never ``write_nowait``);
+                            ``writer_us_per_write``
+``loop_lag_us``,            ``proto/metrics.py:_loop_lag_sampler``, a
+``loop_lag_samples``        sample a 0.25 s; None where no sampler runs;
+                            ``loop_lag_ms``
+``profiler_ticks``,         ``proto/metrics.py:_task_profiler``, a tick a
+``profiler_tick_us``,       0.25 s: what its walk of ``all_tasks()`` held
+``profiler_tick_tasks``     the loop for, over how many tasks; None where
+                            no profiler runs; read by PERF.md
+==========================  ==============================================
+
+The writers' write and the profiler's tick are counters and no spans
+(``writer.write``, ``profiler.tick``): the benchmark's own test holds the
+span names of a traced ``global-steady`` dry run to a lone broker's
+eight (``ingress.*``, ``plane.*``: ``tests/benchmark/test_span_reduce.py``),
+and both would appear there.
 """
 
 from __future__ import annotations

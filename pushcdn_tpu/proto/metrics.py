@@ -936,6 +936,24 @@ PRE_RENDER_HOOKS.append(_refresh_loop_lag)
 # either, so the probe's own timeout covers total stalls)
 _loop_lag_last = 0.0
 
+# The loop's account (``loop_account``): cumulative sums of plain ints
+# that only grow and are never reset, so that the difference between two
+# readings is an interval's and a sum over brokers a deployment's. Every
+# sample's lag (whole microseconds) and the number of samples; the task
+# profiler's ticks, the time its walk held the loop and the live tasks it
+# counted; the writers' synchronous writes (``AsyncioStream.write`` /
+# ``writev``: calls, time inside the transport's ``write``, bytes). The
+# sampler's and the profiler's are None until one runs: a process that
+# serves no metrics endpoint has neither.
+_loop_lag_us: Optional[int] = None
+_loop_lag_samples: Optional[int] = None
+_profiler_ticks: Optional[int] = None
+_profiler_tick_ns = 0
+_profiler_tick_tasks = 0
+_writer_writes = 0
+_writer_write_ns = 0
+_writer_write_bytes = 0
+
 
 async def _loop_lag_sampler(interval_s: float = 0.25) -> None:
     """Sample event-loop scheduling lag: how late a sleep() wakeup ran.
@@ -943,8 +961,10 @@ async def _loop_lag_sampler(interval_s: float = 0.25) -> None:
     decode) shows up here before it shows up as user-visible latency.
     Samples accumulate as a max; the pre-render hook publishes-and-resets
     per scrape."""
-    global _loop_lag_peak, _loop_lag_last
+    global _loop_lag_peak, _loop_lag_last, _loop_lag_us, _loop_lag_samples
     loop = asyncio.get_running_loop()
+    if _loop_lag_samples is None:
+        _loop_lag_us = _loop_lag_samples = 0
     while True:
         t0 = loop.time()
         await asyncio.sleep(interval_s)
@@ -952,6 +972,43 @@ async def _loop_lag_sampler(interval_s: float = 0.25) -> None:
         _loop_lag_last = lag
         if lag > _loop_lag_peak:
             _loop_lag_peak = lag
+        _loop_lag_us += max(int(lag * 1e6), 0)
+        _loop_lag_samples += 1
+
+
+def note_writer_write(t0_ns: int, nbytes: int) -> None:
+    """One synchronous write of a writer task, begun at ``t0_ns``
+    (``time.monotonic_ns()``), is over: ``nbytes`` were handed to the
+    transport (on an empty buffer the ``send()`` happened there)."""
+    global _writer_writes, _writer_write_ns, _writer_write_bytes
+    _writer_writes += 1
+    _writer_write_ns += time.monotonic_ns() - t0_ns
+    _writer_write_bytes += nbytes
+
+
+def loop_account() -> Dict[str, Optional[int]]:
+    """What a device plane's ``describe()`` carries of this module, all
+    cumulative, read from what is already kept when asked: every writer
+    dequeue's wait (``cdn_writer_queue_delay_seconds``, all classes:
+    count, summed wait, and the count above the family's 0.5 s bucket),
+    the writers' writes, the lag sampler's two sums and the task
+    profiler's three (None where none runs)."""
+    waits = WRITER_QUEUE_DELAY_CLS
+    over = WRITER_QUEUE_DELAY.buckets.index(0.5) + 1
+    ticking = _profiler_ticks is not None
+    return {
+        "writer_dequeues": sum(h.total for h in waits),
+        "writer_wait_us": int(sum(h.sum for h in waits) * 1e6),
+        "writer_wait_over_500ms": sum(sum(h.counts[over:]) for h in waits),
+        "writer_writes": _writer_writes,
+        "writer_write_us": _writer_write_ns // 1000,
+        "writer_write_bytes": _writer_write_bytes,
+        "loop_lag_us": _loop_lag_us,
+        "loop_lag_samples": _loop_lag_samples,
+        "profiler_ticks": _profiler_ticks,
+        "profiler_tick_us": _profiler_tick_ns // 1000 if ticking else None,
+        "profiler_tick_tasks": _profiler_tick_tasks if ticking else None,
+    }
 
 
 # ---------------------------------------------------------------------------
@@ -1014,9 +1071,13 @@ async def _task_profiler(interval_s: Optional[float] = None) -> None:
         # supervised() wrapper both stay quiet
         await asyncio.Event().wait()
         return
+    global _profiler_ticks, _profiler_tick_ns, _profiler_tick_tasks
+    if _profiler_ticks is None:
+        _profiler_ticks = 0
     name_cache: Dict[str, str] = {}
     while True:
         await asyncio.sleep(interval_s)
+        t0 = time.monotonic_ns()
         counts: Dict[str, int] = {}
         for task in asyncio.all_tasks():
             if task.done():
@@ -1037,6 +1098,10 @@ async def _task_profiler(interval_s: Optional[float] = None) -> None:
             counts[family] = counts.get(family, 0) + 1
         for family, n in counts.items():
             _family_child(family).inc(n)
+        # what a tick held the loop for, and over how many live tasks
+        _profiler_ticks += 1
+        _profiler_tick_tasks += sum(counts.values())
+        _profiler_tick_ns += time.monotonic_ns() - t0
 
 
 # ---------------------------------------------------------------------------

@@ -271,8 +271,14 @@ class AsyncioStream(RawStream):
         # memoryviews are materialized here (not passed through): newer
         # asyncio transports keep buffer references instead of copying,
         # and the egress pool recycles the underlying buffer as soon as
-        # its lease drops — the transport must own a private copy
+        # its lease drops — the transport must own a private copy.
+        # Counted: the synchronous hand-off alone (on an empty buffer the
+        # ``send()`` happens here), never the drain's await.
+        # ``write_nowait`` is not: the pump's inline write lies inside
+        # ``plane.egress`` and ``pump_egress_us``
+        t0 = time.monotonic_ns()
         self.writer.write(bytes(data) if isinstance(data, memoryview) else data)
+        metrics_mod.note_writer_write(t0, len(data))
         await self.writer.drain()
 
     def write_nowait(self, data) -> bool:
@@ -303,8 +309,10 @@ class AsyncioStream(RawStream):
     async def writev(self, bufs) -> None:
         # one gather handoff: writelines joins the run into a single
         # transport write (one kernel handoff instead of one per buffer)
+        t0 = time.monotonic_ns()
         self.writer.writelines(
             [bytes(b) if isinstance(b, memoryview) else b for b in bufs])
+        metrics_mod.note_writer_write(t0, sum(map(len, bufs)))
         await self.writer.drain()
 
     async def close(self) -> None:

@@ -182,6 +182,14 @@ async def test_meshed_device_brokers_deliver_what_the_reference_owes(
         planes = [b.device_plane for b in mesh.cluster.brokers]
         assert not any(plane.disabled for plane in planes)
         assert met["link"] > 0 and met["user"] > 0, met
+        # the planes count what the retry was handed: a frame once,
+        # however often the ring said "full" to it again
+        assert sum(plane.stage_full_frames for plane in planes) == \
+            met["link"] + met["user"]
+        for plane in planes:
+            said = plane.describe()
+            assert said["stage_full_results"] >= \
+                said["stage_full_frames"] == plane.stage_full_frames > 0
         # the link counters: every broadcast crossed to every peer (each
         # topic has a subscriber there), a direct to its owner alone
         broadcasts = sum(kind == plan.BROADCAST for _, kind, _ in log)
