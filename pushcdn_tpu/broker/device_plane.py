@@ -280,6 +280,9 @@ class DevicePlane:
         # of the inline ones, those one native call sent for a step whose
         # take found the base lane full (senders.egress_streams)
         self.egress_batched = 0
+        # of the batched ones, those whose send() came back short and
+        # were settled one by one; the rest were settled in one pass
+        self.egress_batched_short = 0
         self.warmup_s: Optional[float] = None
 
     # ---- user lifecycle (Connections observer; event-loop only) ----------
@@ -587,6 +590,7 @@ class DevicePlane:
             "egress_inline": self.egress_inline,
             "egress_queued": self.egress_queued,
             "egress_batched": self.egress_batched,
+            "egress_batched_short": self.egress_batched_short,
             "mirrored_users": len(self.slots),
             "unmirrored_users": len(self._unmirrored),
             "user_slots": self.user_slots,
@@ -761,9 +765,10 @@ class DevicePlane:
                 account.enter("egress")
                 gate.stepped(loop.time())
                 with spans.span("plane.egress", step=step) as sp:
-                    routed, inline, queued, batched = (
+                    routed, inline, queued, batched, short = (
                         self.messages_routed, self.egress_inline,
-                        self.egress_queued, self.egress_batched)
+                        self.egress_queued, self.egress_batched,
+                        self.egress_batched_short)
                     for streams, d2, lengths, frames in jobs:
                         if streams is not None:
                             egress_streams(self, self.broker, streams,
@@ -774,7 +779,8 @@ class DevicePlane:
                         deliveries=self.messages_routed - routed,
                         inline=self.egress_inline - inline,
                         queued=self.egress_queued - queued,
-                        batched=self.egress_batched - batched)
+                        batched=self.egress_batched - batched,
+                        short=self.egress_batched_short - short)
                 # the pump's own ``send()``s held the loop: drain the
                 # sockets before the next take. Streams that were all
                 # queued for their writers were no such hold (the loop

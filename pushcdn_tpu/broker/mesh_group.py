@@ -193,6 +193,7 @@ class MeshShardPlane:
             "egress_inline": g.egress_inline,
             "egress_queued": g.egress_queued,
             "egress_batched": g.egress_batched,
+            "egress_batched_short": g.egress_batched_short,
             "h2d_puts": g.h2d_puts, "h2d_bytes": g.h2d_bytes,
             "stage_full_results": g.stage_full_results,
             "stage_full_frames": g.stage_full_frames,
@@ -344,6 +345,7 @@ class MeshBrokerGroup:
         self.egress_inline = 0
         self.egress_queued = 0
         self.egress_batched = 0  # of the inline ones: by the native batch
+        self.egress_batched_short = 0  # of those: settled one by one
         self._members = _Members(self)
         # collectives traced by the most recently COMPILED step
         # specialization (router.trace_collectives delta around the call):
@@ -741,9 +743,10 @@ class MeshBrokerGroup:
                 account.enter("egress")
                 gate.stepped(loop.time())
                 with spans.span("plane.egress", step=step) as sp:
-                    routed, inline, queued, batched = (
+                    routed, inline, queued, batched, short = (
                         self.messages_routed, self.egress_inline,
-                        self.egress_queued, self.egress_batched)
+                        self.egress_queued, self.egress_batched,
+                        self.egress_batched_short)
                     for streams, d2, lengths, frames in egress_jobs:
                         if streams is not None:
                             egress_streams(self, self._members, streams,
@@ -755,7 +758,8 @@ class MeshBrokerGroup:
                         deliveries=self.messages_routed - routed,
                         inline=self.egress_inline - inline,
                         queued=self.egress_queued - queued,
-                        batched=self.egress_batched - batched)
+                        batched=self.egress_batched - batched,
+                        short=self.egress_batched_short - short)
             except asyncio.CancelledError:
                 raise
             except Exception:

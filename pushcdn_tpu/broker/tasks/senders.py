@@ -20,6 +20,7 @@ from pushcdn_tpu.proto import flowclass
 from pushcdn_tpu.proto import ledger as ledger_mod
 from pushcdn_tpu.proto import metrics as metrics_mod
 from pushcdn_tpu.proto.limiter import Bytes
+from pushcdn_tpu.proto.transport.base import Connection
 from pushcdn_tpu.proto.util import mnemonic
 
 if TYPE_CHECKING:
@@ -176,42 +177,64 @@ def _egress_batched(plane, broker: "Broker", streams) -> list:
     before it returns), straight from the step's pooled buffer; the slots
     it did not take, for the caller's loop. The event loop stands still
     for the call, as it does for that loop's ``send()``s, so between a
-    link's check, its send and its settling (``Connection.sent_on_fd``)
-    nothing else can write to, close or reuse its socket, and a user has
-    one stream in ``streams``, so one send: nothing can reorder. A full
-    send is tallied like a stream the pump wrote itself
-    (``egress_inline``) and in ``egress_batched``; so is a short one,
-    whose remainder its transport holds from then on; any other errno
-    removes that user only."""
+    link's check, its send and its settling nothing else can write to,
+    close or reuse its socket, and a user has one stream in ``streams``,
+    so one send: nothing can reorder.
+
+    The sends that took their whole stream (all of them, where the
+    readers keep up) are settled here in one pass, not once a link:
+    the plane's tallies grow by their sums (a stream the pump wrote
+    itself, ``egress_inline``, and ``egress_batched``), and
+    ``Connection.sent_whole_on_fds`` credits the transport's byte count,
+    the class counters and the ledger's transit once with the totals.
+    Every other entry goes through ``Connection.sent_on_fd`` as a send
+    of its own, in batch order: a short one (``EAGAIN``: of no bytes),
+    whose remainder its transport holds from then on, is tallied like a
+    full one and in ``egress_batched_short``; any other errno removes
+    that user only, and is in no tally."""
     slots = plane.slots
-    nbytes = streams.nbytes
     user_connection = broker.connections.get_user_connection
-    batch, rest = [], []
-    for slot in streams.users:
+    users = streams.users
+    taken, keys, links, fds, rest = [], [], [], [], []
+    for slot, size in zip(users, streams.nbytes[users].tolist()):
         key = slots.key_of(slot)
         connection = None if key is None else user_connection(key)
-        fd = None if connection is None \
-            else connection.idle_fd(int(nbytes[slot]))
+        fd = None if connection is None else connection.idle_fd(size)
         if fd is None:
             rest.append(slot)
         else:
-            batch.append((slot, key, connection, fd))
-    if not batch:
+            taken.append(slot)
+            keys.append(key)
+            links.append(connection)
+            fds.append(fd)
+    if not taken:
         return rest
-    taken = np.fromiter((b[0] for b in batch), np.int64, len(batch))
-    sent = native_mod.send_batch(
-        streams.buf, np.fromiter((b[3] for b in batch), np.int32, len(batch)),
-        streams.offsets[taken], nbytes[taken]).tolist()
-    for (slot, key, connection, _), n in zip(batch, sent):
-        nframes = int(streams.msgs[slot])
+    at = np.array(taken, np.int64)
+    nbytes, nframes = streams.nbytes[at], streams.msgs[at]
+    sent = native_mod.send_batch(streams.buf, np.array(fds, np.int32),
+                                 streams.offsets[at], nbytes)
+    whole = sent == nbytes
+    for i in np.flatnonzero(~whole).tolist():
         try:
-            connection.sent_on_fd(streams.stream(slot), n, nframes=nframes)
+            links[i].sent_on_fd(streams.stream(taken[i]), int(sent[i]),
+                                nframes=int(nframes[i]))
         except Exception as exc:
-            _send_failed(broker, key, connection, exc)
+            _send_failed(broker, keys[i], links[i], exc)
             continue
-        plane.messages_routed += nframes
+        plane.messages_routed += int(nframes[i])
         plane.egress_inline += 1
         plane.egress_batched += 1
+        plane.egress_batched_short += 1
+    if whole.all():
+        settled = links
+    else:
+        settled = [links[i] for i in np.flatnonzero(whole).tolist()]
+        nbytes, nframes = nbytes[whole], nframes[whole]
+    if settled:
+        Connection.sent_whole_on_fds(settled, nbytes, nframes)
+        plane.messages_routed += int(nframes.sum())
+        plane.egress_inline += len(settled)
+        plane.egress_batched += len(settled)
     return rest
 
 
@@ -227,10 +250,12 @@ def egress_streams(plane, broker: "Broker", streams,
     next one carries as many frames and makes as many sends however
     short this one is, and the time of the sends is the rate. Only then
     do the idle links' sends leave together over several threads
-    (:func:`_egress_batched`); every other hand-off, and every one of a
-    step that is not back-pressured (where shorter sends would buy a
-    faster cadence of smaller steps with cores), goes one by one
-    below."""
+    (:func:`_egress_batched`, which also accounts for them: the whole
+    sends of the batch in one pass, the plane's tallies by their sums);
+    every other hand-off, and every one of a step that is not
+    back-pressured (where shorter sends would buy a faster cadence of
+    smaller steps with cores), goes one by one below, each accounted by
+    the connection's own call."""
     slots = plane.slots
     users = streams.users
     if back_pressured:

@@ -59,7 +59,9 @@ The spans (the names are what ``benchmark/span_reduce.py`` reads):
                                   for the writer; ``step``, ``deliveries``,
                                   ``inline``, ``queued``, ``batched`` (of
                                   ``inline``, sent by one native call: a
-                                  ``DevicePlane`` step that was back-pressured)
+                                  step or tick that was back-pressured),
+                                  ``short`` (of ``batched``, settled one by
+                                  one after a short send; the rest in one pass)
 ====================  ==========  ===========================================
 
 ``step`` is the plane's own step number; the two thread hops of a step

@@ -1367,7 +1367,8 @@ class Connection:
         the stream gives its descriptor (:meth:`RawStream.idle_fd`: a
         plain socket whose transport holds no bytes back). The caller
         sends once, without blocking, before anything else runs on the
-        loop, and settles with :meth:`sent_on_fd`; that method's argument
+        loop, and settles with :meth:`sent_on_fd` (many whole sends
+        together: :meth:`sent_whole_on_fds`); that method's argument
         covers both, with nothing kept on the connection in between."""
         return self._stream.idle_fd() if self._inline_ok(nbytes) else None
 
@@ -1383,7 +1384,9 @@ class Connection:
         :meth:`try_send_encoded_inline`: the loop has not turned since
         :meth:`idle_fd`, so nothing was queued, written or closed in
         between, and the descriptor was this link's all through.
-        Accounting is that method's too."""
+        Accounting is that method's too. A caller with many such sends
+        settles those that took their whole stream together
+        (:meth:`sent_whole_on_fds`) and brings only the others here."""
         if sent in (-errno.EAGAIN, -errno.EWOULDBLOCK):
             sent = 0
         try:
@@ -1396,6 +1399,22 @@ class Connection:
             self._poison(err)
             raise err
         self._count_inline(cls & 3, len(data), nframes)
+
+    @staticmethod
+    def sent_whole_on_fds(links, nbytes, nframes, cls: int = 2) -> None:
+        """:meth:`sent_on_fd` for many links in one pass, each of whose
+        one ``send()`` took its whole stream: link ``i``'s was
+        ``nbytes[i]`` bytes and ``nframes[i]`` frames (integer arrays).
+        Such a send leaves nothing for a stream and nothing to poison, so
+        settling it is the accounting alone, and every sum of that is
+        process-wide, kept per transport label, class and ledger peer:
+        the links that share those are credited once, with their totals.
+        Every family and the ledger then read what a call a link gives."""
+        accounts = [(link._m_sent, link.ledger_peer) for link in links]
+        for account in set(accounts):
+            at = [i for i, a in enumerate(accounts) if a == account]
+            links[at[0]]._count_inline(cls & 3, int(nbytes[at].sum()),
+                                       int(nframes[at].sum()))
 
     async def send_encoded(self, data, owner=None, flush: bool = False,
                            cls: int = 2, nframes: int = 0,

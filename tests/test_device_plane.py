@@ -3,8 +3,10 @@ churn under traffic, slot-table exhaustion fallback."""
 
 import asyncio
 import contextlib
+import errno
 import os
 
+import numpy as np
 import pytest
 
 from pushcdn_tpu.parallel.frames import UserSlots
@@ -1027,3 +1029,290 @@ async def test_links_without_a_plain_idle_socket_are_never_batched(
         assert got == [payloads] * 3
         assert plane.egress_batched == 0 and not calls
         assert plane.egress_inline + plane.egress_queued == 3 * plane.steps
+
+
+# ---------------------------------------------------------------------------
+# the batch's settling (ISSUE 38): the sends that took their whole stream are
+# settled in one pass (the plane's tallies by their sums, the process-wide
+# counters and the ledger once a (transport label, ledger peer) with the
+# totals); every other entry goes through ``Connection.sent_on_fd`` as before
+# and is counted in ``egress_batched_short``.
+# ---------------------------------------------------------------------------
+
+class _NeverWhole(np.ndarray):
+    """What ``send_batch`` returned, equal to nothing: the comparison
+    that picks the whole sends finds none, so every entry of the batch
+    is settled by ``Connection.sent_on_fd``, as before ISSUE 38."""
+
+    def __eq__(self, other):
+        return np.zeros(self.shape, bool)
+
+    __hash__ = None
+
+
+def _never_whole(fds, sent):
+    """``_record_batches``'s ``after`` that forces the per-link path."""
+    return sent.view(_NeverWhole)
+
+
+def _watch_settling(monkeypatch) -> tuple:
+    """``(each, together)``: every ``Connection.sent_on_fd`` call as
+    ``(link, sent)`` and every ``sent_whole_on_fds`` call as ``(links,
+    nbytes, nframes)`` lists, both still made."""
+    from pushcdn_tpu.proto.transport.base import Connection
+    each, together = [], []
+    real_each, real_together = Connection.sent_on_fd, \
+        Connection.sent_whole_on_fds
+
+    def sent_on_fd(self, data, sent, **kwargs):
+        each.append((self, sent))
+        return real_each(self, data, sent, **kwargs)
+
+    def sent_whole_on_fds(links, nbytes, nframes, **kwargs):
+        together.append((list(links), nbytes.tolist(), nframes.tolist()))
+        return real_together(links, nbytes, nframes, **kwargs)
+    monkeypatch.setattr(Connection, "sent_on_fd", sent_on_fd)
+    monkeypatch.setattr(Connection, "sent_whole_on_fds",
+                        staticmethod(sent_whole_on_fds))
+    return each, together
+
+
+def _stall_reader(link, client) -> None:
+    """``client`` stops reading behind socket buffers of 4 KB at both
+    ends of ``link``: a stream of tens of KB gets a short ``send()``.
+    ``client._connection._stream.reader._transport.resume_reading()``
+    undoes it."""
+    import socket
+    stream = client._connection._stream
+    stream.writer.get_extra_info("socket").setsockopt(
+        socket.SOL_SOCKET, socket.SO_RCVBUF, 4096)
+    link._stream.writer.get_extra_info("socket").setsockopt(
+        socket.SOL_SOCKET, socket.SO_SNDBUF, 4096)
+    stream.reader._transport.pause_reading()
+
+
+def _shut_down_before_the_batch(gone: list):
+    """``_record_batches``'s ``before``: the peer of the fd in ``gone``
+    (popped) is gone by the time of the ``send()``, which gets
+    ``EPIPE``."""
+    import socket
+
+    def shut_down(fds):
+        if gone:
+            sock = socket.socket(fileno=os.dup(gone.pop()))
+            sock.shutdown(socket.SHUT_RDWR)
+            sock.close()
+    return shut_down
+
+
+def _egress_account(plane) -> dict:
+    """Every sum a settled send moves, flat: the process-wide counters,
+    the ledger, the plane's tallies."""
+    from pushcdn_tpu.proto import ledger as ledger_mod
+    from pushcdn_tpu.proto import metrics as metrics_mod
+    book = ledger_mod.LEDGER
+    out = {("bytes_sent",) + k: c.value
+           for k, c in metrics_mod.BYTES_SENT._children.items()}
+    for name, family in (("class_frames_out", metrics_mod.CLASS_FRAMES_OUT),
+                         ("class_bytes_out", metrics_mod.CLASS_BYTES_OUT)):
+        out.update({(name, i): c.value for i, c in enumerate(family)})
+    out.update({("queued", i): n for i, n in enumerate(book.queued)})
+    out.update({("fate",) + k + (i,): n for k, row in book.fates.items()
+                for i, n in enumerate(row)})
+    out.update({("plane", k): getattr(plane, k) for k in (
+        "messages_routed", "egress_inline", "egress_queued",
+        "egress_batched", "egress_batched_short")})
+    return out
+
+
+def _moved(before: dict, after: dict) -> dict:
+    """What ``_egress_account`` moved by, the keys that did not left out."""
+    return {k: v - before.get(k, 0) for k, v in after.items()
+            if v != before.get(k, 0)}
+
+
+def _seeded_rounds(seed: int, lane: int = _LANE) -> list:
+    """Rounds of ``lane`` frames (a full base lane each: a back-pressured
+    step), sizes drawn from ``seed``."""
+    rng = np.random.default_rng(seed)
+    return [[(b"%d.%d|" % (r, i)).ljust(int(rng.integers(8, 900)), b".")
+             for i in range(lane)] for r in range(int(rng.integers(2, 5)))]
+
+
+async def _settled_rounds(seed: int, monkeypatch, per_link: bool):
+    """``_seeded_rounds`` through one broker with four subscribers that
+    keep up; what ``_egress_account`` moved by over them, and how many
+    ``sent_on_fd`` calls settled them."""
+    from pushcdn_tpu.broker.device_plane import DevicePlaneConfig
+    from pushcdn_tpu.proto import ledger as ledger_mod
+
+    one_by_one, _ = _watch_settling(monkeypatch)
+    calls = _record_batches(monkeypatch, after=_never_whole) if per_link \
+        else _record_batches(monkeypatch)
+    rounds = _seeded_rounds(seed)
+    async with _served_over_tcp(
+            seed, DevicePlaneConfig(**_BATCH_PLANE), [{0}] * 4) as (
+                broker, clients):
+        plane = broker.device_plane
+        before = _egress_account(plane)
+        for r, frames in enumerate(rounds):
+            os.write(_socket_of(clients[0]), _wire(*frames))
+            assert await _receive_all(clients, _LANE) == [frames] * 4
+            assert plane.steps == r + 1
+        moved = _moved(before, _egress_account(plane))
+        book = ledger_mod.LEDGER
+        assert book.walk_live_queues() == 0
+        assert book.derived_in_queue() == [0] * len(book.queued)
+    assert [sent for _, nbytes, sent in calls] == \
+        [nbytes for _, nbytes, sent in calls]       # every send was whole
+    assert len(calls) == len(rounds)
+    return moved, len(one_by_one), rounds
+
+
+@pytest.mark.parametrize("seed", [3801, 3802, 3803])
+async def test_settling_in_one_pass_leaves_what_settling_each_link_leaves(
+        seed, monkeypatch):
+    from pushcdn_tpu.proto import flowclass
+
+    bulk, bulk_calls, rounds = await _settled_rounds(seed, monkeypatch,
+                                                     per_link=False)
+    with monkeypatch.context() as patched:
+        each, each_calls, _ = await _settled_rounds(seed, patched,
+                                                    per_link=True)
+    handoffs = 4 * len(rounds)
+    assert (bulk_calls, each_calls) == (0, handoffs)
+    assert bulk.pop(("plane", "egress_batched_short"), 0) == 0
+    assert each.pop(("plane", "egress_batched_short")) == handoffs
+    assert bulk == each
+    # and both are what the steps delivered: every frame to all four,
+    # each stream a frame's u32 length and its bytes
+    frames = 4 * _LANE * len(rounds)
+    wire = 4 * len(_wire(*(f for frames_ in rounds for f in frames_)))
+    live = flowclass.LIVE
+    assert bulk == {
+        ("bytes_sent", "tcp"): wire, ("class_bytes_out", live): wire,
+        ("class_frames_out", live): frames, ("queued", live): frames,
+        ("fate", "delivered", "egress", live): frames,
+        ("plane", "messages_routed"): frames,
+        ("plane", "egress_inline"): handoffs,
+        ("plane", "egress_batched"): handoffs}
+
+
+async def test_a_mixed_batch_settles_the_whole_sends_together_and_the_rest_each(
+        monkeypatch):
+    """One batch with two readers that keep up, one that has stopped
+    reading (a short send) and one whose peer is gone (``EPIPE``): the
+    two whole sends are settled in one ``sent_whole_on_fds`` call, the
+    two others by ``sent_on_fd`` in batch order; ``egress_batched_short``
+    counts the short one, the failed one is in no tally and its user is
+    removed, and nobody's frames change order."""
+    from pushcdn_tpu.broker.device_plane import DevicePlaneConfig
+    from pushcdn_tpu.broker.tasks import senders
+
+    lane = 48   # 48 KB a user a step: over what a stalled link's buffers take
+    first = [(b"0.%d|" % i).ljust(1000, b".") for i in range(lane)]
+    second = [(b"1.%d|" % i).ljust(1000, b".") for i in range(lane)]
+    gone, failed = [], []
+    calls = _record_batches(monkeypatch, _shut_down_before_the_batch(gone))
+    each, together = _watch_settling(monkeypatch)
+    real_failed = senders._send_failed
+
+    def send_failed(broker, key, connection, exc):
+        failed.append(key)
+        real_failed(broker, key, connection, exc)
+    monkeypatch.setattr(senders, "_send_failed", send_failed)
+    async with _served_over_tcp(
+            3810, DevicePlaneConfig(**dict(_BATCH_PLANE, ring_slots=lane)),
+            [{0}] * 4) as (broker, clients):
+        plane = broker.device_plane
+        stalled, victim = clients[2], clients[3]
+        links = [broker.connections.get_user_connection(c.public_key)
+                 for c in clients]
+        _stall_reader(links[2], stalled)
+        stalled_fd, victim_fd = (_broker_fd(broker, c)
+                                 for c in (stalled, victim))
+        gone.append(victim_fd)
+        before = _egress_account(plane)
+        os.write(_socket_of(clients[0]), _wire(*first))
+        assert await _receive_all(clients[:2], lane) == [first] * 2
+        (fds, nbytes, sent), = calls
+        assert len(fds) == 4 and len(set(nbytes)) == 1
+        size = nbytes[0]
+        short_at, failed_at = fds.index(stalled_fd), fds.index(victim_fd)
+        assert 0 <= sent[short_at] < size or sent[short_at] == -errno.EAGAIN
+        assert sent[failed_at] == -errno.EPIPE
+        # those two one by one, in batch order; the two others together
+        assert each == [(link, sent[at]) for at, link in sorted(
+            [(short_at, links[2]), (failed_at, links[3])])]
+        (whole, whole_bytes, whole_frames), = together
+        assert sorted(map(id, whole)) == sorted(map(id, links[:2]))
+        assert (whole_bytes, whole_frames) == ([size] * 2, [lane] * 2)
+        assert failed == [victim.public_key]
+        assert broker.connections.get_user_connection(
+            victim.public_key) is None
+        assert broker.connections.num_users == 3
+        moved = _moved(before, _egress_account(plane))
+        assert {k[1]: v for k, v in moved.items() if k[0] == "plane"} == {
+            "messages_routed": 3 * lane, "egress_inline": 3,
+            "egress_batched": 3, "egress_batched_short": 1}
+        assert moved[("bytes_sent", "tcp")] == 3 * size
+        assert plane.describe()["egress_batched_short"] == 1
+        # the next step: the two that read are settled together again;
+        # the stalled link is batched only if its transport could write
+        # the remainder at once, and then comes back short again
+        os.write(_socket_of(clients[0]), _wire(*second))
+        assert await _receive_all(clients[:2], lane) == [second] * 2
+        fds, nbytes, sent = calls[1]
+        assert victim_fd not in fds and len(fds) in (2, 3)
+        shorts = 1 + sum(n != s for n, s in zip(nbytes, sent))
+        assert plane.egress_batched_short == shorts == len(each) - 1
+        assert [len(t[0]) for t in together] == [2, 2]
+        stalled._connection._stream.reader._transport.resume_reading()
+        got, = await _receive_all([stalled], 2 * lane)
+        assert got == first + second
+        assert broker.connections.num_users == 3 and not plane.disabled
+
+
+@pytest.mark.parametrize("apart_by", ["ledger_peer", "transport_label"])
+async def test_links_that_are_accounted_apart_are_credited_apart(
+        apart_by, monkeypatch):
+    """The one pass credits once for each distinct (transport label,
+    ledger peer) among the whole sends: a link whose bytes count under
+    another transport's label, or whose frames the ledger books as
+    relayed to a peer, gets its own totals and the others theirs."""
+    from pushcdn_tpu.broker.device_plane import DevicePlaneConfig
+    from pushcdn_tpu.proto import flowclass
+    from pushcdn_tpu.proto import metrics as metrics_mod
+
+    payloads = [(b"frame %d|" % i).ljust(100 + i, b".") for i in range(_LANE)]
+    calls = _record_batches(monkeypatch)
+    async with _served_over_tcp(
+            3820, DevicePlaneConfig(**_BATCH_PLANE), [{0}] * 4) as (
+                broker, clients):
+        plane = broker.device_plane
+        odd = broker.connections.get_user_connection(clients[1].public_key)
+        if apart_by == "ledger_peer":
+            odd.ledger_peer = "a-peer"
+        else:
+            odd._m_sent = metrics_mod.BYTES_SENT.labels(transport="other")
+        before = _egress_account(plane)
+        os.write(_socket_of(clients[0]), _wire(*payloads))
+        assert await _receive_all(clients, _LANE) == [payloads] * 4
+        moved = _moved(before, _egress_account(plane))
+    (fds, nbytes, sent), = calls
+    assert len(fds) == 4 and sent == nbytes
+    size, live = len(_wire(*payloads)), flowclass.LIVE
+    want = {("class_bytes_out", live): 4 * size,
+            ("class_frames_out", live): 4 * _LANE,
+            ("queued", live): 4 * _LANE,
+            ("plane", "messages_routed"): 4 * _LANE,
+            ("plane", "egress_inline"): 4, ("plane", "egress_batched"): 4}
+    if apart_by == "ledger_peer":
+        want.update({("bytes_sent", "tcp"): 4 * size,
+                     ("fate", "delivered", "egress", live): 3 * _LANE,
+                     ("fate", "relayed", "mesh", live): _LANE})
+    else:
+        want.update({("bytes_sent", "tcp"): 3 * size,
+                     ("bytes_sent", "other"): size,
+                     ("fate", "delivered", "egress", live): 4 * _LANE})
+    assert moved == want

@@ -16,9 +16,15 @@ from pushcdn_tpu.proto.transport import Memory, Tcp
 from pushcdn_tpu.testing.mesh_cluster import MeshCluster
 from tests.test_device_plane import (
     _broker_fd,
+    _egress_account,
+    _moved,
+    _never_whole,
     _receive_all,
     _record_batches,
+    _shut_down_before_the_batch,
     _socket_of,
+    _stall_reader,
+    _watch_settling,
     _wire,
 )
 from tests.test_integration import wait_until
@@ -784,3 +790,119 @@ async def test_memory_users_of_a_back_pressured_tick_are_never_batched(
         assert (group.egress_inline, group.egress_queued) == (0, 8)
         assert cluster.brokers[3].device_plane.describe()[
             "egress_batched"] == 0
+
+
+# ---------------------------------------------------------------------------
+# the batch's settling (ISSUE 38; ``DevicePlane``'s twin:
+# tests/test_device_plane.py, "the batch's settling"): the group hands
+# ``senders.egress_streams`` its members in a broker's place, so the one pass
+# settles links that live on four brokers, and tallies on the group.
+# ---------------------------------------------------------------------------
+
+async def _settled_ticks(monkeypatch, per_link: bool):
+    """Three back-pressured ticks (a full base ring of broadcasts to all
+    eight users, then a full base bucket of directs to one, then both)
+    through a four-shard group whose users keep up; what
+    ``_egress_account`` moved by, and the ``sent_on_fd`` calls made."""
+    from pushcdn_tpu.proto import ledger as ledger_mod
+
+    one_by_one, _ = _watch_settling(monkeypatch)
+    calls = _record_batches(monkeypatch, after=_never_whole) if per_link \
+        else _record_batches(monkeypatch)
+    broadcasts = [(b"b%d|" % i).ljust(40 + 7 * i, b".") for i in range(_RING)]
+    directs = [(b"d%d|" % i).ljust(30 + 5 * i, b".") for i in range(_BUCKET)]
+    async with _served_group() as (cluster, clients):
+        group = cluster.group
+        publisher, recipient = clients[0], clients[5]   # shards 0 and 2
+        before = _egress_account(group)
+        os.write(_socket_of(publisher), _wire(*broadcasts))
+        assert await _receive_all(clients, _RING) == [broadcasts] * 8
+        os.write(_socket_of(publisher),
+                 _wire(*directs, to=recipient.public_key))
+        assert await _receive_all([recipient], _BUCKET) == [directs]
+        os.write(_socket_of(publisher), _wire(*broadcasts)
+                 + _wire(*directs, to=recipient.public_key))
+        got = await _receive_all(clients, _RING)
+        assert [g[:_RING] for g in got] == [broadcasts] * 8
+        await wait_until(lambda: group.messages_routed
+                         == 2 * (8 * _RING + _BUCKET))
+        assert group.steps == 3 and group.egress_queued == 0
+        moved = _moved(before, _egress_account(group))
+        book = ledger_mod.LEDGER
+        assert book.walk_live_queues() == 0
+        assert book.derived_in_queue() == [0] * len(book.queued)
+        assert [b.device_plane.describe()["egress_batched_short"]
+                for b in cluster.brokers] == [group.egress_batched_short] * 4
+    assert all(sent == nbytes for _, nbytes, sent in calls)
+    return moved, len(one_by_one), sum(len(fds) for fds, _, _ in calls)
+
+
+async def test_the_groups_one_pass_leaves_what_settling_each_link_leaves(
+        monkeypatch):
+    from pushcdn_tpu.proto import flowclass
+
+    bulk, bulk_calls, handoffs = await _settled_ticks(monkeypatch,
+                                                      per_link=False)
+    with monkeypatch.context() as patched:
+        each, each_calls, same = await _settled_ticks(patched, per_link=True)
+    assert handoffs == same == 2 * (8 + 1)
+    assert (bulk_calls, each_calls) == (0, handoffs)
+    assert bulk.pop(("plane", "egress_batched_short"), 0) == 0
+    assert each.pop(("plane", "egress_batched_short")) == handoffs
+    assert bulk == each
+    frames, live = 2 * (8 * _RING + _BUCKET), flowclass.LIVE
+    assert {k: v for k, v in bulk.items()
+            if k[0] not in ("bytes_sent", "class_bytes_out")} == {
+        ("class_frames_out", live): frames, ("queued", live): frames,
+        ("fate", "delivered", "egress", live): frames,
+        ("plane", "messages_routed"): frames,
+        ("plane", "egress_inline"): handoffs,
+        ("plane", "egress_batched"): handoffs}
+    assert bulk[("bytes_sent", "tcp")] == bulk[("class_bytes_out", live)] > 0
+
+
+async def test_the_groups_mixed_batch_settles_the_rest_each_on_its_member(
+        monkeypatch):
+    """One job over the four shards with a reader that has stopped (a
+    short send, shard 1) and a peer that is gone (``EPIPE``, shard 2):
+    those two are settled by ``sent_on_fd`` on the member that holds
+    each, the six others in one pass; ``egress_batched_short`` counts the
+    short one, the failed user leaves its own broker only."""
+    import errno
+
+    lane = 48   # 48 KB a user a tick: over what a stalled link's buffers take
+    frames = [(b"f%d|" % i).ljust(1000, b".") for i in range(lane)]
+    gone = []
+    calls = _record_batches(monkeypatch, _shut_down_before_the_batch(gone))
+    each, together = _watch_settling(monkeypatch)
+    async with _served_group(ring_slots=lane) as (cluster, clients):
+        group = cluster.group
+        stalled, victim = clients[2], clients[5]    # shards 1 and 2
+        others = [c for c in clients if c not in (stalled, victim)]
+        links = {c: cluster.brokers[u // 2].connections.get_user_connection(
+            c.public_key) for u, c in enumerate(clients)}
+        _stall_reader(links[stalled], stalled)
+        stalled_fd = _broker_fd(cluster.brokers[1], stalled)
+        victim_fd = _broker_fd(cluster.brokers[2], victim)
+        gone.append(victim_fd)
+        os.write(_socket_of(clients[0]), _wire(*frames))
+        assert await _receive_all(others, lane) == [frames] * 6
+        (fds, nbytes, sent), = calls
+        assert len(fds) == 8
+        short_at, failed_at = fds.index(stalled_fd), fds.index(victim_fd)
+        assert sent[failed_at] == -errno.EPIPE
+        assert 0 <= sent[short_at] < nbytes[short_at] \
+            or sent[short_at] == -errno.EAGAIN
+        assert each == [(link, sent[at]) for at, link in sorted(
+            [(short_at, links[stalled]), (failed_at, links[victim])])]
+        (whole, _, whole_frames), = together
+        assert sorted(map(id, whole)) == sorted(id(links[c]) for c in others)
+        assert whole_frames == [lane] * 6
+        assert (group.egress_batched, group.egress_batched_short,
+                group.egress_inline, group.messages_routed) == \
+            (7, 1, 7, 7 * lane)
+        assert [b.connections.num_users for b in cluster.brokers] == \
+            [2, 2, 1, 2]
+        stalled._connection._stream.reader._transport.resume_reading()
+        got, = await _receive_all([stalled], lane)
+        assert got == frames and not group.disabled

@@ -110,7 +110,7 @@ async def test_traced_steps_yield_flat_joined_conserving_spans(
         staged0, routed0, steps0 = (plane.frames_staged,
                                     plane.messages_routed, plane.steps)
         handoffs0 = (plane.egress_inline, plane.egress_queued,
-                     plane.egress_batched)
+                     plane.egress_batched, plane.egress_batched_short)
         drained0 = getattr(plane, "frames_drained", 0)  # no group has it
         described0 = cluster.brokers[0].device_plane.describe()
         t0_ns = time.monotonic_ns()
@@ -136,6 +136,7 @@ async def test_traced_steps_yield_flat_joined_conserving_spans(
         inline, queued = (plane.egress_inline - handoffs0[0],
                           plane.egress_queued - handoffs0[1])
         batched = plane.egress_batched - handoffs0[2]
+        short = plane.egress_batched_short - handoffs0[3]
         drained = getattr(plane, "frames_drained", 0) - drained0
     finally:
         client.close()
@@ -198,7 +199,7 @@ async def test_traced_steps_yield_flat_joined_conserving_spans(
     waits = [e[3]["ring_wait_us"] for e in events if e[0] == "plane.take"]
     assert all(0 <= w < trace_ns / 1e3 for w in waits), waits
     _drained_conserves(events, drained)
-    _batched_conserves(events, batched)
+    _batched_conserves(events, batched, short)
     _uploads_conserve(events, described0, described)
     _account_conserves(events, described0, described, elapsed_us)
 
@@ -263,12 +264,16 @@ def _uploads_conserve(events, before: dict, after: dict) -> None:
     assert len({st["bytes"] for st in h2d}) <= 2  # full and sliced shapes
 
 
-def _batched_conserves(events, batched: int) -> None:
+def _batched_conserves(events, batched: int, short: int) -> None:
     """``plane.egress``'s ``batched`` (hand-offs one native call sent)
-    sums to the plane's ``egress_batched`` and is part of ``inline``."""
+    sums to the plane's ``egress_batched`` and is part of ``inline``; its
+    ``short`` (of those, the ones settled one by one) sums to
+    ``egress_batched_short`` and is part of ``batched``."""
     egresses = [e[3] for e in events if e[0] == "plane.egress"]
-    assert all(0 <= g["batched"] <= g["inline"] for g in egresses), egresses
+    assert all(0 <= g["short"] <= g["batched"] <= g["inline"]
+               for g in egresses), egresses
     assert sum(g["batched"] for g in egresses) == batched
+    assert sum(g["short"] for g in egresses) == short
 
 
 def _drained_conserves(events, drained: int) -> None:
@@ -331,26 +336,33 @@ async def test_traced_takes_report_what_the_drain_staged(
         (1, 0), (1, 1), (1, 0), (2, 2), (1, 0), (3, 3)]
 
 
+@pytest.mark.parametrize("settled", ["together", "each"])
 @pytest.mark.parametrize("deploy", ["device_plane", "mesh_group"])
 async def test_traced_egress_reports_what_the_native_batch_sent(
-        deploy, tmp_path):
+        deploy, settled, tmp_path, monkeypatch):
     """Over real TCP links a step whose take found the base lane full
     sends its streams in one native batch (the group's tick: one a
     shard), and ``plane.egress`` says how many: all of that step's
-    hand-offs, none of a step with room left."""
+    hand-offs, none of a step with room left; and how many of them were
+    ``short``, settled one by one: none where every send took its whole
+    stream, all of them where the comparison that says so finds none."""
     import jax
 
     from pushcdn_tpu.broker.device_plane import DevicePlaneConfig
     from pushcdn_tpu.parallel import spans
     from tests.test_device_plane import (
         _SMALL_PLANE,
+        _never_whole,
         _receive_all,
+        _record_batches,
         _served_over_tcp,
         _socket_of,
         _wire,
     )
     from tests.test_mesh_group import _RING, _served_group
     spans.bind()
+    if settled == "each":
+        _record_batches(monkeypatch, after=_never_whole)
     if deploy == "device_plane":
         lane, users = _SMALL_PLANE["ring_slots"], 2
         served = _served_over_tcp(
@@ -377,16 +389,21 @@ async def test_traced_egress_reports_what_the_native_batch_sent(
                 assert got == [frames] * users
         finally:
             jax.profiler.stop_trace()
-        batched, described = plane.egress_batched, facade.describe()
+        batched, short = plane.egress_batched, plane.egress_batched_short
+        described = facade.describe()
     assert batched == described["egress_batched"] == 2 * users
+    assert short == described["egress_batched_short"] == \
+        (batched if settled == "each" else 0)
     threads, _ = _program_spans(str(tmp_path))
     events = [e for evs in threads.values() for e in evs]
-    _batched_conserves(events, batched)
+    _batched_conserves(events, batched, short)
     egresses = sorted((e for e in events if e[0] == "plane.egress"),
                       key=lambda e: e[1])
     assert [(g[3]["inline"], g[3]["queued"], g[3]["batched"])
             for g in egresses] == [
                 (users, 0, users), (users, 0, 0), (users, 0, users)]
+    assert [g[3]["short"] for g in egresses] == [
+        g[3]["batched"] if settled == "each" else 0 for g in egresses]
 
 
 # ---- the loop's side of the window (ISSUE 37) ------------------------------
