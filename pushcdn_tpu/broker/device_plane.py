@@ -283,6 +283,15 @@ class DevicePlane:
         # of the batched ones, those whose send() came back short and
         # were settled one by one; the rest were settled in one pass
         self.egress_batched_short = 0
+        # of inline + queued, those over a link whose stream encrypts
+        # above its socket (a user on TCP+TLS); of those, the ones the
+        # pump wrote itself; and what those inline writes took, record
+        # layer and ``send()`` included, in ns of ``time.monotonic_ns()``
+        # (``egress_tls_write_us`` in ``describe()``). All three stay 0
+        # with plain users (senders.try_send_encoded_to_user_nowait)
+        self.egress_tls = 0
+        self.egress_tls_inline = 0
+        self.egress_tls_write_ns = 0
         self.warmup_s: Optional[float] = None
 
     # ---- user lifecycle (Connections observer; event-loop only) ----------
@@ -591,6 +600,9 @@ class DevicePlane:
             "egress_queued": self.egress_queued,
             "egress_batched": self.egress_batched,
             "egress_batched_short": self.egress_batched_short,
+            "egress_tls": self.egress_tls,
+            "egress_tls_inline": self.egress_tls_inline,
+            "egress_tls_write_us": self.egress_tls_write_ns // 1000,
             "mirrored_users": len(self.slots),
             "unmirrored_users": len(self._unmirrored),
             "user_slots": self.user_slots,
@@ -765,10 +777,10 @@ class DevicePlane:
                 account.enter("egress")
                 gate.stepped(loop.time())
                 with spans.span("plane.egress", step=step) as sp:
-                    routed, inline, queued, batched, short = (
+                    routed, inline, queued, batched, short, tls = (
                         self.messages_routed, self.egress_inline,
                         self.egress_queued, self.egress_batched,
-                        self.egress_batched_short)
+                        self.egress_batched_short, self.egress_tls)
                     for streams, d2, lengths, frames in jobs:
                         if streams is not None:
                             egress_streams(self, self.broker, streams,
@@ -780,7 +792,8 @@ class DevicePlane:
                         inline=self.egress_inline - inline,
                         queued=self.egress_queued - queued,
                         batched=self.egress_batched - batched,
-                        short=self.egress_batched_short - short)
+                        short=self.egress_batched_short - short,
+                        tls=self.egress_tls - tls)
                 # the pump's own ``send()``s held the loop: drain the
                 # sockets before the next take. Streams that were all
                 # queued for their writers were no such hold (the loop

@@ -194,6 +194,9 @@ class MeshShardPlane:
             "egress_queued": g.egress_queued,
             "egress_batched": g.egress_batched,
             "egress_batched_short": g.egress_batched_short,
+            "egress_tls": g.egress_tls,
+            "egress_tls_inline": g.egress_tls_inline,
+            "egress_tls_write_us": g.egress_tls_write_ns // 1000,
             "h2d_puts": g.h2d_puts, "h2d_bytes": g.h2d_bytes,
             "stage_full_results": g.stage_full_results,
             "stage_full_frames": g.stage_full_frames,
@@ -346,6 +349,11 @@ class MeshBrokerGroup:
         self.egress_queued = 0
         self.egress_batched = 0  # of the inline ones: by the native batch
         self.egress_batched_short = 0  # of those: settled one by one
+        # of inline + queued: over a link whose stream encrypts (TCP+TLS);
+        # of those: written by the pump; what those writes took, in ns
+        self.egress_tls = 0
+        self.egress_tls_inline = 0
+        self.egress_tls_write_ns = 0
         self._members = _Members(self)
         # collectives traced by the most recently COMPILED step
         # specialization (router.trace_collectives delta around the call):
@@ -743,10 +751,10 @@ class MeshBrokerGroup:
                 account.enter("egress")
                 gate.stepped(loop.time())
                 with spans.span("plane.egress", step=step) as sp:
-                    routed, inline, queued, batched, short = (
+                    routed, inline, queued, batched, short, tls = (
                         self.messages_routed, self.egress_inline,
                         self.egress_queued, self.egress_batched,
-                        self.egress_batched_short)
+                        self.egress_batched_short, self.egress_tls)
                     for streams, d2, lengths, frames in egress_jobs:
                         if streams is not None:
                             egress_streams(self, self._members, streams,
@@ -759,7 +767,8 @@ class MeshBrokerGroup:
                         inline=self.egress_inline - inline,
                         queued=self.egress_queued - queued,
                         batched=self.egress_batched - batched,
-                        short=self.egress_batched_short - short)
+                        short=self.egress_batched_short - short,
+                        tls=self.egress_tls - tls)
             except asyncio.CancelledError:
                 raise
             except Exception:

@@ -136,8 +136,8 @@ def try_send_frames_to_user_nowait(broker: "Broker", public_key: bytes,
         return 0
 
 
-def try_send_encoded_to_user_nowait(broker: "Broker", public_key: bytes,
-                                    data, owner=None,
+def try_send_encoded_to_user_nowait(plane, broker: "Broker",
+                                    public_key: bytes, data, owner=None,
                                     nframes: int = 0) -> int:
     """Hand a pre-framed egress stream (native.egress_encode output) to
     one user — zero per-frame work here or in the writer. An idle link
@@ -148,18 +148,35 @@ def try_send_encoded_to_user_nowait(broker: "Broker", public_key: bytes,
     the user (failure-is-removal, as everywhere). ``owner`` keeps a
     pooled egress buffer alive until a queued flush completes.
     ``nframes`` feeds the class accounting (the stream itself is
-    opaque)."""
+    opaque).
+
+    A link whose stream encrypts above its socket (a user on TCP+TLS:
+    ``Connection.encrypts``) is tallied on ``plane`` besides:
+    ``egress_tls`` a hand-off, ``egress_tls_inline`` one the caller
+    wrote itself, and ``egress_tls_write_ns``, the clock around that
+    inline call: the link's checks, ``write_nowait`` (the ``bytes()``
+    copy of the pooled buffer, the record layer, the transport's
+    ``send()``) and its accounting. A write that went to the writer
+    task is timed there (``writer_write_us``), one that failed nowhere;
+    a plain link reads the attribute and no clock."""
     connection = broker.connections.get_user_connection(public_key)
     if connection is None:
         return 0
+    encrypts = connection.encrypts
+    t0 = time.monotonic_ns() if encrypts else 0
     try:
-        if connection.try_send_encoded_inline(data, nframes=nframes):
-            return INLINE
-        connection.send_encoded_nowait(data, owner, nframes=nframes)
-        return QUEUED
+        inline = connection.try_send_encoded_inline(data, nframes=nframes)
+        if not inline:
+            connection.send_encoded_nowait(data, owner, nframes=nframes)
     except Exception as exc:
         _send_failed(broker, public_key, connection, exc)
         return 0
+    if encrypts:
+        plane.egress_tls += 1
+        if inline:  # nothing ran between the inline call and this clock
+            plane.egress_tls_inline += 1
+            plane.egress_tls_write_ns += time.monotonic_ns() - t0
+    return INLINE if inline else QUEUED
 
 
 def _send_failed(broker: "Broker", public_key: bytes, connection,
@@ -243,7 +260,9 @@ def egress_streams(plane, broker: "Broker", streams,
     """Deliver one step's native egress (:class:`native.EgressStreams`):
     one pre-framed stream hand-off per user with deliveries, tallied on
     ``plane`` (a ``DevicePlane`` or a broker group): ``messages_routed``,
-    and how each hand-off went, ``egress_inline`` or ``egress_queued``.
+    and how each hand-off went, ``egress_inline`` or ``egress_queued``
+    (of both, ``egress_tls`` over a link that encrypts:
+    :func:`try_send_encoded_to_user_nowait`).
 
     ``back_pressured`` is the pump's observation that the step's take
     found the base lane full: its publishers wait on the step, so the
@@ -266,7 +285,7 @@ def egress_streams(plane, broker: "Broker", streams,
             continue
         nframes = int(streams.msgs[slot])
         how = try_send_encoded_to_user_nowait(
-            broker, key, streams.stream(slot), owner=streams,
+            plane, broker, key, streams.stream(slot), owner=streams,
             nframes=nframes)
         if how:
             plane.messages_routed += nframes

@@ -61,7 +61,10 @@ The spans (the names are what ``benchmark/span_reduce.py`` reads):
                                   ``inline``, sent by one native call: a
                                   step or tick that was back-pressured),
                                   ``short`` (of ``batched``, settled one by
-                                  one after a short send; the rest in one pass)
+                                  one after a short send; the rest in one pass),
+                                  ``tls`` (of ``inline`` + ``queued``, those
+                                  over a stream that encrypts above its
+                                  socket: a user on TCP+TLS)
 ====================  ==========  ===========================================
 
 ``step`` is the plane's own step number; the two thread hops of a step
@@ -101,6 +104,21 @@ the deployment's. Beside the older ones (``steps``, ``frames_staged``,
 ``writer_write_us``,        transport's synchronous ``write``, never the
 ``writer_write_bytes``      drain, never ``write_nowait``);
                             ``writer_us_per_write``
+``egress_tls``,             ``senders.try_send_encoded_to_user_nowait``,
+``egress_tls_inline``,      the one-by-one hand-off of ``egress_streams``,
+``egress_tls_write_us``     only on a link whose stream encrypts above
+                            its socket (``RawStream.encrypts``: users on
+                            TCP+TLS, which the native batch cannot take):
+                            such hand-offs, inline or queued; of those,
+                            the ones the pump wrote itself; and the clock
+                            around those inline calls (the link's checks,
+                            ``write_nowait``: the ``bytes()`` copy, the
+                            record layer, the ``send()``), which lies
+                            inside ``pump_egress_us``. A queued one's
+                            write is ``writer_write_us``'s. 0 with plain
+                            users, who cost one attribute read and no
+                            clock; ``tls_write_us_per_handoff``,
+                            ``tls_write_share``
 ``loop_lag_us``,            ``proto/metrics.py:_loop_lag_sampler``, a
 ``loop_lag_samples``        sample a 0.25 s; None where no sampler runs;
                             ``loop_lag_ms``

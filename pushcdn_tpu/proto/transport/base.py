@@ -203,6 +203,14 @@ class RawStream(abc.ABC):
     # kernel is done with the bytes (io_uring zero-copy deferral)
     wants_owner = False
 
+    # True on a stream that encrypts above its socket (TLS), said once
+    # where the stream is built: what the socket carries are records,
+    # never the stream's bytes, so :meth:`idle_fd` gives no descriptor,
+    # and each :meth:`write_nowait` pays for the record layer on the
+    # caller's task (the device plane's egress counts and times those
+    # hand-offs apart: ``senders.egress_streams``)
+    encrypts = False
+
     @abc.abstractmethod
     async def read_exactly(self, n: int) -> bytes: ...
 
@@ -254,9 +262,11 @@ class RawStream(abc.ABC):
 class AsyncioStream(RawStream):
     """RawStream over an asyncio (StreamReader, StreamWriter) pair."""
 
-    def __init__(self, reader: asyncio.StreamReader, writer: asyncio.StreamWriter):
+    def __init__(self, reader: asyncio.StreamReader,
+                 writer: asyncio.StreamWriter, encrypts: bool = False):
         self.reader = reader
         self.writer = writer
+        self.encrypts = encrypts  # the pair sits on a TLS transport
 
     async def read_exactly(self, n: int) -> bytes:
         return await self.reader.readexactly(n)
@@ -298,9 +308,10 @@ class AsyncioStream(RawStream):
         # sent on the fd must not pass bytes the transport still holds.
         # A TLS transport sits on a socket too, but what that socket
         # carries are records, never these bytes
+        if self.encrypts:
+            return None
         transport = self.writer.transport
-        if transport.is_closing() or transport.get_write_buffer_size() \
-                or transport.get_extra_info("sslcontext") is not None:
+        if transport.is_closing() or transport.get_write_buffer_size():
             return None
         sock = transport.get_extra_info("socket")
         fd = -1 if sock is None else sock.fileno()
@@ -352,6 +363,10 @@ class Connection:
         # the flush path so zero-copy sends can defer its release until
         # the kernel's completion notification
         self._owner_write = bool(getattr(stream, "wants_owner", False))
+        # whether the stream encrypts above its socket
+        # (:attr:`RawStream.encrypts`): no :meth:`idle_fd` then, and every
+        # inline write pays for the record layer on the caller's task
+        self.encrypts = bool(getattr(stream, "encrypts", False))
         # per-transport byte accounting: the label's prefix is the
         # transport name ("tcp:host:port" → "tcp"); the labeled children
         # are cached here so the hot path pays one plain inc per flush

@@ -43,8 +43,9 @@ class _TlsUnfinalized(UnfinalizedConnection):
                 sock.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
             except OSError:
                 pass
-        return Connection(AsyncioStream(self._reader, self._writer), limiter,
-                          label="tcp+tls")
+        return Connection(
+            AsyncioStream(self._reader, self._writer, encrypts=True),
+            limiter, label="tcp+tls")
 
 
 class TcpTlsListener(Listener):
@@ -99,8 +100,8 @@ class TcpTls(Protocol):
                     host, port, ssl=ctx, server_hostname=server_hostname)
         except (OSError, ssl.SSLError, asyncio.TimeoutError) as exc:
             bail(ErrorKind.CONNECTION, f"tls connect to {endpoint} failed", exc)
-        return Connection(AsyncioStream(reader, writer), limiter,
-                          label=f"tcp+tls:{endpoint}")
+        return Connection(AsyncioStream(reader, writer, encrypts=True),
+                          limiter, label=f"tcp+tls:{endpoint}")
 
     @classmethod
     async def bind(cls, endpoint: str,

@@ -26,6 +26,8 @@ _CHUNK = 256 * 1024
 class TlsStream(RawStream):
     """A ``RawStream`` carrying TLS records over an inner ``RawStream``."""
 
+    encrypts = True
+
     def __init__(self, inner: RawStream, ssl_object: ssl.SSLObject,
                  incoming: ssl.MemoryBIO, outgoing: ssl.MemoryBIO):
         self._inner = inner

@@ -384,10 +384,12 @@ def _mixed_traffic(seed: int):
 
 
 @contextlib.asynccontextmanager
-async def _served_over_tcp(seed: int, device_plane, topics, protocol=None):
+async def _served_over_tcp(seed: int, device_plane, topics, protocol=None,
+                           topic_space=None):
     """One broker (with ``device_plane``, or the plain host router) and a
     marshal, with one connected client per entry of ``topics`` over real
-    TCP user links (or ``protocol``'s): ``(broker, clients)``."""
+    TCP user links (or ``protocol``'s): ``(broker, clients)``. The topics
+    are the testing run definition's two unless ``topic_space`` says."""
     import tempfile
 
     from pushcdn_tpu.bin.common import free_ports
@@ -400,7 +402,7 @@ async def _served_over_tcp(seed: int, device_plane, topics, protocol=None):
     from pushcdn_tpu.proto.transport import Tcp
 
     protocol = protocol or Tcp
-    run_def = testing_run_def(user_protocol=protocol)
+    run_def = testing_run_def(user_protocol=protocol, topics=topic_space)
     db = os.path.join(tempfile.mkdtemp(prefix="pushcdn-diff-"), "d.sqlite")
     pub, marshal_port = free_ports(2)
     tag = f"diff-{seed}-{'dev' if device_plane else 'host'}"

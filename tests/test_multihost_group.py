@@ -61,6 +61,8 @@ async def test_single_process_group_routes_and_directory(tmp_path):
     assert group.local_shards == [0, 1, 2, 3]
 
     class FakeUserConnection:
+        encrypts = False  # a plain link: no clock, no ``egress_tls``
+
         def __init__(self):
             self.streams = []
 
