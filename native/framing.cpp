@@ -21,6 +21,36 @@
 #include <thread>
 #include <vector>
 
+namespace {
+
+// The body of the two send_batch entry points: entry i's bytes start at
+// src(i); see pushcdn_send_batch.
+template <class Src>
+void send_entries(Src src, const int32_t* fds, const int64_t* nbytes,
+                  int32_t n, int32_t threads, int64_t* out) {
+  std::atomic<int32_t> next{0};
+  auto work = [&] {
+    for (int32_t i; (i = next.fetch_add(1, std::memory_order_relaxed)) < n;) {
+      ssize_t r;
+      do {
+        r = send(fds[i], src(i), (size_t)nbytes[i],
+                 MSG_DONTWAIT | MSG_NOSIGNAL);
+      } while (r < 0 && errno == EINTR);
+      out[i] = r < 0 ? -(int64_t)errno : (int64_t)r;
+    }
+  };
+  std::vector<std::thread> pool;
+  try {
+    for (int32_t k = 1; k < threads && k < n; ++k) pool.emplace_back(work);
+  } catch (...) {
+    // no thread to be had: the ones there are, and the caller, do it all
+  }
+  work();
+  for (auto& t : pool) t.join();
+}
+
+}  // namespace
+
 extern "C" {
 
 // Pack n variable-length payloads (concatenated in `blob`, located by
@@ -312,25 +342,17 @@ int64_t pushcdn_egress_fill(
 void pushcdn_send_batch(
     const uint8_t* buf, const int32_t* fds, const int64_t* offsets,
     const int64_t* nbytes, int32_t n, int32_t threads, int64_t* out) {
-  std::atomic<int32_t> next{0};
-  auto work = [&] {
-    for (int32_t i; (i = next.fetch_add(1, std::memory_order_relaxed)) < n;) {
-      ssize_t r;
-      do {
-        r = send(fds[i], buf + offsets[i], (size_t)nbytes[i],
-                 MSG_DONTWAIT | MSG_NOSIGNAL);
-      } while (r < 0 && errno == EINTR);
-      out[i] = r < 0 ? -(int64_t)errno : (int64_t)r;
-    }
-  };
-  std::vector<std::thread> pool;
-  try {
-    for (int32_t k = 1; k < threads && k < n; ++k) pool.emplace_back(work);
-  } catch (...) {
-    // no thread to be had: the ones there are, and the caller, do it all
-  }
-  work();
-  for (auto& t : pool) t.join();
+  send_entries([=](int32_t i) { return buf + offsets[i]; }, fds, nbytes, n,
+               threads, out);
+}
+
+// pushcdn_send_batch for entries that each own their bytes: entry i
+// sends bufs[i][0, nbytes[i]) (a TLS link's sealed records).
+void pushcdn_send_batch_ptrs(
+    const uint8_t* const* bufs, const int32_t* fds, const int64_t* nbytes,
+    int32_t n, int32_t threads, int64_t* out) {
+  send_entries([=](int32_t i) { return bufs[i]; }, fds, nbytes, n, threads,
+               out);
 }
 
 }  // extern "C"

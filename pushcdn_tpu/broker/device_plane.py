@@ -285,13 +285,20 @@ class DevicePlane:
         self.egress_batched_short = 0
         # of inline + queued, those over a link whose stream encrypts
         # above its socket (a user on TCP+TLS); of those, the ones the
-        # pump wrote itself; and what those inline writes took, record
-        # layer and ``send()`` included, in ns of ``time.monotonic_ns()``
-        # (``egress_tls_write_us`` in ``describe()``). All three stay 0
-        # with plain users (senders.try_send_encoded_to_user_nowait)
+        # pump wrote itself; and what the loop did for those inline
+        # writes, in ns of ``time.monotonic_ns()`` (``egress_tls_write_us``
+        # in ``describe()``): one by one the record layer and the
+        # ``send()``, in the native batch the seal alone. All four stay 0
+        # with plain users (senders.try_send_encoded_to_user_nowait,
+        # senders._egress_batched)
         self.egress_tls = 0
         self.egress_tls_inline = 0
         self.egress_tls_write_ns = 0
+        # of the inline ones, those one native call sent with the records
+        # the link's own record layer sealed on the loop: a back-pressured
+        # step's (senders._egress_batched; ``egress_batched`` counts the
+        # plain links' alone). Their inline writes above are those seals
+        self.egress_tls_batched = 0
         self.warmup_s: Optional[float] = None
 
     # ---- user lifecycle (Connections observer; event-loop only) ----------
@@ -603,6 +610,7 @@ class DevicePlane:
             "egress_tls": self.egress_tls,
             "egress_tls_inline": self.egress_tls_inline,
             "egress_tls_write_us": self.egress_tls_write_ns // 1000,
+            "egress_tls_batched": self.egress_tls_batched,
             "mirrored_users": len(self.slots),
             "unmirrored_users": len(self._unmirrored),
             "user_slots": self.user_slots,
@@ -777,10 +785,12 @@ class DevicePlane:
                 account.enter("egress")
                 gate.stepped(loop.time())
                 with spans.span("plane.egress", step=step) as sp:
-                    routed, inline, queued, batched, short, tls = (
-                        self.messages_routed, self.egress_inline,
-                        self.egress_queued, self.egress_batched,
-                        self.egress_batched_short, self.egress_tls)
+                    routed, inline, queued, batched, short, tls, \
+                        tls_batched = (
+                            self.messages_routed, self.egress_inline,
+                            self.egress_queued, self.egress_batched,
+                            self.egress_batched_short, self.egress_tls,
+                            self.egress_tls_batched)
                     for streams, d2, lengths, frames in jobs:
                         if streams is not None:
                             egress_streams(self, self.broker, streams,
@@ -793,7 +803,8 @@ class DevicePlane:
                         queued=self.egress_queued - queued,
                         batched=self.egress_batched - batched,
                         short=self.egress_batched_short - short,
-                        tls=self.egress_tls - tls)
+                        tls=self.egress_tls - tls,
+                        tls_batched=self.egress_tls_batched - tls_batched)
                 # the pump's own ``send()``s held the loop: drain the
                 # sockets before the next take. Streams that were all
                 # queued for their writers were no such hold (the loop

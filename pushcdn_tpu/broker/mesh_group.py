@@ -197,6 +197,7 @@ class MeshShardPlane:
             "egress_tls": g.egress_tls,
             "egress_tls_inline": g.egress_tls_inline,
             "egress_tls_write_us": g.egress_tls_write_ns // 1000,
+            "egress_tls_batched": g.egress_tls_batched,
             "h2d_puts": g.h2d_puts, "h2d_bytes": g.h2d_bytes,
             "stage_full_results": g.stage_full_results,
             "stage_full_frames": g.stage_full_frames,
@@ -354,6 +355,7 @@ class MeshBrokerGroup:
         self.egress_tls = 0
         self.egress_tls_inline = 0
         self.egress_tls_write_ns = 0
+        self.egress_tls_batched = 0  # of tls inline: by the native batch
         self._members = _Members(self)
         # collectives traced by the most recently COMPILED step
         # specialization (router.trace_collectives delta around the call):
@@ -751,10 +753,12 @@ class MeshBrokerGroup:
                 account.enter("egress")
                 gate.stepped(loop.time())
                 with spans.span("plane.egress", step=step) as sp:
-                    routed, inline, queued, batched, short, tls = (
-                        self.messages_routed, self.egress_inline,
-                        self.egress_queued, self.egress_batched,
-                        self.egress_batched_short, self.egress_tls)
+                    routed, inline, queued, batched, short, tls, \
+                        tls_batched = (
+                            self.messages_routed, self.egress_inline,
+                            self.egress_queued, self.egress_batched,
+                            self.egress_batched_short, self.egress_tls,
+                            self.egress_tls_batched)
                     for streams, d2, lengths, frames in egress_jobs:
                         if streams is not None:
                             egress_streams(self, self._members, streams,
@@ -768,7 +772,8 @@ class MeshBrokerGroup:
                         queued=self.egress_queued - queued,
                         batched=self.egress_batched - batched,
                         short=self.egress_batched_short - short,
-                        tls=self.egress_tls - tls)
+                        tls=self.egress_tls - tls,
+                        tls_batched=self.egress_tls_batched - tls_batched)
             except asyncio.CancelledError:
                 raise
             except Exception:

@@ -189,70 +189,136 @@ def _send_failed(broker: "Broker", public_key: bytes, connection,
 
 def _egress_batched(plane, broker: "Broker", streams) -> list:
     """Send the streams of one back-pressured step whose links are idle
-    plain sockets (``Connection.idle_fd``) by ONE native call
-    (``native.send_batch``: the sends fanned over a few threads, joined
-    before it returns), straight from the step's pooled buffer; the slots
-    it did not take, for the caller's loop. The event loop stands still
-    for the call, as it does for that loop's ``send()``s, so between a
-    link's check, its send and its settling nothing else can write to,
-    close or reuse its socket, and a user has one stream in ``streams``,
-    so one send: nothing can reorder.
+    by native calls (``native.send_batch``: the sends fanned over a few
+    threads, joined before it returns); the slots they did not take, for
+    the caller's loop. A plain link gives its socket
+    (``Connection.idle_fd``) and is sent straight from the step's pooled
+    buffer; a link whose stream encrypts gives it with its stream sealed
+    there and then by that link's own record layer
+    (``Connection.seal_idle``: a plain link pays the one attribute read),
+    and its records go in a second call. The event loop stands still
+    from the first check to the last settling, as it does for that
+    loop's ``send()``s, so nothing else can write to, close or reuse a
+    socket, or seal on its link, in between; a user has one stream in
+    ``streams``, so one send: nothing can reorder.
 
-    The sends that took their whole stream (all of them, where the
-    readers keep up) are settled here in one pass, not once a link:
-    the plane's tallies grow by their sums (a stream the pump wrote
-    itself, ``egress_inline``, and ``egress_batched``), and
-    ``Connection.sent_whole_on_fds`` credits the transport's byte count,
-    the class counters and the ledger's transit once with the totals.
-    Every other entry goes through ``Connection.sent_on_fd`` as a send
-    of its own, in batch order: a short one (``EAGAIN``: of no bytes),
-    whose remainder its transport holds from then on, is tallied like a
-    full one and in ``egress_batched_short``; any other errno removes
-    that user only, and is in no tally."""
+    The tallies: a plain link's hand-off counts in ``egress_inline`` and
+    ``egress_batched``; a sealed one in ``egress_inline``, ``egress_tls``,
+    ``egress_tls_inline`` and ``egress_tls_batched``, and its seal (from
+    the link's checks to the records) in ``egress_tls_write_ns``: what the
+    loop still does for it, where the one-by-one path times the whole
+    write (a seal whose send then failed is in that clock alone: the loop
+    did it). ``egress_batched`` stays the plain links' count, as the
+    benchmark's tests of a TLS deployment read it (``egress_batched == 0``
+    there)."""
     slots = plane.slots
     user_connection = broker.connections.get_user_connection
     users = streams.users
-    taken, keys, links, fds, rest = [], [], [], [], []
+    plain, sealed, rest, seal_ns = _Batch(), _Batch(), [], 0
+    # the plain links' appends as locals: theirs is the step's long loop
+    taken, keys, links, fds = plain.slots, plain.keys, plain.links, plain.fds
     for slot, size in zip(users, streams.nbytes[users].tolist()):
         key = slots.key_of(slot)
         connection = None if key is None else user_connection(key)
-        fd = None if connection is None else connection.idle_fd(size)
-        if fd is None:
+        if connection is None:
             rest.append(slot)
+        elif connection.encrypts:
+            t0 = time.monotonic_ns()
+            try:
+                got = connection.seal_idle(streams.stream(slot))
+            except Exception as exc:
+                _send_failed(broker, key, connection, exc)
+                continue
+            if got is None:
+                rest.append(slot)
+            else:
+                seal_ns += time.monotonic_ns() - t0
+                sealed.add(slot, key, connection, *got)
         else:
-            taken.append(slot)
-            keys.append(key)
-            links.append(connection)
-            fds.append(fd)
-    if not taken:
-        return rest
-    at = np.array(taken, np.int64)
+            fd = connection.idle_fd(size)
+            if fd is None:
+                rest.append(slot)
+            else:
+                taken.append(slot)
+                keys.append(key)
+                links.append(connection)
+                fds.append(fd)
+    if taken:
+        n, short = _send_and_settle(plane, broker, streams, plain)
+        plane.egress_batched += n
+        plane.egress_batched_short += short
+    if sealed.slots:
+        n, _short = _send_and_settle(plane, broker, streams, sealed)
+        plane.egress_tls += n
+        plane.egress_tls_inline += n
+        plane.egress_tls_batched += n
+        plane.egress_tls_write_ns += seal_ns
+    return rest
+
+
+class _Batch:
+    """One native call's entries, in batch order: a slot, its user's key,
+    connection and fd, and, for sealed links, the records (plain links
+    have none: they are sent from the step's buffer)."""
+
+    def __init__(self):
+        self.slots, self.keys, self.links, self.fds = [], [], [], []
+        self.records = []
+
+    def add(self, slot, key, link, fd, records):
+        self.slots.append(slot)
+        self.keys.append(key)
+        self.links.append(link)
+        self.fds.append(fd)
+        self.records.append(records)
+
+
+def _send_and_settle(plane, broker: "Broker", streams, batch) -> tuple:
+    """Send ``batch`` by one native call (the step's buffer, or the
+    sealed records) and settle it. The sends that took their whole
+    stream (all of them, where the readers keep up) are settled in one
+    pass, not once a link: ``Connection.sent_whole_on_fds`` credits the
+    transport's byte count, the class counters and the ledger's transit
+    once with the totals of the streams (a sealed link's plaintext, as
+    its one-by-one write is credited). Every other entry goes through
+    ``Connection.sent_on_fd`` as a send of its own, in batch order: a
+    short one (``EAGAIN``: of no bytes), whose remainder its transport
+    holds from then on, is settled like a whole one; any other errno
+    removes that user only. ``messages_routed`` and ``egress_inline``
+    grow by what was settled; the hand-offs settled, and the short ones
+    of them."""
+    at = np.array(batch.slots, np.int64)
     nbytes, nframes = streams.nbytes[at], streams.msgs[at]
-    sent = native_mod.send_batch(streams.buf, np.array(fds, np.int32),
-                                 streams.offsets[at], nbytes)
-    whole = sent == nbytes
+    fds = np.array(batch.fds, np.int32)
+    records = batch.records or None
+    if records is None:
+        wire = nbytes
+        sent = native_mod.send_batch(streams.buf, fds, streams.offsets[at],
+                                     nbytes)
+    else:
+        wire = np.fromiter(map(len, records), np.int64, len(records))
+        sent = native_mod.send_batch_each(records, fds)
+    whole = sent == wire
+    links, short = batch.links, 0
     for i in np.flatnonzero(~whole).tolist():
         try:
-            links[i].sent_on_fd(streams.stream(taken[i]), int(sent[i]),
-                                nframes=int(nframes[i]))
+            links[i].sent_on_fd(
+                streams.stream(batch.slots[i]), int(sent[i]),
+                nframes=int(nframes[i]),
+                records=None if records is None else records[i])
         except Exception as exc:
-            _send_failed(broker, keys[i], links[i], exc)
+            _send_failed(broker, batch.keys[i], links[i], exc)
             continue
         plane.messages_routed += int(nframes[i])
-        plane.egress_inline += 1
-        plane.egress_batched += 1
-        plane.egress_batched_short += 1
-    if whole.all():
-        settled = links
-    else:
-        settled = [links[i] for i in np.flatnonzero(whole).tolist()]
+        short += 1
+    if not whole.all():
+        links = [links[i] for i in np.flatnonzero(whole).tolist()]
         nbytes, nframes = nbytes[whole], nframes[whole]
-    if settled:
-        Connection.sent_whole_on_fds(settled, nbytes, nframes)
+    if links:
+        Connection.sent_whole_on_fds(links, nbytes, nframes)
         plane.messages_routed += int(nframes.sum())
-        plane.egress_inline += len(settled)
-        plane.egress_batched += len(settled)
-    return rest
+    plane.egress_inline += len(links) + short
+    return len(links) + short, short
 
 
 def egress_streams(plane, broker: "Broker", streams,
@@ -262,14 +328,15 @@ def egress_streams(plane, broker: "Broker", streams,
     ``plane`` (a ``DevicePlane`` or a broker group): ``messages_routed``,
     and how each hand-off went, ``egress_inline`` or ``egress_queued``
     (of both, ``egress_tls`` over a link that encrypts:
-    :func:`try_send_encoded_to_user_nowait`).
+    :func:`try_send_encoded_to_user_nowait`, :func:`_egress_batched`).
 
     ``back_pressured`` is the pump's observation that the step's take
     found the base lane full: its publishers wait on the step, so the
     next one carries as many frames and makes as many sends however
     short this one is, and the time of the sends is the rate. Only then
-    do the idle links' sends leave together over several threads
-    (:func:`_egress_batched`, which also accounts for them: the whole
+    do the idle links' sends leave together over several threads, a TLS
+    link's sealed on the loop first (:func:`_egress_batched`, which also
+    accounts for them: the whole
     sends of the batch in one pass, the plane's tallies by their sums);
     every other hand-off, and every one of a step that is not
     back-pressured (where shorter sends would buy a faster cadence of

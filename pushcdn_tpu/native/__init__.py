@@ -142,6 +142,10 @@ def _compile() -> Optional[ctypes.CDLL]:
     lib.pushcdn_send_batch.restype = None
     lib.pushcdn_send_batch.argtypes = [
         u8p, i32p, i64p, i64p, ctypes.c_int32, ctypes.c_int32, i64p]
+    lib.pushcdn_send_batch_ptrs.restype = None
+    lib.pushcdn_send_batch_ptrs.argtypes = [
+        ctypes.POINTER(ctypes.c_char_p), i32p, i64p, ctypes.c_int32,
+        ctypes.c_int32, i64p]
     return lib
 
 
@@ -663,6 +667,26 @@ def send_batch(buf, fds: np.ndarray, offsets: np.ndarray,
         _ptr(np.frombuffer(buf, np.uint8), ctypes.c_uint8),
         _ptr(fds, ctypes.c_int32), _ptr(offsets, ctypes.c_int64),
         _ptr(nbytes, ctypes.c_int64), n,
+        min(_SEND_THREADS, len(os.sched_getaffinity(0))),
+        _ptr(out, ctypes.c_int64))
+    return out
+
+
+def send_batch_each(bufs: list, fds: np.ndarray) -> np.ndarray:
+    """:func:`send_batch` for entries that each own their bytes: entry
+    ``i`` sends ``bufs[i]`` (a ``bytes``) on ``fds[i]``, with no copy into
+    a shared buffer; per entry the bytes the socket took, or ``-errno``.
+    The same threads and the same promises of the caller."""
+    n = len(fds)
+    fds = np.ascontiguousarray(fds, np.int32)
+    if len(bufs) != n or not all(type(b) is bytes for b in bufs):
+        raise ValueError("one bytes object an fd")
+    # the array holds each object and points at its bytes
+    ptrs = (ctypes.c_char_p * n)(*bufs)
+    nbytes = np.fromiter(map(len, bufs), np.int64, n)
+    out = np.empty(n, np.int64)
+    _get().pushcdn_send_batch_ptrs(
+        ptrs, _ptr(fds, ctypes.c_int32), _ptr(nbytes, ctypes.c_int64), n,
         min(_SEND_THREADS, len(os.sched_getaffinity(0))),
         _ptr(out, ctypes.c_int64))
     return out

@@ -64,7 +64,9 @@ The spans (the names are what ``benchmark/span_reduce.py`` reads):
                                   one after a short send; the rest in one pass),
                                   ``tls`` (of ``inline`` + ``queued``, those
                                   over a stream that encrypts above its
-                                  socket: a user on TCP+TLS)
+                                  socket: a user on TCP+TLS),
+                                  ``tls_batched`` (of ``tls``, sent sealed
+                                  by one native call; not in ``batched``)
 ====================  ==========  ===========================================
 
 ``step`` is the plane's own step number; the two thread hops of a step
@@ -106,18 +108,24 @@ the deployment's. Beside the older ones (``steps``, ``frames_staged``,
                             ``writer_us_per_write``
 ``egress_tls``,             ``senders.try_send_encoded_to_user_nowait``,
 ``egress_tls_inline``,      the one-by-one hand-off of ``egress_streams``,
-``egress_tls_write_us``     only on a link whose stream encrypts above
-                            its socket (``RawStream.encrypts``: users on
-                            TCP+TLS, which the native batch cannot take):
+``egress_tls_write_us``,    and ``senders._egress_batched``, only on a
+``egress_tls_batched``      link whose stream encrypts above its socket
+                            (``RawStream.encrypts``: users on TCP+TLS):
                             such hand-offs, inline or queued; of those,
-                            the ones the pump wrote itself; and the clock
-                            around those inline calls (the link's checks,
-                            ``write_nowait``: the ``bytes()`` copy, the
-                            record layer, the ``send()``), which lies
-                            inside ``pump_egress_us``. A queued one's
-                            write is ``writer_write_us``'s. 0 with plain
-                            users, who cost one attribute read and no
-                            clock; ``tls_write_us_per_handoff``,
+                            the ones the pump wrote itself; the clock
+                            around what the loop did for those (one by
+                            one: the link's checks, ``write_nowait``'s
+                            ``bytes()`` copy, record layer and
+                            ``send()``; in a back-pressured step's batch:
+                            the checks and the seal, from
+                            ``Connection.seal_idle``'s checks to the
+                            outgoing BIO's read, the ``send()`` being
+                            the native call's), which lies inside
+                            ``pump_egress_us``; and of the inline ones
+                            those the batch sent. A queued one's write is
+                            ``writer_write_us``'s. 0 with plain users,
+                            who cost one attribute read and no clock;
+                            ``tls_write_us_per_handoff``,
                             ``tls_write_share``
 ``loop_lag_us``,            ``proto/metrics.py:_loop_lag_sampler``, a
 ``loop_lag_samples``        sample a 0.25 s; None where no sampler runs;

@@ -29,6 +29,7 @@ import struct
 import time
 import weakref
 from collections import deque
+from asyncio import sslproto
 from typing import List, Optional
 
 from pushcdn_tpu import native
@@ -205,10 +206,11 @@ class RawStream(abc.ABC):
 
     # True on a stream that encrypts above its socket (TLS), said once
     # where the stream is built: what the socket carries are records,
-    # never the stream's bytes, so :meth:`idle_fd` gives no descriptor,
-    # and each :meth:`write_nowait` pays for the record layer on the
-    # caller's task (the device plane's egress counts and times those
-    # hand-offs apart: ``senders.egress_streams``)
+    # never the stream's bytes, so :meth:`idle_fd` gives no descriptor
+    # (:meth:`seal_idle` gives one with the records), and each
+    # :meth:`write_nowait` pays for the record layer on the caller's task
+    # (the device plane's egress counts and times those hand-offs apart:
+    # ``senders.egress_streams``)
     encrypts = False
 
     @abc.abstractmethod
@@ -250,6 +252,25 @@ class RawStream(abc.ABC):
         None and every send goes through the stream."""
         return None
 
+    def seal_idle(self, data) -> Optional[tuple]:
+        """:meth:`idle_fd` for a stream that encrypts above its socket:
+        ``(fd, records)``, ``data`` sealed now by the stream's own record
+        layer, iff a ``send()`` on ``fd`` now would carry this stream's
+        next bytes (no record, and nothing to seal, held back above or
+        below that layer); None, and nothing done, otherwise. Once it has
+        returned records the layer's sequence has moved past them: they
+        must reach the socket, in order, by the caller's one ``send()``
+        and, what that left, by :meth:`write_sealed`. Optional: the
+        default is None and every send goes through the stream."""
+        return None
+
+    def write_sealed(self, records) -> None:
+        """Hand the rest of :meth:`seal_idle`'s records, after the
+        caller's short ``send()``, to the transport beneath the record
+        layer, whose buffer was empty. Only a stream whose
+        :meth:`seal_idle` seals is asked."""
+        raise NotImplementedError
+
     @abc.abstractmethod
     async def close(self) -> None:
         """Flush and close the write side gracefully."""
@@ -257,6 +278,28 @@ class RawStream(abc.ABC):
     @abc.abstractmethod
     def abort(self) -> None:
         """Tear down immediately."""
+
+
+def _seal_parts(transport) -> Optional[tuple]:
+    """What :meth:`AsyncioStream.seal_idle` reads of the asyncio SSL
+    transport ``transport``, resolved once: its ``SSLProtocol``, that
+    protocol's ``SSLObject`` (the public ``ssl_object``), its outgoing
+    BIO, the TCP transport beneath it and the socket's fd. None where
+    this interpreter's asyncio lacks any of them (asyncio 3.12 internals:
+    ``tests/test_device_plane_tls.py`` holds the pinned one to having
+    them), and the stream then never seals."""
+    protocol = getattr(transport, "_ssl_protocol", None)
+    ssl_object = transport.get_extra_info("ssl_object")
+    outgoing = getattr(protocol, "_outgoing", None)
+    tcp = getattr(protocol, "_transport", None)
+    sock = transport.get_extra_info("socket")
+    if None in (protocol, ssl_object, outgoing, tcp, sock) \
+            or not hasattr(sslproto, "SSLProtocolState") \
+            or not all(hasattr(protocol, name) for name in (
+                "_state", "_write_backlog", "_ssl_writing_paused")):
+        return None
+    fd = sock.fileno()
+    return (protocol, ssl_object, outgoing, tcp, fd) if fd >= 0 else None
 
 
 class AsyncioStream(RawStream):
@@ -267,6 +310,7 @@ class AsyncioStream(RawStream):
         self.reader = reader
         self.writer = writer
         self.encrypts = encrypts  # the pair sits on a TLS transport
+        self._seal = _seal_parts(writer.transport) if encrypts else None
 
     async def read_exactly(self, n: int) -> bytes:
         return await self.reader.readexactly(n)
@@ -317,6 +361,33 @@ class AsyncioStream(RawStream):
         fd = -1 if sock is None else sock.fileno()
         return fd if fd >= 0 else None
 
+    def seal_idle(self, data) -> Optional[tuple]:
+        # the state in which ``write()`` would hand these records to the
+        # TCP transport at once and that transport would ``send()`` them
+        # at once: the SSL protocol open and wrapped, nothing in its
+        # backlog, no record in its outgoing BIO (no handshake, ticket or
+        # KeyUpdate waiting), not paused by the TCP transport, and that
+        # transport open with an empty buffer
+        if self._seal is None:
+            return None
+        protocol, ssl_object, outgoing, tcp, fd = self._seal
+        if protocol._state is not sslproto.SSLProtocolState.WRAPPED \
+                or protocol._write_backlog or outgoing.pending \
+                or protocol._ssl_writing_paused \
+                or self.writer.transport.is_closing() \
+                or tcp.get_write_buffer_size():
+            return None
+        done = ssl_object.write(data)  # a memoryview: no ``bytes()`` copy
+        while done < len(data):  # never with OpenSSL's full writes
+            done += ssl_object.write(data[done:])
+        return fd, outgoing.read()
+
+    def write_sealed(self, records) -> None:
+        # what asyncio's ``SSLProtocol._process_outgoing`` does with
+        # records: the TCP transport buffers them, and pauses the
+        # protocol above its high-water mark
+        self._seal[3].write(records)
+
     async def writev(self, bufs) -> None:
         # one gather handoff: writelines joins the run into a single
         # transport write (one kernel handoff instead of one per buffer)
@@ -364,8 +435,9 @@ class Connection:
         # the kernel's completion notification
         self._owner_write = bool(getattr(stream, "wants_owner", False))
         # whether the stream encrypts above its socket
-        # (:attr:`RawStream.encrypts`): no :meth:`idle_fd` then, and every
-        # inline write pays for the record layer on the caller's task
+        # (:attr:`RawStream.encrypts`): no :meth:`idle_fd` then but
+        # :meth:`seal_idle`, and every inline write pays for the record
+        # layer on the caller's task
         self.encrypts = bool(getattr(stream, "encrypts", False))
         # per-transport byte accounting: the label's prefix is the
         # transport name ("tcp:host:port" → "tcp"); the labeled children
@@ -1387,27 +1459,54 @@ class Connection:
         covers both, with nothing kept on the connection in between."""
         return self._stream.idle_fd() if self._inline_ok(nbytes) else None
 
+    def seal_idle(self, data) -> Optional[tuple]:
+        """:meth:`idle_fd` for a link whose stream encrypts: ``(fd,
+        records)``, the already length-delimited ``data`` sealed by the
+        stream's own record layer (:meth:`RawStream.seal_idle`), iff the
+        link is idle as :meth:`try_send_encoded_inline` wants it and the
+        stream holds nothing back; None, and nothing done, otherwise. The
+        caller owes the records one ``send()`` on ``fd`` before anything
+        else runs on the loop, and settles it with :meth:`sent_on_fd`
+        (``records`` given) or, whole, :meth:`sent_whole_on_fds`, credited
+        with ``data``'s bytes. A seal that raises poisons the link and
+        raises, as a write that raises does."""
+        if not self._inline_ok(len(data)):
+            return None
+        try:
+            return self._stream.seal_idle(data)
+        except Exception as exc:
+            err = Error(ErrorKind.CONNECTION, f"seal failed: {exc!r}", exc)
+            self._poison(err)
+            raise err
+
     def sent_on_fd(self, data, sent: int, cls: int = 2,
-                   nframes: int = 0) -> None:
+                   nframes: int = 0, records: Optional[bytes] = None) -> None:
         """Settle the one ``send()`` of ``data`` a caller made on
-        :meth:`idle_fd`'s socket: ``sent`` is what it returned, bytes
-        taken or ``-errno``. The remainder of a short send (all of it
-        after ``EAGAIN``) goes to the stream now, whose buffer was empty,
-        which is what the transport's own ``write`` does after a short
-        ``send``; any other errno poisons the link and raises, as a write
-        that raises does. Order and lifetime are argued as for
-        :meth:`try_send_encoded_inline`: the loop has not turned since
-        :meth:`idle_fd`, so nothing was queued, written or closed in
-        between, and the descriptor was this link's all through.
-        Accounting is that method's too. A caller with many such sends
-        settles those that took their whole stream together
-        (:meth:`sent_whole_on_fds`) and brings only the others here."""
+        :meth:`idle_fd`'s socket (of ``records``, on :meth:`seal_idle`'s):
+        ``sent`` is what it returned, bytes taken or ``-errno``. The
+        remainder of a short send (all of it after ``EAGAIN``) goes to the
+        stream now, whose buffer was empty, which is what the transport's
+        own ``write`` does after a short ``send`` (the records' to the
+        transport beneath the record layer, never through it again:
+        :meth:`RawStream.write_sealed`); any other errno poisons the link
+        and raises, as a write that raises does. Order and lifetime are
+        argued as for :meth:`try_send_encoded_inline`: the loop has not
+        turned since :meth:`idle_fd`, so nothing was queued, written or
+        closed in between, and the descriptor was this link's all
+        through. Accounting is that method's too, of ``data``. A caller
+        with many such sends settles those that took their whole stream
+        together (:meth:`sent_whole_on_fds`) and brings only the others
+        here."""
         if sent in (-errno.EAGAIN, -errno.EWOULDBLOCK):
             sent = 0
         try:
             if sent < 0:
                 raise OSError(-sent, os.strerror(-sent))
-            if sent < len(data) and not self._stream.write_nowait(data[sent:]):
+            if records is not None:
+                if sent < len(records):
+                    self._stream.write_sealed(records[sent:])
+            elif sent < len(data) and \
+                    not self._stream.write_nowait(data[sent:]):
                 raise OSError(errno.EPIPE, "stream closed under a short send")
         except Exception as exc:
             err = Error(ErrorKind.CONNECTION, f"write failed: {exc!r}", exc)

@@ -255,6 +255,41 @@ def test_send_batch_reports_short_sends_and_errnos_per_entry():
         assert sent[2:] == [-errno.EPIPE] * 2   # and no SIGPIPE
 
 
+def test_send_batch_each_sends_each_entrys_own_bytes_once():
+    """The variant for entries that own their bytes (a TLS link's sealed
+    records): 300 entries of their own lengths over the library's
+    threads, each socket gets exactly its object's bytes, once; a full
+    socket reads short, a closed peer ``-EPIPE``; anything but one bytes
+    object an fd is refused before any send."""
+    import errno
+    import socket
+    n = 300
+    stack, pairs = _socket_pairs(n + 1)
+    with stack:
+        rng = np.random.default_rng(41)
+        bufs = [rng.integers(0, 256, int(k), np.uint8).tobytes()
+                for k in rng.integers(1, 2000, n)]
+        order = rng.permutation(n)
+        fds = np.array([pairs[i][0].fileno() for i in order], np.int32)
+        sent = native.send_batch_each([bufs[i] for i in order], fds)
+        assert sent.dtype == np.int64
+        assert sent.tolist() == [len(bufs[i]) for i in order]
+        for i in range(n):
+            pairs[i][1].setblocking(False)
+            assert pairs[i][1].recv(4096) == bufs[i], i
+        a, b = pairs[n]
+        a.setsockopt(socket.SOL_SOCKET, socket.SO_SNDBUF, 4096)
+        big = bytes(1 << 20)
+        took = native.send_batch_each([big], np.array([a.fileno()]))[0]
+        assert 0 < took < len(big)
+        b.close()
+        assert native.send_batch_each([big], np.array([a.fileno()])).tolist() \
+            == [-errno.EPIPE]
+        for bad in ([bytearray(4)], [memoryview(b"abcd")], [b"a", b"b"]):
+            with pytest.raises(ValueError):
+                native.send_batch_each(bad, np.array([a.fileno()]))
+
+
 def test_send_batch_refuses_streams_outside_the_buffer():
     stack, pairs = _socket_pairs(1)
     with stack:
