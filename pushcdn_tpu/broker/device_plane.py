@@ -299,6 +299,13 @@ class DevicePlane:
         # step's (senders._egress_batched; ``egress_batched`` counts the
         # plain links' alone). Their inline writes above are those seals
         self.egress_tls_batched = 0
+        # of inline + queued (and of those that failed), the hand-offs
+        # whose stream is longer than one flush unit
+        # (``Connection._BATCH_COALESCE_LIMIT``), which no idle link takes
+        # from the pump: they go to the writers; and their bytes
+        # (senders.egress_streams)
+        self.egress_oversize = 0
+        self.egress_oversize_bytes = 0
         self.warmup_s: Optional[float] = None
 
     # ---- user lifecycle (Connections observer; event-loop only) ----------
@@ -585,6 +592,7 @@ class DevicePlane:
     def describe(self) -> dict:
         """The plane's device and state as one JSON-able dict — logged
         once at start, served under ``/debug/topology``."""
+        from pushcdn_tpu import native as native_mod
         from pushcdn_tpu.parallel import runtime
         from pushcdn_tpu.proto.metrics import loop_account
         dev = runtime.device()
@@ -611,6 +619,9 @@ class DevicePlane:
             "egress_tls_inline": self.egress_tls_inline,
             "egress_tls_write_us": self.egress_tls_write_ns // 1000,
             "egress_tls_batched": self.egress_tls_batched,
+            "egress_oversize": self.egress_oversize,
+            "egress_oversize_bytes": self.egress_oversize_bytes,
+            **native_mod.egress_pool_counters(),
             "mirrored_users": len(self.slots),
             "unmirrored_users": len(self._unmirrored),
             "user_slots": self.user_slots,
@@ -786,11 +797,11 @@ class DevicePlane:
                 gate.stepped(loop.time())
                 with spans.span("plane.egress", step=step) as sp:
                     routed, inline, queued, batched, short, tls, \
-                        tls_batched = (
+                        tls_batched, oversize = (
                             self.messages_routed, self.egress_inline,
                             self.egress_queued, self.egress_batched,
                             self.egress_batched_short, self.egress_tls,
-                            self.egress_tls_batched)
+                            self.egress_tls_batched, self.egress_oversize)
                     for streams, d2, lengths, frames in jobs:
                         if streams is not None:
                             egress_streams(self, self.broker, streams,
@@ -804,7 +815,8 @@ class DevicePlane:
                         batched=self.egress_batched - batched,
                         short=self.egress_batched_short - short,
                         tls=self.egress_tls - tls,
-                        tls_batched=self.egress_tls_batched - tls_batched)
+                        tls_batched=self.egress_tls_batched - tls_batched,
+                        oversize=self.egress_oversize - oversize)
                 # the pump's own ``send()``s held the loop: drain the
                 # sockets before the next take. Streams that were all
                 # queued for their writers were no such hold (the loop

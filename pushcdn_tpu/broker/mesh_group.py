@@ -178,6 +178,7 @@ class MeshShardPlane:
     def describe(self) -> dict:
         """This shard's view of the group for ``/debug/topology`` (the
         single-shard plane's twin)."""
+        from pushcdn_tpu import native as native_mod
         from pushcdn_tpu.parallel import runtime
         from pushcdn_tpu.proto.metrics import loop_account
         dev = runtime.device()
@@ -198,6 +199,9 @@ class MeshShardPlane:
             "egress_tls_inline": g.egress_tls_inline,
             "egress_tls_write_us": g.egress_tls_write_ns // 1000,
             "egress_tls_batched": g.egress_tls_batched,
+            "egress_oversize": g.egress_oversize,
+            "egress_oversize_bytes": g.egress_oversize_bytes,
+            **native_mod.egress_pool_counters(),
             "h2d_puts": g.h2d_puts, "h2d_bytes": g.h2d_bytes,
             "stage_full_results": g.stage_full_results,
             "stage_full_frames": g.stage_full_frames,
@@ -356,6 +360,9 @@ class MeshBrokerGroup:
         self.egress_tls_inline = 0
         self.egress_tls_write_ns = 0
         self.egress_tls_batched = 0  # of tls inline: by the native batch
+        # of all: streams over one flush unit, and their bytes
+        self.egress_oversize = 0
+        self.egress_oversize_bytes = 0
         self._members = _Members(self)
         # collectives traced by the most recently COMPILED step
         # specialization (router.trace_collectives delta around the call):
@@ -754,11 +761,11 @@ class MeshBrokerGroup:
                 gate.stepped(loop.time())
                 with spans.span("plane.egress", step=step) as sp:
                     routed, inline, queued, batched, short, tls, \
-                        tls_batched = (
+                        tls_batched, oversize = (
                             self.messages_routed, self.egress_inline,
                             self.egress_queued, self.egress_batched,
                             self.egress_batched_short, self.egress_tls,
-                            self.egress_tls_batched)
+                            self.egress_tls_batched, self.egress_oversize)
                     for streams, d2, lengths, frames in egress_jobs:
                         if streams is not None:
                             egress_streams(self, self._members, streams,
@@ -773,7 +780,8 @@ class MeshBrokerGroup:
                         batched=self.egress_batched - batched,
                         short=self.egress_batched_short - short,
                         tls=self.egress_tls - tls,
-                        tls_batched=self.egress_tls_batched - tls_batched)
+                        tls_batched=self.egress_tls_batched - tls_batched,
+                        oversize=self.egress_oversize - oversize)
             except asyncio.CancelledError:
                 raise
             except Exception:

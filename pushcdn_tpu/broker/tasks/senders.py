@@ -187,7 +187,7 @@ def _send_failed(broker: "Broker", public_key: bytes, connection,
     broker.update_metrics()
 
 
-def _egress_batched(plane, broker: "Broker", streams) -> list:
+def _egress_batched(plane, broker: "Broker", streams, sizes) -> list:
     """Send the streams of one back-pressured step whose links are idle
     by native calls (``native.send_batch``: the sends fanned over a few
     threads, joined before it returns); the slots they did not take, for
@@ -200,7 +200,8 @@ def _egress_batched(plane, broker: "Broker", streams) -> list:
     from the first check to the last settling, as it does for that
     loop's ``send()``s, so nothing else can write to, close or reuse a
     socket, or seal on its link, in between; a user has one stream in
-    ``streams``, so one send: nothing can reorder.
+    ``streams``, so one send: nothing can reorder. ``sizes`` is
+    ``streams.nbytes`` of its users, in their order.
 
     The tallies: a plain link's hand-off counts in ``egress_inline`` and
     ``egress_batched``; a sealed one in ``egress_inline``, ``egress_tls``,
@@ -217,7 +218,7 @@ def _egress_batched(plane, broker: "Broker", streams) -> list:
     plain, sealed, rest, seal_ns = _Batch(), _Batch(), [], 0
     # the plain links' appends as locals: theirs is the step's long loop
     taken, keys, links, fds = plain.slots, plain.keys, plain.links, plain.fds
-    for slot, size in zip(users, streams.nbytes[users].tolist()):
+    for slot, size in zip(users, sizes.tolist()):
         key = slots.key_of(slot)
         connection = None if key is None else user_connection(key)
         if connection is None:
@@ -341,11 +342,23 @@ def egress_streams(plane, broker: "Broker", streams,
     every other hand-off, and every one of a step that is not
     back-pressured (where shorter sends would buy a faster cadence of
     smaller steps with cores), goes one by one below, each accounted by
-    the connection's own call."""
+    the connection's own call.
+
+    A stream longer than one flush unit (``Connection._BATCH_COALESCE_LIMIT``)
+    is taken by no idle link, in the batch or one by one: it goes to the
+    user's writer task, and later streams of that user queue behind it.
+    Such hand-offs and their bytes are counted here, once a step and
+    whichever way the step goes (``egress_oversize``,
+    ``egress_oversize_bytes``)."""
     slots = plane.slots
     users = streams.users
+    sizes = streams.nbytes[users]
+    over = sizes > Connection._BATCH_COALESCE_LIMIT
+    if over.any():
+        plane.egress_oversize += int(np.count_nonzero(over))
+        plane.egress_oversize_bytes += int(sizes[over].sum())
     if back_pressured:
-        users = _egress_batched(plane, broker, streams)
+        users = _egress_batched(plane, broker, streams, sizes)
     for slot in users:
         key = slots.key_of(int(slot))
         if key is None:  # released mid-step: user is gone, drop

@@ -490,6 +490,13 @@ _EGRESS_NEED_HW = 1 << 20  # decaying high-water mark of real step sizes
 # allocated), so a persistent registration stays valid for the buffer's
 # whole life.
 _EGRESS_REGISTRARS: list = []
+# every take, those that found no pooled buffer that fits and allocated,
+# and the bytes they allocated (plain ints that only grow; the planes'
+# ``describe()`` passes them through: :func:`egress_pool_counters`). The
+# lock guards these three alone: planes of one process encode on their
+# own worker threads
+_EGRESS_COUNTS = [0, 0, 0]
+_EGRESS_COUNTS_LOCK = threading.Lock()
 
 
 def add_egress_registrar(fn) -> None:
@@ -505,6 +512,22 @@ def egress_pool_buffers() -> list:
     return list(_EGRESS_POOL)
 
 
+def egress_pool_counters() -> Dict[str, int]:
+    """What :func:`_egress_take` has done in this process: takes, fresh
+    allocations among them, and the bytes those allocated."""
+    takes, fresh, fresh_bytes = _EGRESS_COUNTS
+    return {"egress_pool_takes": takes, "egress_pool_fresh": fresh,
+            "egress_pool_fresh_bytes": fresh_bytes}
+
+
+def _egress_count(fresh_bytes: int = 0) -> None:
+    with _EGRESS_COUNTS_LOCK:
+        _EGRESS_COUNTS[0] += 1
+        if fresh_bytes:
+            _EGRESS_COUNTS[1] += 1
+            _EGRESS_COUNTS[2] += fresh_bytes
+
+
 def _egress_note_need(nbytes: int) -> None:
     """Record a step's actual egress size (geometric decay: the
     high-water mark forgets a spike within ~tens of steps)."""
@@ -517,12 +540,15 @@ def _egress_take(nbytes: int):
     Returns (bytearray, lease). Lock-free on purpose: encode runs both on
     the event loop and in mesh-group worker threads, and the lease's
     ``__del__`` (which appends back) can fire inside any allocation's GC —
-    so only GIL-atomic list ops are used, with a defensive retry."""
+    so only GIL-atomic list ops are used, with a defensive retry. Each
+    take is counted (:func:`egress_pool_counters`) under a lock that
+    holds integer sums alone, so no ``__del__`` can run inside it."""
     pool = _EGRESS_POOL
     try:
         for _ in range(len(pool)):
             buf = pool.pop()
             if len(buf) >= nbytes:
+                _egress_count()
                 return buf, _EgressLease(buf)
             pool.insert(0, buf)  # too small for this step: rotate away
     except IndexError:  # raced another taker
@@ -532,6 +558,7 @@ def _egress_take(nbytes: int):
     # frame larger, which then pays a fresh allocation's page faults
     # (0.4 s for 280 MB at 5,000 users) while the small ones fill the pool
     buf = bytearray(max(nbytes + (nbytes >> 1), 1 << 20))
+    _egress_count(len(buf))
     for fn in _EGRESS_REGISTRARS:
         fn(buf)
     return buf, _EgressLease(buf)

@@ -66,7 +66,9 @@ The spans (the names are what ``benchmark/span_reduce.py`` reads):
                                   over a stream that encrypts above its
                                   socket: a user on TCP+TLS),
                                   ``tls_batched`` (of ``tls``, sent sealed
-                                  by one native call; not in ``batched``)
+                                  by one native call; not in ``batched``),
+                                  ``oversize`` (the streams longer than one
+                                  flush unit, which go to the writers)
 ====================  ==========  ===========================================
 
 ``step`` is the plane's own step number; the two thread hops of a step
@@ -127,6 +129,16 @@ the deployment's. Beside the older ones (``steps``, ``frames_staged``,
                             who cost one attribute read and no clock;
                             ``tls_write_us_per_handoff``,
                             ``tls_write_share``
+``egress_oversize``,        ``senders.egress_streams``, once a step: the
+``egress_oversize_bytes``   hand-offs whose stream is longer than one
+                            flush unit (``Connection.
+                            _BATCH_COALESCE_LIMIT``), and their bytes;
+                            ``egress_oversize_per_step``
+``egress_pool_takes``,      ``native._egress_take``, process-wide: every
+``egress_pool_fresh``,      take of a step's egress buffer, those that
+``egress_pool_fresh_bytes`` found none pooled that fits and allocated,
+                            and the bytes allocated;
+                            ``egress_pool_fresh_share``
 ``loop_lag_us``,            ``proto/metrics.py:_loop_lag_sampler``, a
 ``loop_lag_samples``        sample a 0.25 s; None where no sampler runs;
                             ``loop_lag_ms``

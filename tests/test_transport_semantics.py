@@ -12,6 +12,7 @@ added in round 3.
 import asyncio
 import struct
 
+import numpy as np
 import pytest
 
 from pushcdn_tpu import native
@@ -494,12 +495,13 @@ class _Broker:
 
 class _Plane:
     """What ``senders.egress_streams`` uses of a plane: the slot table
-    (one user per slot) and the three tallies."""
+    (one user per slot) and the five tallies."""
 
     def __init__(self, keys):
         self.keys = list(keys)
         self.slots = self
         self.messages_routed = self.egress_inline = self.egress_queued = 0
+        self.egress_oversize = self.egress_oversize_bytes = 0
 
     def key_of(self, slot):
         return self.keys[slot]
@@ -512,6 +514,7 @@ class _Streams:
     def __init__(self, n: int, stream: bytes, nframes: int):
         self.users = range(n)
         self.msgs = [nframes] * n
+        self.nbytes = np.full(n, len(stream), np.int64)
         self._stream = stream
 
     def stream(self, slot):
