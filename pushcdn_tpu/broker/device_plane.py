@@ -53,6 +53,9 @@ Flow per step:
   egress:  deliver[u, f] → per-user non-blocking send of the frame bytes
   drain:   after an egress the pump wrote itself (the loop stood still),
            the receive loops stage what the sockets hold before the take
+  pace:    before that, after a step that sent in the native batch though
+           its take found room, the take waits until the wall since the
+           last take has caught up with the CPU spent since it
 """
 
 from __future__ import annotations
@@ -67,6 +70,7 @@ import numpy as np
 
 from pushcdn_tpu.broker.pump_common import (
     CoalesceGate,
+    CpuPacer,
     PumpAccount,
     RevCache,
     TopicMaskCache,
@@ -243,12 +247,19 @@ class DevicePlane:
         # heartbeat fail-open logic reads it off any plane uniformly
         self.overflow_seen = False
         self._kick = asyncio.Event()
+        # set by a stager that leaves the base lane full: it ends a pacing
+        # wait (``_pace``)
+        self._lane_full = asyncio.Event()
         self._task: Optional[asyncio.Task] = None
         self._step_inflight = False
         # True from the end of a ``plane.egress`` in which the pump wrote
         # streams itself (the loop stood still: the sockets hold that
-        # time's frames) until the drain before the next take is over
+        # time's frames), or after which it paces the next take, until
+        # the drain before the next take is over
         self._between_steps = False
+        # which step sends in the native batch off saturation, and the
+        # pace of the take after it
+        self._pacer = CpuPacer()
         self.steps = 0
         self.frames_staged = 0      # frames accepted into a ring
         # of those, staged while the pump drained the sockets into the
@@ -278,8 +289,12 @@ class DevicePlane:
         self.egress_inline = 0
         self.egress_queued = 0
         # of the inline ones, those one native call sent for a step whose
-        # take found the base lane full (senders.egress_streams)
+        # take found the base lane full or whose sends were the period
+        # (senders.egress_streams; ``_pump``); of those (and of
+        # ``egress_tls_batched``), the ones of a step whose take was not
+        # back-pressured
         self.egress_batched = 0
+        self.egress_offsat_batched = 0
         # of the batched ones, those whose send() came back short and
         # were settled one by one; the rest were settled in one pass
         self.egress_batched_short = 0
@@ -295,8 +310,8 @@ class DevicePlane:
         self.egress_tls_inline = 0
         self.egress_tls_write_ns = 0
         # of the inline ones, those one native call sent with the records
-        # the link's own record layer sealed on the loop: a back-pressured
-        # step's (senders._egress_batched; ``egress_batched`` counts the
+        # the link's own record layer sealed on the loop: a batched step's
+        # (senders._egress_batched; ``egress_batched`` counts the
         # plain links' alone). Their inline writes above are those seals
         self.egress_tls_batched = 0
         # of inline + queued (and of those that failed), the hand-offs
@@ -482,6 +497,8 @@ class DevicePlane:
             if self._staged_since is None:
                 self._staged_since = time.monotonic()
             self._kick.set()
+            if not self.rings[0].free_slots:
+                self._lane_full.set()
             return StageResult.STAGED
         self.stage_full_results += 1
         return StageResult.FULL
@@ -547,6 +564,8 @@ class DevicePlane:
             if self._staged_since is None:
                 self._staged_since = time.monotonic()
             self._kick.set()
+            if not self.rings[0].free_slots:
+                self._lane_full.set()
         return results
 
     def covered_broker_idents(self) -> set:
@@ -615,6 +634,7 @@ class DevicePlane:
             "egress_queued": self.egress_queued,
             "egress_batched": self.egress_batched,
             "egress_batched_short": self.egress_batched_short,
+            "egress_offsat_batched": self.egress_offsat_batched,
             "egress_tls": self.egress_tls,
             "egress_tls_inline": self.egress_tls_inline,
             "egress_tls_write_us": self.egress_tls_write_ns // 1000,
@@ -716,6 +736,31 @@ class DevicePlane:
             self._between_steps = False
         return self.frames_staged - first
 
+    async def _pace(self) -> int:
+        """After a step whose sends left in the native batch though its
+        take was not back-pressured: wait until the wall since that take
+        has caught up with the CPU the process spent since it
+        (``CpuPacer.owed_ns``), so that the batch's threads buy the users
+        an earlier stream and not more steps; the ns waited. A take that
+        finds the base lane full never waits, and a stager that fills it
+        ends the wait: its publishers wait on the step. The pump calls it
+        while ``_between_steps`` holds, so the idle bypass stays shut, and
+        the loop turns meanwhile: what reaches a socket is staged into
+        the rings, as during a long egress."""
+        owed = self._pacer.owed_ns()
+        if owed <= 0 or not self.rings[0].free_slots:
+            return 0
+        self._lane_full.clear()
+        t0 = time.monotonic_ns()
+        try:
+            async with asyncio.timeout(owed / 1e9):
+                await self._lane_full.wait()
+        except asyncio.TimeoutError:
+            pass
+        waited = time.monotonic_ns() - t0
+        self._account.paced(waited)
+        return waited
+
     async def _pump(self) -> None:
         from pushcdn_tpu.broker.tasks.senders import egress_streams
         from pushcdn_tpu.parallel.frames import slice_batch
@@ -724,8 +769,17 @@ class DevicePlane:
         gate = CoalesceGate(c.batch_window_s, c.coalesce_min_frames)
         # one sequential task: its states partition its wall time
         account = self._account = PumpAccount()
+        pacer = self._pacer
+        # the last step's sends left in the batch off saturation: the next
+        # take waits out the CPU that step cost, and what is still owed
+        # at that take is owed from it
+        paced = carry = False
         while True:
             drained = 0
+            if paced:
+                account.enter("gate")
+                paced, carry = False, True
+                await self._pace()
             if self._between_steps:
                 account.enter("drain")
                 drained = await self._drain()
@@ -759,10 +813,17 @@ class DevicePlane:
             self._staged_since = None
             u_eff = self._step_users()
             self.frames_drained += drained
-            # the base lane full at the take: its stagers wait on the
-            # step, so this step's length is the publishers' rate
+            # The base lane full at the take: its stagers wait on the
+            # step, so this step's length is the publishers' rate, and its
+            # idle links' sends leave in the native batch. So do they off
+            # saturation where the sends are the period (``CpuPacer``):
+            # the take after such a step is paced, so the batch's threads
+            # spend no more CPU than the one-by-one loop
             back_pressured = not self.rings[0].free_slots
+            batch = back_pressured or pacer.sends_lead
             account.enter("take")
+            pacer.took(carry)
+            carry = False
             parked_us, gate_us, drain_us = account.since_take()
             with spans.span("plane.take", step=step, frames=staged,
                             ring_wait_us=int(waited * 1e6), users=u_eff,
@@ -794,6 +855,7 @@ class DevicePlane:
                 finally:
                     self._step_inflight = False
                 account.enter("egress")
+                pacer.egress_began()
                 gate.stepped(loop.time())
                 with spans.span("plane.egress", step=step) as sp:
                     routed, inline, queued, batched, short, tls, \
@@ -805,9 +867,15 @@ class DevicePlane:
                     for streams, d2, lengths, frames in jobs:
                         if streams is not None:
                             egress_streams(self, self.broker, streams,
-                                           back_pressured)
+                                           batch)
                         else:
                             self._egress(d2, lengths, frames)
+                    pacer.egress_ended(back_pressured)
+                    paced = batch and not back_pressured
+                    if paced:
+                        self.egress_offsat_batched += (
+                            self.egress_batched - batched
+                            + self.egress_tls_batched - tls_batched)
                     sp.set_metadata(
                         deliveries=self.messages_routed - routed,
                         inline=self.egress_inline - inline,
@@ -820,8 +888,9 @@ class DevicePlane:
                 # the pump's own ``send()``s held the loop: drain the
                 # sockets before the next take. Streams that were all
                 # queued for their writers were no such hold (the loop
-                # turned beside the step)
-                self._between_steps = self.egress_inline != inline
+                # turned beside the step). A paced take keeps the idle
+                # bypass shut until it is over
+                self._between_steps = self.egress_inline != inline or paced
             except asyncio.CancelledError:
                 raise
             except Exception:

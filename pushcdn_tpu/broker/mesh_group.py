@@ -195,6 +195,8 @@ class MeshShardPlane:
             "egress_queued": g.egress_queued,
             "egress_batched": g.egress_batched,
             "egress_batched_short": g.egress_batched_short,
+            # the group batches back-pressured ticks alone and paces none
+            "egress_offsat_batched": 0,
             "egress_tls": g.egress_tls,
             "egress_tls_inline": g.egress_tls_inline,
             "egress_tls_write_us": g.egress_tls_write_ns // 1000,

@@ -11,7 +11,9 @@ one home keeps them in lockstep):
 - the revision-keyed device-state cache (steady state pays zero H2D for
   the user table),
 - the pump's state account (where the one sequential pump task spends its
-  wall time, as cumulative microseconds in ``describe()``).
+  wall time, as cumulative microseconds in ``describe()``),
+- the CPU pacer (``DevicePlane`` alone: which step sends in the native
+  batch off saturation, and how long the take after it waits).
 """
 
 from __future__ import annotations
@@ -78,7 +80,7 @@ class PumpAccount:
     ``pump_parked_us``    awaiting ``_kick`` with nothing staged
     ``pump_gate_us``      ``_load_programs``, the ``sleep(0)`` that lets
                           the pass's stagers land, the coalescing
-                          ``sleep(wait)``
+                          ``sleep(wait)``, ``DevicePlane._pace``
     ``pump_drain_us``     ``DevicePlane._drain``
     ``pump_take_us``      the ``plane.take`` section
     ``pump_worker_us``    ``await asyncio.to_thread(...)`` of the step:
@@ -87,15 +89,22 @@ class PumpAccount:
     ``worker_busy_us``    the step's wall measured on the worker thread
                           (:meth:`run`), so ``pump_worker_us`` less this
                           is the two hops
+    ``pump_paced_us``     of ``pump_gate_us``, the waits of ``_pace``
+                          (:meth:`paced`); 0 in the group, which never
+                          paces
+    ``pump_paced_steps``  the takes that waited there
     ====================  ================================================
     """
 
     STATES = ("parked", "gate", "drain", "take", "worker", "egress")
-    __slots__ = ("us", "worker_busy_us", "_state", "_since", "_taken")
+    __slots__ = ("us", "worker_busy_us", "paced_us", "paced_steps",
+                 "_state", "_since", "_taken")
 
     def __init__(self):
         self.us: Dict[str, int] = dict.fromkeys(self.STATES, 0)
         self.worker_busy_us = 0
+        self.paced_us = 0
+        self.paced_steps = 0
         self._state = "parked"
         self._since = time.monotonic_ns()
         self._taken = (0, 0, 0)
@@ -127,11 +136,84 @@ class PumpAccount:
         finally:
             self.worker_busy_us += (time.monotonic_ns() - t0) // 1000
 
+    def paced(self, ns: int) -> None:
+        """A take waited ``ns`` inside the open ``gate`` state."""
+        self.paced_us += ns // 1000
+        self.paced_steps += 1
+
     def counters(self) -> Dict[str, int]:
-        """The seven counters under their names in ``describe()``."""
+        """The nine counters under their names in ``describe()``."""
         out = {f"pump_{state}_us": us for state, us in self.us.items()}
         out["worker_busy_us"] = self.worker_busy_us
+        out["pump_paced_us"] = self.paced_us
+        out["pump_paced_steps"] = self.paced_steps
         return out
+
+
+class CpuPacer:
+    """Which step of ``DevicePlane``'s pump sends in the native batch
+    though its take was not back-pressured, and how long the take after
+    such a step waits: the routing process's CPU against the wall, on
+    injectable clocks (``time.process_time_ns``, the process's user and
+    system time over all its threads, as ``broker_cpu_us_per_delivery``
+    reads it; ``time.monotonic_ns``).
+
+    One by one, a step's sends cost the event loop's core, so the wall
+    they take is their CPU and the pump steps at most once per step's
+    CPU. In the batch the same sends leave over several threads in a
+    fraction of that wall (``native.send_batch``); a pump that stepped
+    again at once would send to every user again sooner, and the process
+    would spend more CPU a delivery. So after such a step the next take
+    waits until the wall since this take has caught up with the CPU
+    spent since it (:meth:`owed_ns`), and a take after such a wait
+    carries what it still owes (:meth:`took`): the process spends no
+    more than the one core it spent one by one, and each user's stream
+    leaves sooner after the decision.
+
+    ``sends_lead`` is the pump's observation that the sends are the
+    period: over the last step that was not back-pressured, the CPU its
+    egress spent exceeded the wall from its take to its egress (the
+    snapshot and the worker). CPU and not wall, since the batch shortens
+    the egress's wall and not its CPU: once engaged, the observation
+    stays where it was. A back-pressured step leaves it False: its length
+    is its publishers' rate, so a lull after it (a step whose take found
+    room) goes one by one, as every such step did before."""
+
+    __slots__ = ("cpu_ns", "wall_ns", "sends_lead", "_take", "_egress")
+
+    def __init__(self, cpu_ns: Callable[[], int] = time.process_time_ns,
+                 wall_ns: Callable[[], int] = time.monotonic_ns):
+        self.cpu_ns, self.wall_ns = cpu_ns, wall_ns
+        self.sends_lead = False
+        self._take = self._egress = (wall_ns(), cpu_ns())
+
+    def took(self, carry: bool = False) -> None:
+        """A take begins. With ``carry`` (the step before was paced) what
+        the last take still owed is owed from this one too: the CPU spent
+        while the pace waited, and after it up to this take, which that
+        pace could not see. A credit (the wall ahead of the CPU) is never
+        carried, nor anything past a step that was not paced."""
+        wall, cpu = self.wall_ns(), self.cpu_ns()
+        owed = 0
+        if carry:
+            wall0, cpu0 = self._take
+            owed = max((cpu - cpu0) - (wall - wall0), 0)
+        self._take = (wall, cpu - owed)
+
+    def egress_began(self) -> None:
+        self._egress = (self.wall_ns(), self.cpu_ns())
+
+    def egress_ended(self, back_pressured: bool) -> None:
+        """The step's egress is over: update :attr:`sends_lead`."""
+        wall, cpu = self._egress
+        self.sends_lead = not back_pressured and \
+            self.cpu_ns() - cpu > wall - self._take[0]
+
+    def owed_ns(self) -> int:
+        """The CPU spent since the last take less the wall since it: how
+        long the next take still waits (nothing at 0 or below)."""
+        wall, cpu = self._take
+        return (self.cpu_ns() - cpu) - (self.wall_ns() - wall)
 
 
 class RevCache:

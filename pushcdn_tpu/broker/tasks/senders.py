@@ -188,8 +188,8 @@ def _send_failed(broker: "Broker", public_key: bytes, connection,
 
 
 def _egress_batched(plane, broker: "Broker", streams, sizes) -> list:
-    """Send the streams of one back-pressured step whose links are idle
-    by native calls (``native.send_batch``: the sends fanned over a few
+    """Send the streams of one batched step whose links are idle by
+    native calls (``native.send_batch``: the sends fanned over a few
     threads, joined before it returns); the slots they did not take, for
     the caller's loop. A plain link gives its socket
     (``Connection.idle_fd``) and is sent straight from the step's pooled
@@ -323,7 +323,7 @@ def _send_and_settle(plane, broker: "Broker", streams, batch) -> tuple:
 
 
 def egress_streams(plane, broker: "Broker", streams,
-                   back_pressured: bool = False) -> None:
+                   batch: bool = False) -> None:
     """Deliver one step's native egress (:class:`native.EgressStreams`):
     one pre-framed stream hand-off per user with deliveries, tallied on
     ``plane`` (a ``DevicePlane`` or a broker group): ``messages_routed``,
@@ -331,18 +331,20 @@ def egress_streams(plane, broker: "Broker", streams,
     (of both, ``egress_tls`` over a link that encrypts:
     :func:`try_send_encoded_to_user_nowait`, :func:`_egress_batched`).
 
-    ``back_pressured`` is the pump's observation that the step's take
-    found the base lane full: its publishers wait on the step, so the
-    next one carries as many frames and makes as many sends however
-    short this one is, and the time of the sends is the rate. Only then
-    do the idle links' sends leave together over several threads, a TLS
-    link's sealed on the loop first (:func:`_egress_batched`, which also
-    accounts for them: the whole
+    ``batch`` is the pump's decision that the idle links' sends leave
+    together over several threads, a TLS link's sealed on the loop first
+    (:func:`_egress_batched`, which also accounts for them: the whole
     sends of the batch in one pass, the plane's tallies by their sums);
-    every other hand-off, and every one of a step that is not
-    back-pressured (where shorter sends would buy a faster cadence of
-    smaller steps with cores), goes one by one below, each accounted by
-    the connection's own call.
+    every other hand-off, and every one of a step not batched, goes one
+    by one below, each accounted by the connection's own call. Both pumps
+    batch a step whose take found the base lane full: its publishers
+    wait on the step, so the next one carries as many frames and makes as
+    many sends however short this one is, and the time of the sends is
+    the rate. ``DevicePlane``'s also batches, off saturation, a step
+    whose sends are the period (``pump_common.CpuPacer``), and paces the
+    take after it by the CPU the step cost, where shorter sends would
+    else buy a faster cadence of smaller steps with cores: the same
+    sends at the same cadence, each user's stream out sooner.
 
     A stream longer than one flush unit (``Connection._BATCH_COALESCE_LIMIT``)
     is taken by no idle link, in the batch or one by one: it goes to the
@@ -357,7 +359,7 @@ def egress_streams(plane, broker: "Broker", streams,
     if over.any():
         plane.egress_oversize += int(np.count_nonzero(over))
         plane.egress_oversize_bytes += int(sizes[over].sum())
-    if back_pressured:
+    if batch:
         users = _egress_batched(plane, broker, streams, sizes)
     for slot in users:
         key = slots.key_of(int(slot))

@@ -667,6 +667,11 @@ def egress_encode(deliver: np.ndarray, lengths: np.ndarray,
 # (the time is kernel work per socket: it divides, it does not grow), and
 # ``fanout4-sat`` delivered 42k, 58k, 69k, 75k a second at 26-27 us of CPU
 # a delivery (30.8 one by one). Past 8 the host has no cores to give.
+# The batch runs for a step whose take found the base lane full, and, off
+# saturation, for one whose sends are the period
+# (``pump_common.CpuPacer``): there the take after it waits until the
+# wall catches up with the CPU the step cost, so the threads shorten the
+# time to each user's stream and not the process's CPU a delivery.
 _SEND_THREADS = 8
 
 

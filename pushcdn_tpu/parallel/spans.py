@@ -59,7 +59,8 @@ The spans (the names are what ``benchmark/span_reduce.py`` reads):
                                   for the writer; ``step``, ``deliveries``,
                                   ``inline``, ``queued``, ``batched`` (of
                                   ``inline``, sent by one native call: a
-                                  step or tick that was back-pressured),
+                                  step or tick that was back-pressured,
+                                  or a step whose sends were the period),
                                   ``short`` (of ``batched``, settled one by
                                   one after a short send; the rest in one pass),
                                   ``tls`` (of ``inline`` + ``queued``, those
@@ -95,6 +96,15 @@ the deployment's. Beside the older ones (``steps``, ``frames_staged``,
                             around the step: ``pump_worker_us`` less this
                             is the two hops; ``step_hop_ms``,
                             ``sat_step_hop_ms``
+``pump_paced_us``,          ``PumpAccount.paced``, called by
+``pump_paced_steps``        ``DevicePlane._pace``: the waits of the takes
+                            paced after a step that sent in the native
+                            batch off saturation, a part of
+                            ``pump_gate_us``, and how many; no reader
+``egress_offsat_batched``   ``DevicePlane._pump``, once a step: of
+                            ``egress_batched`` + ``egress_tls_batched``,
+                            the hand-offs of a step whose take was not
+                            back-pressured (0 in the group); no reader
 ``stage_full_results``      ``try_stage`` / ``stage_batch`` of both
                             planes, every ``FULL`` handed back (a retry's
                             too); no reader

@@ -819,9 +819,115 @@ async def test_the_drain_is_bounded_in_loop_passes(sockets):
 
 
 # ---------------------------------------------------------------------------
-# the native batch (ISSUE 31): the sends of a step whose take found the base
-# lane full leave in one ``native.send_batch`` call, for the links that are
-# idle plain sockets; every other step, and every other link, goes one by one.
+# the CPU pacer: off saturation, a step whose sends are the
+# period sends in the native batch, and the take after it waits until the
+# wall has caught up with the CPU the step cost
+# ---------------------------------------------------------------------------
+
+_MS = 1_000_000
+
+
+@pytest.mark.parametrize(
+    "device_ms,egress_wall_ms,egress_cpu_ms,back_pressured,leads", [
+        (30, 400, 400, False, True),    # one by one: the sends, the period
+        (30, 80, 400, False, True),     # batched: the CPU still says so
+        (120, 40, 40, False, False),    # the worker is the period
+        (30, 400, 400, True, False),    # back-pressured: its rate's length
+    ], ids=["one_by_one", "batched", "worker_leads", "back_pressured"])
+def test_the_pacer_observes_the_sends_and_owes_the_cpu(
+        device_ms, egress_wall_ms, egress_cpu_ms, back_pressured, leads):
+    """On injected clocks: ``sends_lead`` is the egress's CPU against the
+    wall from the take to the egress, and what the next take owes is the
+    CPU since the take less the wall since it."""
+    from pushcdn_tpu.broker.pump_common import CpuPacer
+
+    cpu, wall = [5 * _MS], [7 * _MS]
+    pacer = CpuPacer(cpu_ns=lambda: cpu[0], wall_ns=lambda: wall[0])
+    assert not pacer.sends_lead and pacer.owed_ns() == 0
+    pacer.took()
+    wall[0] += device_ms * _MS
+    cpu[0] += device_ms * _MS   # the worker's thread
+    pacer.egress_began()
+    wall[0] += egress_wall_ms * _MS
+    cpu[0] += egress_cpu_ms * _MS
+    pacer.egress_ended(back_pressured)
+    assert pacer.sends_lead == leads
+    assert pacer.owed_ns() == (egress_cpu_ms - egress_wall_ms) * _MS
+    wall[0] += 10 * _MS         # a wait, at no CPU, is paid off
+    owed = (egress_cpu_ms - egress_wall_ms - 10) * _MS
+    assert pacer.owed_ns() == owed
+    # a take after a paced step carries what is still owed, never a credit
+    pacer.took(carry=True)
+    assert pacer.owed_ns() == max(owed, 0)
+    pacer.took()
+    assert pacer.owed_ns() == 0
+
+
+@pytest.mark.parametrize("case", ["paced", "owes_nothing", "lane_full",
+                                  "lane_fills_meanwhile"])
+async def test_a_paced_take_waits_the_cpu_and_a_back_pressured_one_never(
+        case):
+    """``_pace`` on a plane whose CPU clock is injected: a take that owes
+    60 ms of CPU waits until the wall has caught up with it, and the
+    account says how long (``pump_paced_us`` / ``pump_paced_steps``); a
+    take that owes nothing, or that finds the base lane full, does not
+    wait; a stager that fills the lane ends the wait."""
+    import time
+
+    from pushcdn_tpu.broker.device_plane import DevicePlane, DevicePlaneConfig
+    from pushcdn_tpu.broker.staging import StageResult
+    from pushcdn_tpu.proto.limiter import Bytes
+    from pushcdn_tpu.proto.message import Broadcast, serialize
+
+    plane = DevicePlane(None, DevicePlaneConfig(
+        num_user_slots=32, ring_slots=16, frame_bytes=1024,
+        bypass_max_items=0))
+    message = Broadcast(topics=[0], message=b"x")
+    frame = serialize(message)
+
+    def fill():
+        while plane.rings[0].free_slots:
+            assert plane.try_stage(message, Bytes(frame)) == \
+                StageResult.STAGED
+
+    async def fill_later():
+        await asyncio.sleep(0.02)
+        fill()
+
+    cpu = [0]
+    pacer = plane._pacer
+    pacer.cpu_ns = lambda: cpu[0]
+    owes = {"paced": 60, "owes_nothing": 0}.get(case, 5000) * _MS
+    if case == "lane_full":
+        fill()
+    filler = asyncio.create_task(fill_later()) \
+        if case == "lane_fills_meanwhile" else None
+    pacer.took()
+    cpu[0] += owes
+    t0 = time.monotonic_ns()
+    waited = await plane._pace()
+    elapsed = time.monotonic_ns() - t0
+    if filler is not None:
+        await filler
+    counters = plane._account.counters()
+    assert 0 <= waited <= elapsed
+    assert counters["pump_paced_us"] == waited // 1000
+    assert counters["pump_paced_steps"] == (waited > 0)
+    if case == "paced":
+        assert pacer.owed_ns() <= 0 < owes - 10 * _MS < waited
+        assert elapsed < owes + 500 * _MS
+    elif case == "lane_fills_meanwhile":
+        assert 10 * _MS < waited < elapsed < 2000 * _MS
+        assert not plane.rings[0].free_slots
+    else:
+        assert waited == 0 and elapsed < 50 * _MS
+
+
+# ---------------------------------------------------------------------------
+# the native batch: the sends of a step whose take found the base lane full,
+# or whose sends are the period off saturation, leave in one
+# ``native.send_batch`` call, for the links that are idle plain sockets;
+# every other step, and every other link, goes one by one.
 # ---------------------------------------------------------------------------
 
 _LANE = 16
@@ -853,26 +959,66 @@ def _broker_fd(broker, client) -> int:
         ._stream.writer.get_extra_info("socket").fileno()
 
 
-@pytest.mark.parametrize("frames", [_LANE, _LANE - 1],
-                         ids=["lane_full", "lane_not_full"])
+def _costly_sends(monkeypatch, plane) -> list:
+    """The pump observes that a step's sends are its period: each
+    hand-off one by one, and each native batch (where ``_record_batches``
+    is handed the returned list's ``cost`` as its ``before``), moves the
+    pacer's CPU and wall clocks on by a second together, as a send that
+    held the loop's core for that second would. A pace waits their
+    difference, so these seconds add nothing to it; the list's one
+    element is the CPU the test adds alone (``pacer_cpu_ns``)."""
+    import time
+
+    from pushcdn_tpu.broker.tasks import senders
+    ahead = [0, 0]   # both clocks; the CPU clock alone
+    pacer = plane._pacer
+    pacer.cpu_ns = lambda: time.process_time_ns() + ahead[0] + ahead[1]
+    pacer.wall_ns = lambda: time.monotonic_ns() + ahead[0]
+
+    def cost(*_):
+        ahead[0] += 10**9
+    real = senders.try_send_encoded_to_user_nowait
+
+    def hand_off(*args, **kwargs):
+        cost()
+        return real(*args, **kwargs)
+    monkeypatch.setattr(senders, "try_send_encoded_to_user_nowait", hand_off)
+    return [cost, ahead]
+
+
+@pytest.mark.parametrize("frames,sends_lead", [
+    (_LANE, False), (_LANE - 1, False), (_LANE - 1, True)],
+    ids=["lane_full", "lane_not_full", "lane_not_full_sends_lead"])
 async def test_only_a_back_pressured_step_is_sent_by_the_native_batch(
-        frames, monkeypatch):
+        frames, sends_lead, monkeypatch):
+    """Which step's sends leave in the native batch: one whose take found
+    the base lane full; one whose take found room, where the step before
+    it observed that its sends were the period (``CpuPacer``); no other.
+    A first step (here one short of the lane) goes one by one and makes
+    the observation; the second is the one judged."""
     from pushcdn_tpu.broker.device_plane import DevicePlaneConfig
 
+    first = [b"first %d" % i for i in range(_LANE - 1)]
     payloads = [b"frame %d" % i for i in range(frames)]
-    calls = _record_batches(monkeypatch)
     async with _served_over_tcp(
             3101, DevicePlaneConfig(**_BATCH_PLANE), [{0}] * 4) as (
                 broker, clients):
         plane = broker.device_plane
+        if sends_lead:
+            _costly_sends(monkeypatch, plane)
+        os.write(_socket_of(clients[0]), _wire(*first))
+        assert await _receive_all(clients, len(first)) == [first] * 4
+        assert (plane.steps, plane.egress_batched) == (1, 0)
+        assert plane._pacer.sends_lead == sends_lead
+        calls = _record_batches(monkeypatch)
         # one write, one read, one receive batch: the take finds what it
         # staged, all 16 slots or one short of them
         os.write(_socket_of(clients[0]), _wire(*payloads))
         got = await _receive_all(clients, frames)
         assert got == [payloads] * 4
         assert (plane.steps, plane.egress_inline, plane.egress_queued) == \
-            (1, 4, 0)
-        if frames == _LANE:
+            (2, 8, 0)
+        if frames == _LANE or sends_lead:
             assert plane.egress_batched == 4
             (fds, nbytes, sent), = calls
             assert sorted(fds) == sorted(_broker_fd(broker, c)
@@ -880,15 +1026,24 @@ async def test_only_a_back_pressured_step_is_sent_by_the_native_batch(
             assert sent == nbytes
         else:
             assert plane.egress_batched == 0 and not calls
-        assert plane.describe()["egress_batched"] == plane.egress_batched
+        described = plane.describe()
+        assert described["egress_batched"] == plane.egress_batched
+        assert described["egress_offsat_batched"] == (4 if sends_lead else 0)
+        if frames == _LANE:
+            assert described["pump_paced_steps"] == 0
 
 
+@pytest.mark.parametrize("regime", ["saturated", "off_saturation"])
 async def test_a_short_send_keeps_its_order_and_later_steps_queue_behind_it(
-        monkeypatch):
+        regime, monkeypatch):
     """A reader that stops reading: the batch's ``send()`` takes part of
     its stream, the transport gets the rest, and while it holds bytes the
     link is not batched again (its later streams join the transport's
-    buffer, then the writer's queue); the others go on in the batch."""
+    buffer, then the writer's queue); the others go on in the batch.
+    Saturated, every take finds the base lane full; off saturation it
+    finds room, and the steps are batched because their sends are the
+    period (after a first step one by one, before the reader stops) and
+    the takes after them paced."""
     import socket
 
     from pushcdn_tpu.broker.device_plane import DevicePlaneConfig
@@ -898,11 +1053,21 @@ async def test_a_short_send_keeps_its_order_and_later_steps_queue_behind_it(
     lane = 48
     rounds = [[(b"%d.%d|" % (r, i)).ljust(1000, b".") for i in range(lane)]
               for r in range(5)]
-    calls = _record_batches(monkeypatch)
+    off = regime == "off_saturation"
+    prime = [b"prime %d" % i for i in range(lane)] if off else []
     async with _served_over_tcp(
-            3110, DevicePlaneConfig(**dict(_BATCH_PLANE, ring_slots=lane)),
+            3110, DevicePlaneConfig(**dict(
+                _BATCH_PLANE, ring_slots=2 * lane if off else lane)),
             [{0}] * 3) as (broker, clients):
         plane = broker.device_plane
+        if off:
+            cost, _ = _costly_sends(monkeypatch, plane)
+            os.write(_socket_of(clients[0]), _wire(*prime))
+            assert await _receive_all(clients, lane) == [prime] * 3
+            assert plane._pacer.sends_lead and not plane.egress_batched
+            calls = _record_batches(monkeypatch, before=cost)
+        else:
+            calls = _record_batches(monkeypatch)
         stalled = clients[2]
         link = broker.connections.get_user_connection(stalled.public_key)
         stream = stalled._connection._stream
@@ -918,7 +1083,7 @@ async def test_a_short_send_keeps_its_order_and_later_steps_queue_behind_it(
             held = transport.get_write_buffer_size()
             assert (link.idle_fd(1) is None) == bool(held)
             os.write(_socket_of(clients[0]), _wire(*frames))
-            await wait_until(lambda: plane.steps == r + 1
+            await wait_until(lambda: plane.steps == r + 1 + off
                              and not plane._step_inflight)
             assert await _receive_all(clients[:2], lane) == [frames] * 2
             fds, nbytes, sent = calls[r]
@@ -934,12 +1099,86 @@ async def test_a_short_send_keeps_its_order_and_later_steps_queue_behind_it(
         # low-water mark, then the writer's queue took the streams
         assert short and unbatched and plane.egress_queued >= 1
         assert plane.egress_batched == sum(len(c[0]) for c in calls)
-        assert plane.egress_inline + plane.egress_queued == 3 * len(rounds)
+        assert plane.egress_inline + plane.egress_queued == \
+            3 * (len(rounds) + off)
+        described = plane.describe()
+        assert described["egress_offsat_batched"] == \
+            (plane.egress_batched if off else 0)
+        if not off:
+            assert described["pump_paced_steps"] == 0
         stream.reader._transport.resume_reading()
         everything = [f for frames in rounds for f in frames]
         got, = await _receive_all([stalled], len(everything))
         assert got == everything
         assert broker.connections.num_users == 3 and not plane.disabled
+
+
+def _marked(plane) -> tuple:
+    """``describe()`` and the clock at one instant: the pump's open state
+    is credited up to now first (re-entered), so the six state counters
+    sum to the pump's whole life."""
+    import time
+    account = plane._account
+    account.enter(account._state)
+    now = time.monotonic_ns()
+    return plane.describe(), now
+
+
+async def test_paced_takes_add_up_to_the_account_inside_the_gate(monkeypatch):
+    """Served over TCP off saturation, the sends the period
+    (``_costly_sends``) and each step 30 ms of CPU beyond its wall (added
+    on the worker thread, as the batch's threads add it): the first step
+    goes one by one, the three after it in the batch, and the take after
+    each of those waits until the wall since that step's take has caught
+    up with its CPU. The waits, timed around each ``_pace``, sum to what
+    ``pump_paced_us`` moved by, inside what ``pump_gate_us`` moved by,
+    and the six state counters still move by what the clock moved by."""
+    import time
+
+    from pushcdn_tpu.broker.device_plane import DevicePlaneConfig
+
+    rounds = [[b"round %d %d" % (r, i) for i in range(_LANE - 1)]
+              for r in range(4)]
+    async with _served_over_tcp(
+            3150, DevicePlaneConfig(**_BATCH_PLANE), [{0}] * 3) as (
+                broker, clients):
+        plane = broker.device_plane
+        cost, ahead = _costly_sends(monkeypatch, plane)
+        calls = _record_batches(monkeypatch, before=cost)
+
+        def in_worker(_n_taken):
+            ahead[1] += 30 * _MS
+        _record_takes(plane, in_worker)
+        paces = []      # (owed at the call, ns it returned, ns it took)
+        real = plane._pace
+
+        async def pace():
+            owed, t0 = plane._pacer.owed_ns(), time.monotonic_ns()
+            waited = await real()
+            paces.append((owed, waited, time.monotonic_ns() - t0))
+            return waited
+        plane._pace = pace
+        before, t0_ns = _marked(plane)
+        for frames in rounds:
+            os.write(_socket_of(clients[0]), _wire(*frames))
+            assert await _receive_all(clients, len(frames)) == [frames] * 3
+        await wait_until(lambda: len(paces) == 3 and not plane._between_steps)
+        after, t1_ns = _marked(plane)
+    moved = {k: after[k] - before[k] for k in after
+             if k.startswith("pump_") or k in ("steps", "egress_batched",
+                                                "egress_offsat_batched")}
+    assert (moved["steps"], len(calls)) == (4, 3)
+    assert moved["egress_batched"] == moved["egress_offsat_batched"] == 9
+    assert all(owed > 0 and owed - _MS <= waited <= took
+               for owed, waited, took in paces), paces
+    assert moved["pump_paced_steps"] == 3
+    assert moved["pump_paced_us"] == sum(w // 1000 for _, w, _ in paces)
+    assert sum(w for _, w, _ in paces) <= sum(t for *_, t in paces) \
+        <= sum(w for _, w, _ in paces) + 3 * 2 * _MS
+    assert moved["pump_paced_us"] <= moved["pump_gate_us"]
+    states = sum(moved[f"pump_{s}_us"] for s in (
+        "parked", "gate", "drain", "take", "worker", "egress"))
+    assert states == pytest.approx((t1_ns - t0_ns) / 1e3, abs=50)
 
 
 @pytest.mark.parametrize("err", ["EPIPE", "ECONNRESET"])

@@ -438,6 +438,11 @@ async def test_pump_account_partitions_the_pumps_wall_time(deploy):
     busy = after["worker_busy_us"] - before["worker_busy_us"]
     assert 0 < busy <= moved["worker"]
     assert after["steps"] - before["steps"] >= 4
+    # both planes say how often a step was batched off saturation and a
+    # take paced; never here (no step batched: the Memory transport
+    # queues every stream, and the group paces none)
+    assert after["egress_offsat_batched"] == after["pump_paced_steps"] \
+        == after["pump_paced_us"] == 0
 
 
 @pytest.mark.parametrize("deploy", [_single_plane, _mesh_group],
