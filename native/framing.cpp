@@ -21,6 +21,8 @@
 #include <thread>
 #include <vector>
 
+#include "frame_slot.h"
+
 namespace {
 
 // The body of the two send_batch entry points: entry i's bytes start at
@@ -70,16 +72,10 @@ int32_t pushcdn_pack_frames(
   for (int32_t i = 0; i < n && packed < capacity; ++i) {
     const int32_t len = lengths[i];
     if (len < 0 || len > frame_bytes) return packed;  // caller handles
-    uint8_t* slot = out_frames + (int64_t)packed * frame_bytes;
-    std::memcpy(slot, blob + offsets[i], (size_t)len);
-    if (len < frame_bytes) std::memset(slot + len, 0, (size_t)(frame_bytes - len));
-    out_kind[packed] = kinds[i];
-    out_len[packed] = len;
-    std::memcpy(out_tmask + (int64_t)packed * topic_words,
-                tmasks + (int64_t)i * topic_words,
-                (size_t)topic_words * sizeof(uint32_t));
-    out_dest[packed] = dests[i];
-    out_valid[packed] = 1;
+    pushcdn_pack_slot(out_frames, out_kind, out_len, out_tmask, out_dest,
+                      out_valid, packed, frame_bytes, topic_words,
+                      blob + offsets[i], len, kinds[i],
+                      tmasks + (int64_t)i * topic_words, dests[i]);
     ++packed;
   }
   return packed;

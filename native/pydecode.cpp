@@ -12,6 +12,11 @@
 // malformed frames go through the Python fallback callable so error
 // semantics (Error(DESERIALIZE)) are byte-identical.
 //
+// Beside it, the broker's receive-chunk stager (pushcdn_stage_chunk_py):
+// the user loop's per-frame scan and DevicePlane.stage_batch for one
+// FrameChunk in one call, reading the recipient's slot from the plane's
+// own dict, so the CPython API is what it needs as well.
+//
 // Loaded via ctypes.PyDLL (GIL held for the whole call). Compiled
 // separately from framing.cpp, which is a plain-C-ABI CDLL whose calls
 // release the GIL — mixing the two conventions in one library would make
@@ -21,6 +26,9 @@
 #include <Python.h>
 
 #include <cstdint>
+#include <vector>
+
+#include "frame_slot.h"
 
 #ifndef Py_T_OBJECT_EX  // pre-3.12 spelling
 #define Py_T_OBJECT_EX T_OBJECT_EX
@@ -242,6 +250,195 @@ PyObject* pushcdn_decode_frames_py(PyObject* buf, PyObject* offs,
   }
   Py_XDECREF(master);
   return out;
+}
+
+// Stage frames [start, len(offs)) of one receive chunk into a device
+// plane's lanes, in arrival order, up to the first frame the pass cannot
+// take: what DevicePlane.stage_batch does for the same frames after the
+// scalar scan, with the wire bytes read here. A frame is taken when it is
+// a Broadcast whose topics are all takeable (`topic_ok`: a valid topic,
+// not durable, inside the plane's mask words) and `broadcasts` is set, or
+// a Direct whose recipient `slot_of` (the plane's key -> slot dict) holds,
+// traced or not, well formed, and it fits the widest lane. A taken frame
+// goes best-fit into the narrowest lane it fits with free credit (status
+// 1) or, where none has, is held back (status 2: FULL); status bit 4 marks
+// a traced one. It takes nothing unless it can take `min_take` frames or
+// more. With `stop_full` set (a retry of held-back frames, which waits on
+// the first that finds no room, as a frame-by-frame retry would) it stops
+// there instead, sets counts[13] and returns the frames staged before it.
+//
+// `buf` is any object with the buffer protocol. `lanes` holds 8 int64 per
+// lane, ascending by width: frame_bytes, slots, and the addresses of the
+// ring's bytes, kind, length, topic mask, dest and valid columns; `used`
+// is each lane's fill, updated. `counts` gets 16 int64: the taken frames
+// per flow class (`classes`: topic -> class; a Direct is live), the staged
+// broadcasts per class and their bytes with the length prefix, then the
+// staged, the held-back and the traced totals. Returns the number of
+// frames taken, or -1 when the inputs are not a chunk's (nothing staged).
+namespace {
+
+constexpr uint8_t TRACE_FLAG = 0x80;
+constexpr uint8_t CLASS_LIVE = 2;
+constexpr int NCLS = 4;
+
+struct Taken {
+  Py_ssize_t off;
+  int32_t len, kind, dest;
+  uint8_t cls, traced;
+};
+
+// The wire layout deserialize() reads (proto/message.py): the kind byte,
+// a traced frame's 16- or 20-byte block, then a Direct's u32 recipient
+// length and recipient or a Broadcast's u16 topic count and topics, all
+// little-endian. False where the frame is not one the pass takes.
+bool classify(const uint8_t* f, Py_ssize_t n, PyObject* slot_of,
+              const uint8_t* topic_ok, const uint8_t* classes,
+              int32_t broadcasts, Taken* fr, uint32_t* mask) {
+  const uint8_t wire = f[0];
+  const uint8_t kind = wire & (uint8_t)~TRACE_FLAG;
+  Py_ssize_t at = 1;
+  if (wire & TRACE_FLAG) {
+    if (kind != KIND_DIRECT && kind != KIND_BROADCAST) return false;
+    if (n < 17) return false;
+    at = (f[16] & 0x80) ? 21 : 17;  // origin_ns's top bit: a view tag
+    if (n < at) return false;
+    fr->traced = 1;
+  }
+  fr->kind = kind;
+  if (kind == KIND_BROADCAST) {
+    if (!broadcasts || n < at + 2) return false;
+    const Py_ssize_t nt = (Py_ssize_t)f[at] | ((Py_ssize_t)f[at + 1] << 8);
+    const uint8_t* topics = f + at + 2;
+    if (nt == 0 || at + 2 + nt > n) return false;
+    for (Py_ssize_t t = 0; t < nt; ++t) {
+      const uint8_t topic = topics[t];
+      if (!topic_ok[topic]) return false;
+      mask[topic >> 5] |= 1u << (topic & 31);
+    }
+    fr->cls = classes[topics[0]];
+    return true;
+  }
+  if (kind != KIND_DIRECT || n < at + 4) return false;
+  const Py_ssize_t rlen = (Py_ssize_t)f[at] | ((Py_ssize_t)f[at + 1] << 8) |
+                          ((Py_ssize_t)f[at + 2] << 16) |
+                          ((Py_ssize_t)f[at + 3] << 24);
+  if (at + 4 + rlen > n) return false;
+  PyObject* key = PyBytes_FromStringAndSize((const char*)f + at + 4, rlen);
+  if (key == nullptr) {
+    PyErr_Clear();
+    return false;
+  }
+  PyObject* slot = PyDict_GetItemWithError(slot_of, key);  // borrowed
+  Py_DECREF(key);
+  if (slot == nullptr || !PyLong_Check(slot)) {
+    PyErr_Clear();
+    return false;
+  }
+  const long s = PyLong_AsLong(slot);
+  if (s < 0 || s > INT32_MAX) {
+    PyErr_Clear();
+    return false;
+  }
+  fr->dest = (int32_t)s;
+  return true;
+}
+
+int64_t stage_frames(const uint8_t* data, Py_ssize_t buf_len, PyObject* offs,
+                     PyObject* lens, Py_ssize_t start, PyObject* slot_of,
+                     const uint8_t* topic_ok, const uint8_t* classes,
+                     int32_t topic_words, int32_t broadcasts,
+                     int32_t min_take, int32_t stop_full,
+                     const int64_t* lanes, int32_t nlanes, int32_t* used,
+                     uint8_t* status, int64_t* counts) {
+  const Py_ssize_t count = PyList_GET_SIZE(offs);
+  if (PyList_GET_SIZE(lens) != count || start < 0 || start > count)
+    return -1;
+  for (int c = 0; c < 16; ++c) counts[c] = 0;
+  const int64_t widest = lanes[(nlanes - 1) * 8];
+
+  // pass 1: what each frame is, up to the first one the pass cannot take
+  static thread_local std::vector<Taken> frames;
+  static thread_local std::vector<uint32_t> masks;
+  frames.clear();
+  masks.clear();
+  for (Py_ssize_t i = start; i < count; ++i) {
+    const Py_ssize_t o = PyLong_AsSsize_t(PyList_GET_ITEM(offs, i));
+    const Py_ssize_t n = PyLong_AsSsize_t(PyList_GET_ITEM(lens, i));
+    if (o < 0 || n < 1 || o + n > buf_len || n > widest) {
+      PyErr_Clear();
+      break;
+    }
+    Taken fr{o, (int32_t)n, 0, -1, CLASS_LIVE, 0};
+    uint32_t mask[8] = {0, 0, 0, 0, 0, 0, 0, 0};
+    if (!classify(data + o, n, slot_of, topic_ok, classes, broadcasts, &fr,
+                  mask))
+      break;
+    frames.push_back(fr);
+    masks.insert(masks.end(), mask, mask + topic_words);
+  }
+  const Py_ssize_t taken = (Py_ssize_t)frames.size();
+  if (taken < min_take) return 0;
+
+  // pass 2: best-fit into a lane with free credit, packed in place
+  for (Py_ssize_t j = 0; j < taken; ++j) {
+    const Taken& fr = frames[j];
+    const int c = fr.cls < NCLS ? fr.cls : CLASS_LIVE;
+    uint8_t st = 2;
+    for (int32_t li = 0; li < nlanes; ++li) {
+      const int64_t* lane = lanes + li * 8;
+      const int32_t fb = (int32_t)lane[0];
+      if (fr.len > fb || used[li] >= (int32_t)lane[1]) continue;
+      pushcdn_pack_slot((uint8_t*)lane[2], (int32_t*)lane[3],
+                        (int32_t*)lane[4], (uint32_t*)lane[5],
+                        (int32_t*)lane[6], (uint8_t*)lane[7], used[li], fb,
+                        topic_words, data + fr.off, fr.len, fr.kind,
+                        masks.data() + j * topic_words, fr.dest);
+      used[li] += 1;
+      st = 1;
+      break;
+    }
+    if (st == 2 && stop_full) {
+      counts[13] = 1;
+      return j;
+    }
+    counts[c] += 1;
+    counts[14] += fr.traced;
+    status[j] = st | (fr.traced ? 4 : 0);
+    if (st == 1) {
+      counts[12] += 1;
+      if (fr.kind == KIND_BROADCAST) {
+        counts[NCLS + c] += 1;
+        counts[2 * NCLS + c] += 4 + (int64_t)fr.len;
+      }
+    } else {
+      counts[13] += 1;
+    }
+  }
+  return taken;
+}
+
+}  // namespace
+
+int64_t pushcdn_stage_chunk_py(
+    PyObject* buf, PyObject* offs, PyObject* lens, Py_ssize_t start,
+    PyObject* slot_of, const uint8_t* topic_ok, const uint8_t* classes,
+    int32_t topic_words, int32_t broadcasts, int32_t min_take,
+    int32_t stop_full, const int64_t* lanes, int32_t nlanes, int32_t* used,
+    uint8_t* status, int64_t* counts) {
+  if (!PyList_Check(offs) || !PyList_Check(lens) || !PyDict_Check(slot_of) ||
+      nlanes < 1 || topic_words < 1 || topic_words > 8)
+    return -1;
+  Py_buffer view;
+  if (PyObject_GetBuffer(buf, &view, PyBUF_SIMPLE) != 0) {
+    PyErr_Clear();
+    return -1;
+  }
+  const int64_t taken = stage_frames(
+      (const uint8_t*)view.buf, view.len, offs, lens, start, slot_of,
+      topic_ok, classes, topic_words, broadcasts, min_take, stop_full, lanes,
+      nlanes, used, status, counts);
+  PyBuffer_Release(&view);
+  return taken;
 }
 
 }  // extern "C"

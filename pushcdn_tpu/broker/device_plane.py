@@ -93,6 +93,7 @@ from pushcdn_tpu.parallel.router import (
     RouterState,
     routing_step_lanes_single,
 )
+from pushcdn_tpu.proto import flowclass
 from pushcdn_tpu.proto.error import Error
 from pushcdn_tpu.proto.limiter import Bytes
 from pushcdn_tpu.proto.message import (
@@ -270,6 +271,15 @@ class DevicePlane:
         # (each then retries alone: ``_stage_with_backpressure``)
         self.stage_full_results = 0
         self.stage_full_frames = 0
+        # the user loops' native pass from a receive chunk to the rings
+        # (``stage_chunk``): of ``frames_staged``, the frames it staged;
+        # the items it stopped in before their end (the rest went to the
+        # scalar scan); and of the frames it held back on a full ring,
+        # those the retry staged (``handlers._retry_full``)
+        self.ingress_native_frames = 0
+        self.ingress_native_stops = 0
+        self.ingress_native_restaged = 0
+        self._stager = None   # (stager, its arrays), made at first use
         # where the pump's wall time goes (made anew when the pump starts)
         self._account = PumpAccount()
         # the broker↔broker leg, counted by the receive loops: of
@@ -568,6 +578,102 @@ class DevicePlane:
                 self._lane_full.set()
         return results
 
+    def _stager_args(self):
+        """The chunk stager and the arrays it reads and writes, made once:
+        the lanes' widths, credits and column addresses (a ring's columns
+        live as long as the ring: ``take_batch`` copies them), the topics
+        a frame may carry (valid, not durable, inside the mask words), and
+        its per-call outputs. None where the native library is missing."""
+        if self._stager is None:
+            from pushcdn_tpu import native as native_mod
+            fn = native_mod.chunk_stager()
+            if fn is None:
+                self._stager = False
+                return None
+            lanes = np.array(
+                [[r.frame_bytes, r.slots]
+                 + [a.ctypes.data for a in r.columns()] for r in self.rings],
+                np.int64)
+            broker = self.broker
+            durable = broker.durable
+            barred = durable.topics if durable is not None else ()
+            topic_ok = np.zeros(256, np.uint8)
+            for t in broker.run_def.topics.valid:
+                if 0 <= t < 32 * self.config.topic_words and t not in barred:
+                    topic_ok[t] = 1
+            self._stager = (fn, lanes, np.zeros(len(self.rings), np.int32),
+                            topic_ok, np.zeros(16, np.int64),
+                            np.zeros(0, np.uint8))
+        return self._stager or None
+
+    def takes_chunks(self) -> bool:
+        """Whether ``stage_chunk`` can run: the plane serves and the
+        native library is there."""
+        return not self.disabled and self._stager_args() is not None
+
+    def stage_chunk(self, buf, offs, lens, first: int,
+                    retry: bool = False) -> tuple:
+        """Stage frames ``first..`` of one receive chunk (``buf`` holds
+        frame ``i`` at ``offs[i]``, ``lens[i]`` bytes long) in arrival
+        order, up to the first frame the native pass cannot take (a
+        control or malformed frame, an unknown recipient or topic, a
+        durable topic, a broadcast while unmirrored users exist, a frame
+        wider than the widest lane): each frame it takes goes where
+        ``stage_batch`` would put it after the scalar scan, staged or
+        held back by a full ring. Returns ``(taken, status, counts)``:
+        how many frames it took, per frame 1 (staged) or 2 (held back,
+        ``FULL``) with 4 added for a traced frame (valid until the next
+        call), and the stager's sums as a list (``native/pydecode.cpp``);
+        ``(0, None, None)`` where the pass cannot run. It takes nothing
+        where the plane is idle and fewer than ``bypass_max_items + 1``
+        could be taken: the scalar scan decides the idle bypass on the
+        whole batch.
+
+        ``retry``: frames a full ring held back, staged in order up to the
+        first that still finds no room, which ``counts[13]`` then flags
+        (a ``FULL`` handed back, as ``try_stage``'s); ``taken`` is the
+        frames staged. The caller keeps the idle bypass's check."""
+        args = self._stager_args()
+        if args is None or self.disabled:
+            return 0, None, None
+        fn, lanes, used, topic_ok, counts, status = args
+        n = len(offs) - first
+        if len(status) < n:
+            status = np.zeros(max(n, 2 * len(status)), np.uint8)
+            self._stager = args[:5] + (status,)
+        rings = self.rings
+        for li, ring in enumerate(rings):
+            used[li] = ring.slots - ring.free_slots
+        min_take = (self.config.bypass_max_items + 1
+                    if not retry and self._idle_bypass(0) else 0)
+        classes = flowclass.active_table()
+        taken = fn(buf, offs, lens, first, self.slots.by_key,
+                   topic_ok.ctypes.data, classes.ctypes.data,
+                   self.config.topic_words, 0 if self._unmirrored else 1,
+                   min_take, int(retry), lanes.ctypes.data, len(rings),
+                   used.ctypes.data, status.ctypes.data, counts.ctypes.data)
+        if taken < 0:
+            return 0, None, None
+        for li, ring in enumerate(rings):
+            ring.packed(int(used[li]) - (ring.slots - ring.free_slots))
+        counts = counts.tolist()
+        staged, full = counts[12], counts[13]
+        self.stage_full_results += full
+        if not retry:
+            self.stage_full_frames += full
+        if staged:
+            self.frames_staged += staged
+            if retry:
+                self.ingress_native_restaged += staged
+            else:
+                self.ingress_native_frames += staged
+            if self._staged_since is None:
+                self._staged_since = time.monotonic()
+            self._kick.set()
+            if not rings[0].free_slots:
+                self._lane_full.set()
+        return taken, status[:taken], counts
+
     def covered_broker_idents(self) -> set:
         """Broker identifiers whose delivery this plane covers — none for
         the single-shard plane (host links handle all peers)."""
@@ -627,6 +733,9 @@ class DevicePlane:
             "frames_drained": self.frames_drained,
             "stage_full_results": self.stage_full_results,
             "stage_full_frames": self.stage_full_frames,
+            "ingress_native_frames": self.ingress_native_frames,
+            "ingress_native_stops": self.ingress_native_stops,
+            "ingress_native_restaged": self.ingress_native_restaged,
             "link_frames_staged": self.link_frames_staged,
             "link_frames_forwarded": self.link_frames_forwarded,
             "messages_routed": self.messages_routed,

@@ -207,6 +207,11 @@ class MeshShardPlane:
             "h2d_puts": g.h2d_puts, "h2d_bytes": g.h2d_bytes,
             "stage_full_results": g.stage_full_results,
             "stage_full_frames": g.stage_full_frames,
+            # a shard stages directs into the group's buckets, which the
+            # native chunk pass does not pack: its user loops scan
+            "ingress_native_frames": 0,
+            "ingress_native_stops": 0,
+            "ingress_native_restaged": 0,
             **g._account.counters(),
             **loop_account(),
         }

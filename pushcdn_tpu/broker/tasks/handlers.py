@@ -29,13 +29,15 @@ import logging
 import time
 from typing import TYPE_CHECKING, List, Optional, Sequence
 
+import numpy as np
+
 from pushcdn_tpu.broker.staging import StageResult
 from pushcdn_tpu.parallel.spans import span
 from pushcdn_tpu.proto import flowclass
 from pushcdn_tpu.proto import ledger as ledger_mod
 from pushcdn_tpu.proto import metrics as metrics_mod
 from pushcdn_tpu.proto import trace as trace_mod
-from pushcdn_tpu.proto.def_ import HookResult
+from pushcdn_tpu.proto.def_ import HookResult, no_hook
 from pushcdn_tpu.proto.error import Error
 from pushcdn_tpu.proto.limiter import Bytes
 from pushcdn_tpu.proto.message import (
@@ -49,6 +51,7 @@ from pushcdn_tpu.proto.message import (
     UserSync,
     deserialize,
 )
+from pushcdn_tpu.proto.transport.base import FrameChunk
 
 
 def _ingress_class(message) -> int:
@@ -581,12 +584,201 @@ def _route_after_stage(broker: "Broker", device, stage_items: list,
 # user receive loop
 # ---------------------------------------------------------------------------
 
+def _takes_chunks(broker: "Broker", device, hook) -> bool:
+    """Whether the user loop drains whole receive chunks through the
+    native pass (``DevicePlane.stage_chunk``): where
+    ``cutthrough.acquire``'s rules would route natively (the default
+    hook, no sharded durable topics), on a plane that stages into rings
+    of its own (a mesh group's shard has none) and serves."""
+    if device is None or hook is not no_hook:
+        return False
+    takes = getattr(device, "takes_chunks", None)
+    if takes is None or not takes():
+        return False
+    durable = broker.durable
+    return not (durable is not None and durable.enabled
+                and broker.connections.num_shards > 1)
+
+
+def _frame_at(item, i: int) -> tuple:
+    """Where frame ``i`` of a receive item lies: ``(buf, offset,
+    length)`` (a bare ``Bytes`` is a chunk of one)."""
+    if type(item) is FrameChunk:
+        return item.buf, item.offs[i], item.lens[i]
+    return item.data, 0, len(item.data)
+
+
+def _entry(item, i: int, topics, owned: list) -> tuple:
+    """Frame ``i`` of a receive item as the scan's ``(message, raw,
+    pruned)`` entry; a ``Bytes`` it makes joins ``owned``."""
+    if type(item) is FrameChunk:
+        raw = item.frame(i)
+        owned.append(raw)
+    else:
+        raw = item
+    message = deserialize(raw.data)
+    pruned = (topics.prune(message.topics)[0]
+              if isinstance(message, Broadcast) else None)
+    return message, raw, pruned
+
+
+def _credit_staged(counts, status, frames) -> None:
+    """What a staged frame the pass took still owes where no entry of
+    the scan carries it: a broadcast's class counters (the stager's
+    sums), a traced frame's ingress span. ``frames(j)`` is frame ``j``'s
+    ``(buf, offset, length)``."""
+    n = flowclass.N_CLASSES
+    for cls in range(n):
+        if counts[n + cls]:
+            metrics_mod.CLASS_FRAMES_IN[cls].inc(counts[n + cls])
+            metrics_mod.CLASS_BYTES_IN[cls].inc(counts[2 * n + cls])
+    if counts[14]:
+        for j in np.flatnonzero(status == 5):
+            buf, o, ln = frames(int(j))
+            _emit_staged_trace(deserialize(buf[o:o + ln]))
+
+
+def _native_pass(broker: "Broker", device, topics, items: list,
+                 held: list, held_results: list, held_at: list,
+                 owned: list):
+    """The native pass over one drained batch (``recv_frames``): item by
+    item in arrival order, each ``FrameChunk`` (a bare ``Bytes`` is a
+    chunk of one) staged by one call up to the first frame the pass
+    cannot take. Returns the frames it left, in order, as ``Bytes`` for
+    the scalar scan, and how many frames it took and staged.
+
+    Of the frames it took, those that still owe host work join ``held``
+    as the scan's entries, their results in ``held_results`` and where
+    they lie in ``held_at`` (``(item, index)``): a frame a full ring held
+    back (``_retry_full`` retries it), as ``(None, None, None)`` until
+    something needs its message, and where a peer link exists every one,
+    whole (a staged broadcast still owes the peers their copy,
+    ``_route_after_stage``). The rest owe only what the stager summed:
+    the ingress ledger's classes, a staged broadcast's class counters, a
+    traced frame's span. ``owned`` gets the ``Bytes`` the caller releases
+    after the batch."""
+    linked = broker.connections.num_brokers > 0
+    took = staged = 0
+    for idx, item in enumerate(items):
+        chunk = type(item) is FrameChunk
+        if chunk:
+            first = item.first
+            n = len(item.offs) - first
+            k, status, counts = device.stage_chunk(item.buf, item.offs,
+                                                   item.lens, first)
+        else:
+            first, n = 0, 1
+            k, status, counts = device.stage_chunk(item.data, [0],
+                                                   [len(item.data)], 0)
+        if k:
+            took += k
+            staged += counts[12]
+            for cls in range(flowclass.N_CLASSES):
+                if counts[cls]:
+                    ledger_mod.note_ingress(cls, counts[cls])
+            if linked:
+                for j in range(k):
+                    held.append(_entry(item, first + j, topics, owned))
+                    held_results.append(StageResult.STAGED if status[j] & 1
+                                        else StageResult.FULL)
+                    held_at.append((item, first + j))
+            else:
+                _credit_staged(counts, status,
+                               lambda j: _frame_at(item, first + j))
+                for j in (np.flatnonzero(status & 2) if counts[13] else ()):
+                    held.append((None, None, None))
+                    held_results.append(StageResult.FULL)
+                    held_at.append((item, first + int(j)))
+            if not chunk:
+                owned.append(item)
+        if k < n:
+            device.ingress_native_stops += 1
+            if chunk:
+                item.skip(k)
+            return _as_bytes(items[idx:]), took, staged
+    return [], took, staged
+
+
+def _as_bytes(items: list) -> list:
+    """What is left of ``recv_frames`` items, frame by frame as ``Bytes``
+    (a chunk's hand out its permit), for the scan."""
+    out = []
+    for item in items:
+        if type(item) is FrameChunk:
+            while item.remaining:
+                out.append(item.take())
+        else:
+            out.append(item)
+    return out
+
+
+async def _retry_full(device, topics, stage_items: list, results: list,
+                      held_at: list, owned: list) -> None:
+    """The full ring's retries, frame by frame in the order they came:
+    each held-back frame blocks THIS reader until the pump has made room
+    (``_stage_with_backpressure``). A run of frames the native pass held
+    back goes to the stager at once, which stages them in order up to the
+    first that still finds no room, as the frame-by-frame retry would,
+    and is retried from there after the same 2 ms; a frame it cannot take
+    (the plane idle, disabled, or what the frame needs gone meanwhile)
+    takes ``_stage_with_backpressure`` itself, as does every frame of the
+    scalar scan's."""
+    n_held = len(held_at)
+    i = 0
+    while i < len(results):
+        if results[i] != StageResult.FULL:
+            i += 1
+            continue
+        at = held_at[i] if i < n_held else None
+        if at is not None and device.takes_chunks() \
+                and not device._idle_bypass(1):
+            item = at[0]
+            j = i
+            while j < n_held and results[j] == StageResult.FULL \
+                    and held_at[j][0] is item:
+                j += 1
+            idxs = [held_at[x][1] for x in range(i, j)]
+            if type(item) is FrameChunk:
+                buf = item.buf
+                offs = [item.offs[x] for x in idxs]
+                lens = [item.lens[x] for x in idxs]
+            else:
+                buf, offs, lens = item.data, [0], [len(item.data)]
+            k, status, counts = device.stage_chunk(buf, offs, lens, 0,
+                                                   retry=True)
+            if counts is not None:
+                results[i:i + k] = [StageResult.STAGED] * k
+                if k and stage_items[i][0] is None:
+                    # entries of their own would carry these (a peer link)
+                    _credit_staged(counts, status,
+                                   lambda x: (buf, offs[x], lens[x]))
+                i += k
+                if i == j:
+                    continue
+                if counts[13]:
+                    await asyncio.sleep(0.002)
+                    continue
+            at = held_at[i]
+        # frame i, whole, through the frame-by-frame retry
+        entry = stage_items[i]
+        if entry[0] is None:
+            entry = stage_items[i] = _entry(at[0], at[1], topics, owned)
+        results[i] = await _stage_with_backpressure(device, entry[0],
+                                                    entry[1])
+        if at is not None and results[i] == StageResult.STAGED:
+            device.ingress_native_restaged += 1
+        i += 1
+
+
 async def user_receive_loop(broker: "Broker", public_key: bytes,
                             connection) -> None:
     """Pump one user's messages until the connection dies or the user is
     kicked (user/handler.rs:104-161). Messages are drained and routed in
     batches: one ``recv_raw_many`` wakeup routes every pending frame, and
-    the fan-out goes out as per-peer ``send_raw_many`` batches."""
+    the fan-out goes out as per-peer ``send_raw_many`` batches. On a
+    device plane that takes them, the batch is drained as whole receive
+    chunks, and the native pass stages what it can before the scan sees
+    the rest (``_native_pass``)."""
     from pushcdn_tpu.broker.tasks import cutthrough  # lazy: import cycle
     hook = broker.run_def.user_def.hook
     topics = broker.run_def.topics
@@ -605,164 +797,12 @@ async def user_receive_loop(broker: "Broker", public_key: bytes,
                                               is_user=True,
                                               conn=connection)
                 continue
-            raws = await connection.recv_raw_many()
-            metrics_mod.ROUTE_SCALAR_FRAMES.inc(len(raws))
-            egress = EgressBatch(broker)
-            interest_cache: dict = {}
-            # device-eligible (message, raw, pruned_topics) collected during
-            # the scan and staged in ONE stage_batch call after it (one
-            # native pack per size lane instead of a per-frame ring push)
-            stage_items: list = []
             device = broker.device_plane
-            try:
-                with span("ingress.scan", frames=len(raws)):
-                    for raw in raws:
-                        try:
-                            message = deserialize(raw.data)
-                        except Error:
-                            # malformed frame ⇒ disconnect
-                            # (user/handler.rs:106-118)
-                            logger.info(
-                                "user %s sent malformed frame; disconnecting",
-                                mnemonic(public_key))
-                            connection.flightrec.record("malformed-frame",
-                                                        abnormal=True)
-                            ledger_mod.record_fate("dropped", "malformed",
-                                                   flowclass.CLASS_NONE)
-                            alive = False
-                            break
-                        ledger_mod.note_ingress(_ingress_class(message))
-                        result = hook(public_key, message)
-                        if result == HookResult.SKIP:
-                            continue
-                        if result == HookResult.DISCONNECT:
-                            alive = False
-                            break
-
-                        if isinstance(message, Direct):
-                            # device path covers local-recipient delivery (and,
-                            # for a mesh-group plane, any recipient in the
-                            # group); host path covers the rest
-                            if device is not None:
-                                stage_items.append((message, raw, None))
-                                continue
-                            a0 = egress.appended
-                            route_direct(broker, message.recipient, raw,
-                                         to_user_only=False, egress=egress)
-                            _emit_scalar_trace(message, egress, a0)
-                        elif isinstance(message, Broadcast):
-                            pruned, _bad = topics.prune(message.topics)
-                            if pruned:
-                                # durable topics (ISSUE 14): retention stamp in
-                                # the same synchronous block as the route
-                                # decision; a False return means the owning
-                                # shard fans out through its ordered drainer
-                                durable = broker.durable
-                                if durable is not None and \
-                                        not durable.on_publish(
-                                            pruned, message, raw,
-                                            to_users_only=False):
-                                    continue
-                                if device is not None:
-                                    stage_items.append((message, raw, pruned))
-                                    continue
-                                a0 = egress.appended
-                                route_broadcast(
-                                    broker, pruned, raw, to_users_only=False,
-                                    egress=egress,
-                                    interest_cache=interest_cache,
-                                    raw_topics=message.topics)
-                                _emit_scalar_trace(message, egress, a0)
-                        elif isinstance(message, Subscribe):
-                            pruned, bad = topics.prune(message.topics)
-                            if bad:
-                                # unknown topic ⇒ disconnect (subscribe.rs
-                                # test behavior: invalid-topic
-                                # subscriptions kick)
-                                alive = False
-                                break
-                            adm = broker.admission
-                            if adm is not None and \
-                                    not adm.allow_subscribe(connection):
-                                # over-rate: drop the mutation, notify typed
-                                # through the ordered egress path (ISSUE 7)
-                                adm.shed_subscribe(public_key, connection,
-                                                   egress)
-                                continue
-                            broker.connections.subscribe_user_to(public_key,
-                                                                 pruned)
-                        elif isinstance(message, Unsubscribe):
-                            adm = broker.admission
-                            if adm is not None and \
-                                    not adm.allow_subscribe(connection):
-                                adm.shed_subscribe(public_key, connection,
-                                                   egress)
-                                continue
-                            pruned, _bad = topics.prune(message.topics)
-                            broker.connections.unsubscribe_user_from(
-                                public_key, pruned)
-                        elif isinstance(message, SubscribeFrom):
-                            # durable replay subscribe (ISSUE 14): registration
-                            # + ring snapshot + replay enqueue in one
-                            # synchronous block (the handover invariant)
-                            adm = broker.admission
-                            if adm is not None and \
-                                    not adm.allow_subscribe(connection):
-                                adm.shed_subscribe(public_key, connection,
-                                                   egress)
-                                continue
-                            durable = broker.durable
-                            if durable is None or \
-                                    not durable.handle_subscribe_from(
-                                        public_key, message, connection):
-                                alive = False
-                                break
-                        else:
-                            # users may not send auth or sync messages
-                            # post-handshake
-                            alive = False
-                            break
-
-                # phase 2: batch-stage the collected device-eligible
-                # messages, then host-route whatever the device didn't take
-                if stage_items:
-                    with span("ingress.stage",
-                              frames=len(stage_items)) as sp:
-                        results = device.stage_batch(
-                            [(m, r) for m, r, _ in stage_items])
-                        sp.set_metadata(
-                            staged=results.count(StageResult.STAGED))
-                    if StageResult.FULL in results:
-                        # a full ring blocks THIS reader until the pump
-                        # has made room, frame by frame in the order they
-                        # came; nothing below awaits, so the pass that
-                        # routes the batch is one flat span
-                        for i, (message, raw, _) in enumerate(stage_items):
-                            if results[i] == StageResult.FULL:
-                                results[i] = await _stage_with_backpressure(
-                                    device, message, raw)
-                    # the broker↔broker leg of the batch, and the host
-                    # route of what the device did not take. Spanned only
-                    # where a peer link exists: a lone broker pays nothing
-                    if broker.connections.num_brokers:
-                        with span("links.forward",
-                                  frames=len(stage_items)) as sp:
-                            forwards = egress.forwarded
-                            _route_after_stage(broker, device, stage_items,
-                                               results, egress,
-                                               interest_cache)
-                            forwards = egress.forwarded - forwards
-                            device.link_frames_forwarded += forwards
-                            sp.set_metadata(forwards=forwards)
-                    else:
-                        _route_after_stage(broker, device, stage_items,
-                                           results, egress, interest_cache)
-            finally:
-                try:
-                    await egress.flush()
-                finally:
-                    for raw in raws:
-                        raw.release()
+            native = _takes_chunks(broker, device, hook)
+            items = await (connection.recv_frames() if native
+                           else connection.recv_raw_many())
+            alive = await _route_user_batch(broker, public_key, connection,
+                                            hook, topics, items, native)
     except (Error, asyncio.IncompleteReadError):
         pass  # connection died: fall through to removal
     except asyncio.CancelledError:
@@ -775,6 +815,206 @@ async def user_receive_loop(broker: "Broker", public_key: bytes,
         if broker.connections.get_user_connection(public_key) is connection:
             broker.connections.remove_user(public_key, reason="receive loop ended")
         broker.update_metrics()
+
+
+async def _route_user_batch(broker: "Broker", public_key: bytes,
+                            connection, hook, topics, items: list,
+                            native: bool) -> bool:
+    """One drained batch of a user's frames, in the order they came:
+    the native pass (``native``: ``items`` are ``recv_frames``'), then
+    the scalar scan of what it left, one ``stage_batch`` of what that
+    scan collected, the full ring's retries and the host's part of the
+    route. False once the user is to be disconnected."""
+    alive = True
+    device = broker.device_plane
+    total = (sum(i.remaining if type(i) is FrameChunk else 1 for i in items)
+             if native else len(items))
+    metrics_mod.ROUTE_SCALAR_FRAMES.inc(total)
+    egress = EgressBatch(broker)
+    interest_cache: dict = {}
+    # of the frames the native pass took, those that still owe host work,
+    # as the scan's entries, and their stage results
+    held: list = []
+    held_results: list = []
+    held_at: list = []
+    owned: list = []
+    took = staged = 0
+    raws = items
+    # device-eligible (message, raw, pruned_topics) collected during
+    # the scan and staged in ONE stage_batch call after it (one
+    # native pack per size lane instead of a per-frame ring push)
+    stage_items: list = []
+    try:
+        with span("ingress.scan", frames=total):
+            if native and device._idle_bypass(total):
+                # the idle bypass host-routes the whole batch: the scan's
+                raws = _as_bytes(items)
+            elif native:
+                raws, took, staged = _native_pass(
+                    broker, device, topics, items, held, held_results,
+                    held_at, owned)
+            for raw in raws:
+                try:
+                    message = deserialize(raw.data)
+                except Error:
+                    # malformed frame ⇒ disconnect
+                    # (user/handler.rs:106-118)
+                    logger.info(
+                        "user %s sent malformed frame; disconnecting",
+                        mnemonic(public_key))
+                    connection.flightrec.record("malformed-frame",
+                                                abnormal=True)
+                    ledger_mod.record_fate("dropped", "malformed",
+                                           flowclass.CLASS_NONE)
+                    alive = False
+                    break
+                ledger_mod.note_ingress(_ingress_class(message))
+                result = hook(public_key, message)
+                if result == HookResult.SKIP:
+                    continue
+                if result == HookResult.DISCONNECT:
+                    alive = False
+                    break
+
+                if isinstance(message, Direct):
+                    # device path covers local-recipient delivery (and,
+                    # for a mesh-group plane, any recipient in the
+                    # group); host path covers the rest
+                    if device is not None:
+                        stage_items.append((message, raw, None))
+                        continue
+                    a0 = egress.appended
+                    route_direct(broker, message.recipient, raw,
+                                 to_user_only=False, egress=egress)
+                    _emit_scalar_trace(message, egress, a0)
+                elif isinstance(message, Broadcast):
+                    pruned, _bad = topics.prune(message.topics)
+                    if pruned:
+                        # durable topics: retention stamp in
+                        # the same synchronous block as the route
+                        # decision; a False return means the owning
+                        # shard fans out through its ordered drainer
+                        durable = broker.durable
+                        if durable is not None and \
+                                not durable.on_publish(
+                                    pruned, message, raw,
+                                    to_users_only=False):
+                            continue
+                        if device is not None:
+                            stage_items.append((message, raw, pruned))
+                            continue
+                        a0 = egress.appended
+                        route_broadcast(
+                            broker, pruned, raw, to_users_only=False,
+                            egress=egress,
+                            interest_cache=interest_cache,
+                            raw_topics=message.topics)
+                        _emit_scalar_trace(message, egress, a0)
+                elif isinstance(message, Subscribe):
+                    pruned, bad = topics.prune(message.topics)
+                    if bad:
+                        # unknown topic ⇒ disconnect (subscribe.rs
+                        # test behavior: invalid-topic
+                        # subscriptions kick)
+                        alive = False
+                        break
+                    adm = broker.admission
+                    if adm is not None and \
+                            not adm.allow_subscribe(connection):
+                        # over-rate: drop the mutation, notify typed
+                        # through the ordered egress path
+                        adm.shed_subscribe(public_key, connection,
+                                           egress)
+                        continue
+                    broker.connections.subscribe_user_to(public_key,
+                                                         pruned)
+                elif isinstance(message, Unsubscribe):
+                    adm = broker.admission
+                    if adm is not None and \
+                            not adm.allow_subscribe(connection):
+                        adm.shed_subscribe(public_key, connection,
+                                           egress)
+                        continue
+                    pruned, _bad = topics.prune(message.topics)
+                    broker.connections.unsubscribe_user_from(
+                        public_key, pruned)
+                elif isinstance(message, SubscribeFrom):
+                    # durable replay subscribe: registration
+                    # + ring snapshot + replay enqueue in one
+                    # synchronous block (the handover invariant)
+                    adm = broker.admission
+                    if adm is not None and \
+                            not adm.allow_subscribe(connection):
+                        adm.shed_subscribe(public_key, connection,
+                                           egress)
+                        continue
+                    durable = broker.durable
+                    if durable is None or \
+                            not durable.handle_subscribe_from(
+                                public_key, message, connection):
+                        alive = False
+                        break
+                else:
+                    # users may not send auth or sync messages
+                    # post-handshake
+                    alive = False
+                    break
+
+        # phase 2: batch-stage the collected device-eligible messages
+        # (after what the native pass staged, in the order they came),
+        # then host-route whatever the device did not take
+        if stage_items or took:
+            with span("ingress.stage",
+                      frames=took + len(stage_items)) as sp:
+                results = device.stage_batch(
+                    [(m, r) for m, r, _ in stage_items]) \
+                    if stage_items else []
+                sp.set_metadata(
+                    staged=staged + results.count(StageResult.STAGED))
+            stage_items[:0] = held
+            results[:0] = held_results
+            if StageResult.FULL in results:
+                # a full ring blocks THIS reader until the pump has made
+                # room, frame by frame in the order they came; nothing
+                # below awaits, so the pass that routes the batch is one
+                # flat span
+                await _retry_full(device, topics, stage_items, results,
+                                  held_at, owned)
+            if held:
+                # a held-back frame the retry staged natively owes nothing
+                # more (the stager's sums were credited)
+                kept = [x for x in range(len(stage_items))
+                        if stage_items[x][0] is not None]
+                stage_items = [stage_items[x] for x in kept]
+                results = [results[x] for x in kept]
+            # the broker↔broker leg of the batch, and the host route of
+            # what the device did not take. Spanned only where a peer
+            # link exists: a lone broker pays nothing
+            if broker.connections.num_brokers:
+                with span("links.forward",
+                          frames=len(stage_items)) as sp:
+                    forwards = egress.forwarded
+                    _route_after_stage(broker, device, stage_items,
+                                       results, egress, interest_cache)
+                    forwards = egress.forwarded - forwards
+                    device.link_frames_forwarded += forwards
+                    sp.set_metadata(forwards=forwards)
+            else:
+                _route_after_stage(broker, device, stage_items, results,
+                                   egress, interest_cache)
+    finally:
+        try:
+            await egress.flush()
+        finally:
+            for raw in raws:
+                raw.release()
+            for raw in owned:
+                raw.release()
+            if native:
+                for item in items:
+                    if type(item) is FrameChunk:
+                        item.release()
+    return alive
 
 
 # ---------------------------------------------------------------------------

@@ -26,6 +26,8 @@ import numpy as np
 
 _REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 _SRC = os.path.join(_REPO, "native", "framing.cpp")
+# the slot layout both the framing and the pydecode libraries pack into
+_FRAME_SLOT_H = os.path.join(_REPO, "native", "frame_slot.h")
 _BUILD_DIR = os.path.join(_REPO, ".build")
 
 _lock = threading.Lock()
@@ -101,7 +103,8 @@ def build_all() -> Dict[str, bool]:
 
 
 def _compile() -> Optional[ctypes.CDLL]:
-    lib = _build_lib("framing", (_SRC,), ctypes.CDLL, ("-pthread",))
+    lib = _build_lib("framing", (_SRC, _FRAME_SLOT_H), ctypes.CDLL,
+                     ("-pthread",))
     if lib is None:
         return None
 
@@ -170,7 +173,7 @@ def available() -> bool:
 # lib's plain-C calls release it.
 
 _PYDECODE_SRC = os.path.join(_REPO, "native", "pydecode.cpp")
-_pydecode_fn = None
+_pydecode_lib = None
 _pydecode_tried = False
 
 
@@ -182,8 +185,8 @@ def _compile_pydecode():
     # upgrade must recompile, not reuse.
     import sysconfig
     abi = sysconfig.get_config_var("SOABI") or "unknown-abi"
-    lib = _build_lib(f"pydecode-{abi}", (_PYDECODE_SRC,), ctypes.PyDLL,
-                     ("-I", sysconfig.get_paths()["include"]))
+    lib = _build_lib(f"pydecode-{abi}", (_PYDECODE_SRC, _FRAME_SLOT_H),
+                     ctypes.PyDLL, ("-I", sysconfig.get_paths()["include"]))
     if lib is None:
         return None
     fn = lib.pushcdn_decode_frames_py
@@ -191,7 +194,24 @@ def _compile_pydecode():
     fn.argtypes = [ctypes.py_object, ctypes.py_object, ctypes.py_object,
                    ctypes.c_ssize_t, ctypes.py_object, ctypes.py_object,
                    ctypes.py_object, ctypes.c_ssize_t]
-    return fn
+    vp = ctypes.c_void_p
+    fn = lib.pushcdn_stage_chunk_py
+    fn.restype = ctypes.c_int64
+    fn.argtypes = [ctypes.py_object, ctypes.py_object, ctypes.py_object,
+                   ctypes.c_ssize_t, ctypes.py_object, vp, vp,
+                   ctypes.c_int32, ctypes.c_int32, ctypes.c_int32,
+                   ctypes.c_int32, vp, ctypes.c_int32, vp, vp, vp]
+    return lib
+
+
+def _pydecode_library():
+    global _pydecode_lib, _pydecode_tried
+    if _pydecode_lib is None and not _pydecode_tried:
+        with _lock:
+            if _pydecode_lib is None and not _pydecode_tried:
+                _pydecode_lib = _compile_pydecode()
+                _pydecode_tried = True
+    return _pydecode_lib
 
 
 def pydecode():
@@ -205,13 +225,21 @@ def pydecode():
     (message.ZERO_COPY_MIN is the callers' threshold). Raises whatever
     ``fallback`` raises on malformed frames.
     """
-    global _pydecode_fn, _pydecode_tried
-    if _pydecode_fn is None and not _pydecode_tried:
-        with _lock:
-            if _pydecode_fn is None and not _pydecode_tried:
-                _pydecode_fn = _compile_pydecode()
-                _pydecode_tried = True
-    return _pydecode_fn
+    lib = _pydecode_library()
+    return None if lib is None else lib.pushcdn_decode_frames_py
+
+
+def chunk_stager():
+    """The receive-chunk stager of the same library, or None when it is
+    unavailable (``DevicePlane.stage_chunk`` wraps it).
+
+    Signature: ``fn(buf, offs, lens, start, slot_of, topic_ok, classes,
+    topic_words, broadcasts, min_take, stop_full, lanes, nlanes, used,
+    status, counts)`` → frames taken, or -1 (native/pydecode.cpp,
+    ``pushcdn_stage_chunk_py``); the pointers are addresses of numpy
+    arrays the caller keeps alive."""
+    lib = _pydecode_library()
+    return None if lib is None else lib.pushcdn_stage_chunk_py
 
 
 def _ptr(a: np.ndarray, ctype):

@@ -163,6 +163,12 @@ class UserSlots:
     def slot_of(self, public_key: bytes) -> Optional[int]:
         return self._key_to_slot.get(public_key)
 
+    @property
+    def by_key(self) -> Dict[bytes, int]:
+        """The live key -> slot dict, read-only by contract: where the
+        native chunk stager looks a direct's recipient up."""
+        return self._key_to_slot
+
     def key_of(self, slot: int) -> Optional[bytes]:
         return self._slot_to_key[slot]
 
@@ -217,6 +223,18 @@ class FrameRing:
     @property
     def free_slots(self) -> int:
         return self.slots - self._used
+
+    def columns(self) -> tuple:
+        """The bytes, kind, length, topic-mask, dest and valid columns, for
+        a packer that fills slots in place from the cursor (they live as
+        long as the ring: ``take_batch`` copies them)."""
+        return (self._bytes, self._kind, self._length, self._topic_mask,
+                self._dest, self._valid)
+
+    def packed(self, n: int) -> None:
+        """Take ``n`` slots from the cursor on that such a packer filled."""
+        self._used += n
+        self._next += n
 
     def _alloc(self) -> Optional[int]:
         # Slots fill sequentially and are only freed wholesale by

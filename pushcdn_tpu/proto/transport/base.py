@@ -111,6 +111,29 @@ class FrameChunk:
             self._master.release()  # fully handed out
         return b
 
+    @property
+    def first(self) -> int:
+        """Index of the next frame :meth:`take` hands out."""
+        return self._pos
+
+    def frame(self, i: int) -> Bytes:
+        """Frame ``i`` (not yet handed out) as a permit-sharing
+        :class:`Bytes`, leaving the cursor where it is."""
+        b = self._master.clone()
+        o = self.offs[i]
+        b.data = self.buf[o:o + self.lens[i]]
+        return b
+
+    def skip(self, n: int) -> None:
+        """Move the cursor past ``n`` frames a whole-chunk consumer dealt
+        with in place (releasing the chunk's own reference once none is
+        left, as :meth:`take` does)."""
+        if n <= 0:
+            return
+        self._pos += n
+        if self._pos == len(self.offs):
+            self._master.release()
+
     def views(self):
         """Zero-copy memoryviews of every remaining frame; the caller owns
         consumption and MUST call :meth:`release` afterwards."""

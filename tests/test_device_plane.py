@@ -701,15 +701,26 @@ async def test_the_plane_is_idle_only_while_the_pump_is_parked(
         sock = _socket_of(clients[0])
         armed = [_wire(b"lone")] if when == "draining" else []
         _write_during_egress(monkeypatch, sock, armed)
-        seen = []   # (batch size, was the plane idle, results) per batch
-        real = plane.stage_batch
+        # (frames, was the plane idle, results) per batch the scalar
+        # scan staged, and per chunk the native pass took frames of
+        seen = []
+        real, real_chunk = plane.stage_batch, plane.stage_chunk
 
         def stage_batch(items):
             idle = plane._idle_bypass(len(items))
             results = real(items)
             seen.append((len(items), idle, results))
             return results
-        plane.stage_batch = stage_batch
+
+        def stage_chunk(buf, offs, lens, first):
+            idle = plane._idle_bypass(len(offs) - first)
+            taken, status, counts = real_chunk(buf, offs, lens, first)
+            if taken:
+                seen.append((taken, idle, [
+                    StageResult.STAGED if s & 1 else StageResult.FULL
+                    for s in status]))
+            return taken, status, counts
+        plane.stage_batch, plane.stage_chunk = stage_batch, stage_chunk
 
         burst = [b"burst %d" % i for i in range(4)]
         os.write(sock, _wire(*burst))   # one read, one batch over the bypass

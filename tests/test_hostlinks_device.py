@@ -140,18 +140,34 @@ class Mesh:
 def _count_full_rings_on_links(monkeypatch, mesh):
     """How often a peer's link (and a user's own) met a full ring: the
     retry that blocks the reader, told apart by where the frame's
-    publisher lives."""
+    publisher lives. A peer's link hands each such frame to
+    ``_stage_with_backpressure``; a user loop hands its batch's to
+    ``_retry_full`` (which stages the native pass's in runs, and the
+    rest through the same function)."""
+    from pushcdn_tpu.broker.staging import StageResult
     from pushcdn_tpu.broker.tasks import handlers
     real, met = handlers._stage_with_backpressure, {"link": 0, "user": 0}
+    real_retry = handlers._retry_full
+
+    def home_of(message):
+        publisher = plan.HEADER.unpack_from(bytes(message.message))[0]
+        return mesh.layout.group_of(mesh.layout.pub_users[publisher])
 
     async def counted(device, message, raw):
-        publisher = plan.HEADER.unpack_from(bytes(message.message))[0]
-        home = mesh.layout.group_of(mesh.layout.pub_users[publisher])
-        here = mesh.cluster.brokers.index(device.broker)
-        met["link" if here != home else "user"] += 1
+        if mesh.cluster.brokers.index(device.broker) != home_of(message):
+            met["link"] += 1
         return await real(device, message, raw)
 
+    async def retried(device, topics, stage_items, results, *rest):
+        here = mesh.cluster.brokers.index(device.broker)
+        for (message, _, _), result in zip(stage_items, results):
+            if result == StageResult.FULL:
+                assert home_of(message) == here
+                met["user"] += 1
+        return await real_retry(device, topics, stage_items, results, *rest)
+
     monkeypatch.setattr(handlers, "_stage_with_backpressure", counted)
+    monkeypatch.setattr(handlers, "_retry_full", retried)
     return met
 
 
@@ -203,6 +219,47 @@ async def test_meshed_device_brokers_deliver_what_the_reference_owes(
             assert said["link_frames_forwarded"] == \
                 plane.link_frames_forwarded
             assert said["device_memory_peak_bytes"] >= 0
+
+
+@pytest.mark.parametrize("ring_slots", [64, 16])
+async def test_the_native_pass_on_a_linked_broker_forwards_what_it_staged(
+        ring_slots):
+    """The user loop's native pass on brokers with a peer link: it stages
+    the broadcasts of a batch and stops at a direct whose recipient lives
+    behind the other broker (the rest of the batch is scanned), or, with
+    16 slots, holds frames back for the retry; every broadcast it staged
+    still reaches the peer, once, in its publisher's order, and every
+    direct its owner's broker."""
+    async with Mesh(2, _plane(ring_slots, extra_lanes=(),
+                              bypass_max_items=0), seed=45) as mesh:
+        planes = [b.device_plane for b in mesh.cluster.brokers]
+        log = []
+        for _ in range(3):
+            planned = [mesh.frames_of(p, 32) for p in range(PUBLISHERS)]
+            await asyncio.gather(*(mesh.publish(p, frames)
+                                   for p, frames in enumerate(planned)))
+            log += [(p, frame.kind, frame.target)
+                    for p, frames in enumerate(planned)
+                    for frame, _ in frames]
+        owed = reference.route(mesh.table, log)
+        await wait_until(lambda: mesh.received() >= reference.total(owed),
+                         timeout=60)
+        await asyncio.sleep(0.3)  # a delivery too many would come now
+        assert reference.compare(
+            owed, [d.report() for d in mesh.detectors]) == []
+        assert (mesh.duplicates(), mesh.foreign) == (0, 0)
+        # a probe is a direct to its own publisher: it crosses no link
+        crossing = sum(kind in (plan.BROADCAST, plan.DIRECT)
+                       for _, kind, _ in log)
+        assert sum(p.link_frames_forwarded for p in planes) == crossing
+        said = [p.describe() for p in planes]
+        assert sum(d["ingress_native_frames"] for d in said) > 0
+        assert sum(d["ingress_native_stops"] for d in said) > 0
+        for d in said:
+            assert d["ingress_native_frames"] <= d["frames_staged"]
+            assert d["ingress_native_restaged"] <= d["stage_full_frames"]
+        if ring_slots == 16:
+            assert sum(d["ingress_native_restaged"] for d in said) > 0
 
 
 async def test_lone_frames_over_a_link_take_the_idle_bypass_to_users_only():
@@ -311,6 +368,7 @@ async def test_traced_links_spans_conserve_the_planes_link_counters(
 
         await rounds(1)  # no session: the same path records nothing
         before = counted()
+        native = sum(p.ingress_native_frames for p in planes)
         options = jax.profiler.ProfileOptions()
         options.python_tracer_level = 0
         options.host_tracer_level = 2
@@ -321,6 +379,8 @@ async def test_traced_links_spans_conserve_the_planes_link_counters(
             jax.profiler.stop_trace()
         link_staged, forwarded, staged = (
             b - a for a, b in zip(before, counted()))
+        # the user loops' native pass took frames meanwhile
+        assert sum(p.ingress_native_frames for p in planes) > native
     threads, _ = _program_spans(str(tmp_path))
     events = [e for evs in threads.values() for e in evs]
     names = {e[0] for e in events}
